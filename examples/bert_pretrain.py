@@ -17,18 +17,17 @@ import time
 
 import jax
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 import paddle_tpu as paddle
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 import paddle_tpu.optimizer as opt
 from paddle_tpu.models.bert import (BertConfig, BertForPretraining,
                                     bert_pretrain_loss_fn, ernie_large)
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch-size", type=int, default=64)

@@ -16,6 +16,7 @@ import tempfile
 import numpy as np
 
 import paddle_tpu as paddle
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 import paddle_tpu.optimizer as opt
 from paddle_tpu.models.gpt import GPT, GPTConfig, gpt_loss_fn
 from paddle_tpu.models.generation import (DecoderPredictor,
@@ -24,6 +25,7 @@ from paddle_tpu.models.generation import (DecoderPredictor,
 
 
 def main():
+    enable_compile_cache()
     paddle.seed(0)
     cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=2,
                     num_heads=4, max_seq_len=32)

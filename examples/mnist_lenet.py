@@ -13,9 +13,11 @@ import argparse
 import numpy as np
 
 import paddle_tpu as paddle
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=2)
     ap.add_argument("--batch-size", type=int, default=64)

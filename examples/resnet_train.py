@@ -18,15 +18,14 @@ import time
 
 import jax
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 import paddle_tpu as paddle
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--depth", type=int, default=50,
                     choices=[18, 34, 50, 101, 152])

@@ -587,8 +587,8 @@ def _serving_programs() -> List[_Program]:
 
 def _collective_programs() -> List[_Program]:
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
+    from ..parallel import shard_map
     from ..parallel.ring_attention import (ring_attention,
                                            ulysses_attention)
 
@@ -627,7 +627,7 @@ def _collective_programs() -> List[_Program]:
                    in_specs=({"w": P("dp", None), "b": P("dp")},),
                    # ptlint: disable=PT-S001  committed registry layout
                    out_specs={"w": P(None, None), "b": P(None)},
-                   check_rep=False)
+                   check_vma=False)
     return [
         _Program("collective.ring_attention", ring, (q, q, q),
                  donation_applies=False),

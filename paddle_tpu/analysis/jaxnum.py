@@ -847,7 +847,7 @@ class _Interp:
             was_downcast=s.was_downcast))
 
     # -------------------------------------------------- control flow
-    def _h_pjit(self, eqn, path, mult):
+    def _h_jit(self, eqn, path, mult):
         inner = eqn.params["jaxpr"]
         ins = [self.read(v) for v in eqn.invars]
         outs = self.run(inner, ins, f"{path}/pjit", mult)

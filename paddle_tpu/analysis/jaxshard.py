@@ -476,7 +476,7 @@ class _Interp:
                         mult)
         self.write(eqn.outvars[0], dst)
 
-    def _h_pjit(self, eqn, path: str, mult: int) -> None:
+    def _h_jit(self, eqn, path: str, mult: int) -> None:
         inner = eqn.params["jaxpr"]
         in_sh = eqn.params.get("in_shardings",
                                (None,) * len(eqn.invars))
@@ -920,7 +920,7 @@ def analyze_jit(fn, *args, name: str, mesh,
     sizes = _mesh_sizes(mesh)
     closed = jax.make_jaxpr(fn)(*args)
     outer = closed.jaxpr
-    pj = [e for e in outer.eqns if e.primitive.name == "pjit"]
+    pj = [e for e in outer.eqns if e.primitive.name == "jit"]
     if len(outer.eqns) != 1 or not pj:
         raise ValueError(
             f"{name}: expected a single top-level pjit equation "
@@ -1207,7 +1207,7 @@ def _collective_mesh_programs():
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ..parallel.compat import shard_map
+    from ..parallel import shard_map
     from ..parallel.ring_attention import (ring_attention,
                                            ulysses_attention)
 

@@ -74,12 +74,9 @@ class CUDAPinnedPlace(CPUPlace):
 
 @functools.lru_cache(maxsize=None)
 def _accelerator_platform():
-    """Best accelerator platform name available in this process."""
-    try:
-        platform = jax.default_backend()
-    except RuntimeError:
-        return "cpu"
-    return platform
+    """Platform name of this process's default backend. A backend that
+    fails to initialise raises here: answering "cpu" would hide it."""
+    return jax.default_backend()
 
 
 @functools.lru_cache(maxsize=None)

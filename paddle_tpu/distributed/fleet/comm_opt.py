@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ...parallel.compat import shard_map
+from ...parallel import shard_map
 
 from ...core.tensor import Tensor
 from ...core import random as _random

@@ -70,8 +70,8 @@ class BertSelfAttention(nn.Layer):
         """Packed-pair flash routing (ops/pallas/packed_flash.route_gate).
         At ERNIE-large geometry (T=512, d=64, 16 heads) the upstream
         flash kernel pads head_dim 64->128 AND stages an f32 output —
-        128 MB/layer of HLO temps (the bs=32 OOM receipt in BENCH_DETAIL
-        notes); the packed kernel keeps pairs on the 128 lanes with bf16
+        128 MB/layer of HLO temps (an earlier builder saw bs=32 run out
+        of HBM on it); the packed kernel keeps pairs on the 128 lanes with bf16
         in/out."""
         from ..ops.pallas import packed_flash
         return packed_flash.route_gate(

@@ -177,7 +177,7 @@ class GPTAttention(nn.Layer):
         parallelism composes with the other degrees."""
         from jax.sharding import PartitionSpec as P
         from ..core.dispatch import dispatch
-        from ..parallel.compat import shard_map
+        from ..parallel import shard_map
         from ..parallel.mesh import ensure_global_mesh
         from ..parallel.ring_attention import ring_attention
         if self.cfg.dropout > 0.0 and self.training:
@@ -194,7 +194,7 @@ class GPTAttention(nn.Layer):
             lambda q_, k_, v_: ring_attention(q_, k_, v_, "sp",
                                               causal=True),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            axis_names={"sp"}, check_vma=False)
+            axis_names={"sp"})
         return dispatch("ring_attention", fn, (q, k, v), {}, True)
 
 

@@ -110,28 +110,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         true_d = q.shape[-1] // 2
         sc = scale if scale is not None else 1.0 / float(np.sqrt(true_d))
         from ...ops.pallas.flash_attention import _packed_flash
-        try:
-            out = _packed_flash(q, k, v, is_causal, sc)
-            _note_flash(True)
-            return out
-        except Exception as e:
-            _note_flash(False, e)
-            # unpack to plain heads-major and continue composed:
-            # [B,Hp,T,128] -> [B,Hp,T,2,64] -> [B,Hp,2,T,64] -> [B,H,T,64]
-            from ...ops import manipulation as M
-            B, Hp, T = q.shape[0], q.shape[1], q.shape[2]
-
-            def unpack(t):
-                t = M.reshape(t, [B, Hp, T, 2, true_d])
-                return M.reshape(M.transpose(t, [0, 1, 3, 2, 4]),
-                                 [B, 2 * Hp, T, true_d])
-            q, k, v = unpack(q), unpack(k), unpack(v)
-            out = _sdpa(q, k, v, None, is_causal, sc, None, 0.0, True)
-            # repack so the caller's downstream reshape sees one layout
-            out = M.reshape(M.transpose(
-                M.reshape(out, [B, Hp, 2, T, true_d]), [0, 1, 3, 2, 4]),
-                [B, Hp, T, 2 * true_d])
-            return out
+        # the caller's gate (packed_flash.route_gate) already said this
+        # geometry is supported on this backend, so a failure here is a
+        # broken kernel and raises: no composed detour hides it
+        out = _packed_flash(q, k, v, is_causal, sc)
+        _note_flash(True)
+        return out
     head_dim = q.shape[-1]
     sc = scale if scale is not None else 1.0 / float(np.sqrt(head_dim))
     dropout_active = dropout_p > 0.0 and training
@@ -140,17 +124,20 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                  and not dropout_active
                  and q_seq >= _flags.flag("flash_attention_min_seq"))
     if use_flash:
-        try:
-            from ...ops.pallas.flash_attention import flash_attention
-            out = flash_attention(q, k, v, causal=is_causal, scale=sc,
-                                  heads_major=_heads_major)
+        from ...ops.pallas import flash_attention as _fa
+        if _fa.supported(q.shape, _heads_major):
+            # the gate said yes: a failure past this point is a broken
+            # kernel and raises — no composed detour hides it
+            out = _fa.flash_attention(q, k, v, causal=is_causal, scale=sc,
+                                      heads_major=_heads_major)
             _note_flash(True)
             return out
-        except Exception as e:
-            # fall back to composed path (e.g. odd shapes, CPU quirks) —
-            # but LOUDLY: a silent fallback costs ~1.5x attention time with
-            # green tests (round-3 verdict weak #4)
-            _note_flash(False, e)
+        # the kernel's gate declined (non-TPU backend, a sequence that
+        # does not tile): composed attention, LOUDLY once — a silent
+        # detour costs ~1.5x attention time with green tests
+        _note_flash(False, NotImplementedError(
+            f"flash gate declined shape {tuple(q.shape)} on backend "
+            f"{jax.default_backend()!r}"))
     else:
         # deliberate routing (mask/dropout/short-seq), not a fallback:
         # record the path without the warning

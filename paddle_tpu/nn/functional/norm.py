@@ -159,8 +159,8 @@ def _bn_train(x, weight, bias, eps, c_axis):
 # dedup the autodiff residuals: composed bn→relu saves BOTH the conv
 # output (BN's custom-vjp residual) and the BN output (relu's vjp mask
 # input), materialising an extra full activation tensor per BN site in
-# fwd and reading it back in bwd. ResNet-50 is HBM-bound (BENCH_DETAIL
-# resnet_roofline), so those bytes are the step time.
+# fwd and reading it back in bwd. ResNet-50 was profiled HBM-bound by an
+# earlier builder, so those bytes would be the step time.
 #
 # This fused op saves ONLY the conv output: the relu mask is recomputed
 # in bwd as the affine test  x*scale + shift (+z) > 0  (per-channel fp32

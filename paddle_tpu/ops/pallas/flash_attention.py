@@ -17,6 +17,7 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ...core.dispatch import op
 from ...core.tensor import Tensor
@@ -27,8 +28,20 @@ def _kernel():
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         flash_attention as fa, BlockSizes)
     if not _patch_lmdi_width1():
-        _patch_dq_di_broadcast()
+        import warnings
+        warnings.warn(
+            "flash attention: the width-1 l/m/di rewrite no longer matches "
+            "the installed jax.experimental.pallas.ops.tpu.flash_attention "
+            "source; the backward runs UNPATCHED and materialises three "
+            "[B, H, T, 128] f32 broadcasts per layer", RuntimeWarning,
+            stacklevel=2)
     return fa, BlockSizes
+
+
+def applied_patch() -> str:
+    """Which source rewrite of the upstream kernel is live in this
+    process: "lmdi_width1" or "none" (chip_smoke.py prints it)."""
+    return "lmdi_width1" if _patch_lmdi_width1() else "none"
 
 
 @functools.lru_cache(maxsize=1)
@@ -44,7 +57,8 @@ def _patch_lmdi_width1():
     (`jnp.broadcast_to(x, capped_logits.shape)` — a register splat, no
     HBM traffic). Result-identical; verified against composed attention
     on TPU. Applied by guarded source rewrite; any drift in the upstream
-    text → return False and fall back to the narrower dq-di patch."""
+    text → return False, and `_kernel` warns that the backward runs
+    unpatched."""
     import inspect
     import re
     import jax.experimental.pallas.ops.tpu.flash_attention as m
@@ -90,56 +104,44 @@ def _patch_lmdi_width1():
     return True
 
 
-@functools.lru_cache(maxsize=1)
-def _patch_dq_di_broadcast():
-    """Fix an upstream waste in the pallas flash bwd-dq wrapper: it
-    materialises `di` broadcast to [B, H, T, block_k_major] (1.6 GB at
-    T=1024/block 1024) although its BlockSpec only ever reads a
-    MIN_BLOCK_SIZE-wide block — profiled at ~4 ms/layer of pure HBM
-    broadcast traffic on v5e (50 ms/step on the 12-layer GPT). The kernel
-    body already tiles di from 128 lanes, so shrinking the broadcast is
-    result-identical. Patched by source rewrite with a guard: if the
-    upstream line is gone (fixed), this is a no-op."""
-    import inspect
-    import jax.experimental.pallas.ops.tpu.flash_attention as m
+def _mesh_ways():
+    """(mesh, batch axes, batch ways, head ways) of the global mesh when it
+    spans several devices, else None. Batch shards over (dp, sharding) and
+    heads over tp — the layouts ShardedTrainStep and the TP layers give
+    q/k/v — and every other axis has to be trivial."""
+    from ...parallel.mesh import get_global_mesh
+    mesh = get_global_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    batch_axes = tuple(a for a in ("dp", "sharding") if a in mesh.shape)
+    b_ways = int(np.prod([mesh.shape[a] for a in batch_axes], dtype=int))
+    return mesh, batch_axes, b_ways, mesh.shape.get("tp", 1)
 
-    try:
-        src = inspect.getsource(m._flash_attention_bwd_dq)
-    except (OSError, AttributeError):
+
+def supported(q_shape, heads_major: bool = False) -> bool:
+    """Kernel gate. pallas TPU kernel: seq must tile into the (≥128) q/k
+    blocks; head_dim needs lane alignment only (verified on v5e: d=64 and
+    d=96 both run and match composed attention to bf16 tolerance). Under
+    a multi-device mesh the kernel runs per shard (`_fa_sharded`), so
+    batch and heads must divide over it. Other backends are routed to
+    composed attention; a backend that fails to initialise raises out of
+    `jax.default_backend()`."""
+    if jax.default_backend() != "tpu":
         return False
-    bad = "di = jnp.broadcast_to(di[..., None], (*di.shape, block_k_major))"
-    good = "di = jnp.broadcast_to(di[..., None], (*di.shape, MIN_BLOCK_SIZE))"
-    if bad not in src:
-        return False  # upstream fixed; nothing to do
-    # second guard: only patch if the kernel provably reads di through a
-    # MIN_BLOCK_SIZE-wide BlockSpec — if a future jax consumes the full
-    # block_k_major width, shrinking the broadcast would be silently wrong
-    if ("di_spec = pl.BlockSpec((1, 1, block_q_major, MIN_BLOCK_SIZE)"
-            not in src):
-        return False
-    # exec into the live module dict so the patched function shares the
-    # module's globals (a snapshot copy would freeze later rebinds)
-    exec(src.replace(bad, good), m.__dict__)  # noqa: S102 - vendored jax fix
-    return True
-
-
-def _supported(q_shape):
-    # pallas TPU kernel: seq must tile into the (≥128) q/k blocks; head_dim
-    # needs lane alignment only (verified on v5e: d=64 and d=96 both run
-    # and match composed attention to bf16 tolerance). Non-TPU backends
-    # fall back to composed attention.
-    try:
-        if jax.default_backend() not in ("tpu",):
+    b, d = q_shape[0], q_shape[3]
+    h, t = (q_shape[1], q_shape[2]) if heads_major else \
+        (q_shape[2], q_shape[1])
+    ways = _mesh_ways()
+    if ways is not None:
+        mesh, _, b_ways, h_ways = ways
+        if b_ways * h_ways != mesh.size or b % b_ways or h % h_ways:
             return False
-    except RuntimeError:
-        return False
-    b, t, h, d = q_shape
     return t % 128 == 0 and d % 8 == 0 and d >= 32
 
 
 def _largest_block(t):
     # largest power-of-two block ≤1024 that divides the sequence (the
-    # kernel requires seq % block == 0; _supported guarantees t % 128 == 0).
+    # kernel requires seq % block == 0; `supported` guarantees t % 128 == 0).
     # 1024-wide measured +2.4% over 512 at T=1024/hd=128 on v5e (r2); a
     # 1024×128 bf16 q tile is 256KiB — comfortably inside VMEM.
     for b in (1024, 512, 256, 128):
@@ -216,20 +218,41 @@ def _fa_bwd(causal, scale, res, do):
 _fa_core.defvjp(_fa_fwd, _fa_bwd)
 
 
+def _fa_sharded(qh, kh, vh, causal, scale):
+    """`_fa_core` under the global mesh. GSPMD cannot partition a Mosaic
+    kernel ("wrap the call in a shard_map"), and attention is independent
+    across batch and heads, so on several devices each runs the kernel on
+    its own [B/ways, H/tp, T, D] shard inside a full-manual shard_map.
+    check_vma is off because upstream's pallas_call out_shapes carry no
+    vma, which the check demands."""
+    ways = _mesh_ways()
+    if ways is None:
+        return _fa_core(qh, kh, vh, causal, scale)
+    mesh, batch_axes, _, _ = ways
+    # ptlint: disable=PT-S001  not a layout decision: it restates the
+    # layout q/k/v already have (batch over dp x sharding, heads over tp)
+    # so the kernel can run per shard; `supported` declines anything else
+    spec = P(batch_axes, "tp" if "tp" in mesh.shape else None, None, None)
+    return jax.shard_map(
+        lambda q, k, v: _fa_core(q, k, v, causal, scale), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(qh, kh, vh)
+
+
 @op("flash_attention")
 def _flash(q, k, v, causal, scale):
     # paddle layout [B, T, H, D] -> kernel layout [B, H, T, D]
     qh = jnp.swapaxes(q, 1, 2)
     kh = jnp.swapaxes(k, 1, 2)
     vh = jnp.swapaxes(v, 1, 2)
-    out = _fa_core(qh, kh, vh, causal, scale)
+    out = _fa_sharded(qh, kh, vh, causal, scale)
     return jnp.swapaxes(out, 1, 2)
 
 
 @op("flash_attention_hm")
 def _flash_hm(q, k, v, causal, scale):
     # already in kernel layout [B, H, T, D]; output stays heads-major
-    return _fa_core(q, k, v, causal, scale)
+    return _fa_sharded(q, k, v, causal, scale)
 
 
 @op("packed_flash_attention")
@@ -246,12 +269,10 @@ def flash_attention(q, k, v, causal=False, scale=None, heads_major=False):
     skips the swapaxes copies the custom-call boundary would force)."""
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
-    b, x1, x2, d = q.shape
-    shape_btdh = (b, x2, x1, d) if heads_major else tuple(q.shape)
-    if not _supported(shape_btdh):
+    if not supported(q.shape, heads_major):
         raise NotImplementedError(
             f"flash_attention: unsupported shape {q.shape} or non-TPU "
-            "backend; caller should fall back to composed attention")
+            "backend; caller should route to composed attention")
     if heads_major:
         return _flash_hm(q, k, v, causal, scale)
     return _flash(q, k, v, causal, scale)

@@ -3,9 +3,9 @@
 At head_dim 64 the standard flash path pays twice: (a) d=64 tiles fill
 half the 128-lane MXU (unavoidable — a real kernel floor), and (b) XLA
 materialises the [B,T,H,64]<->[B,H,T,64] transposes around the pallas
-custom call because 64-minor layouts don't fuse (measured 18.8 GB/step of
-extra traffic on the 12-head GPT bench geometry, BENCH_DETAIL
-mfu_12head). This module removes (b): adjacent head PAIRS stay packed on
+custom call because 64-minor layouts don't fuse (an earlier builder profiled
+18.8 GB/step of extra traffic on the 12-head GPT bench geometry). This
+module removes (b): adjacent head PAIRS stay packed on
 the 128-lane minor dimension end to end — [B, H/2, T, 128], a pure
 reshape of the projection output, whose transpose to heads-major fuses —
 and the kernels split the two 64-wide halves IN REGISTERS (BlockSpec
@@ -65,10 +65,7 @@ BWD_SINGLE_MAX = 1024
 
 
 def supported(head_dim: int, num_heads: int, q_seq: int, kv_seq: int) -> bool:
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except RuntimeError:
+    if jax.default_backend() != "tpu":
         return False
     return (head_dim == 64 and num_heads % 2 == 0
             and q_seq == kv_seq and q_seq % 128 == 0 and q_seq <= MAX_SEQ)
@@ -78,15 +75,17 @@ def route_gate(head_dim: int, num_heads: int, q_seq: int, kv_seq: int,
                dropout_active: bool = False, masked: bool = False) -> bool:
     """Model-side routing gate shared by GPTAttention/BertSelfAttention:
     packed-pair kernels apply under the same conditions as the flash path
-    (no mask/dropout, seq past the flash threshold), outside a tp-sharded
-    fused-qkv region (sliced_qkv takes the unpacked tp path), and within
-    this kernel's scope (`supported`)."""
+    (no mask/dropout, seq past the flash threshold), on one device (GSPMD
+    cannot partition a Mosaic kernel, and under tp sliced_qkv takes the
+    unpacked path; several devices get the standard flash kernel per
+    shard, flash_attention._fa_sharded), and within this kernel's scope
+    (`supported`)."""
     if masked or dropout_active:
         return False
     from ...core import flags as _flags
     from ...parallel.mesh import get_global_mesh
     mesh = get_global_mesh()
-    if mesh is not None and mesh.shape.get("tp", 1) > 1:
+    if mesh is not None and mesh.size > 1:
         return False
     return (_flags.flag("use_flash_attention")
             and q_seq >= _flags.flag("flash_attention_min_seq")

@@ -40,10 +40,7 @@ def supported(head_dim: int, num_heads: int, block_size: int) -> bool:
     ``interpret=True``); lane-aligned head_dim so the [H, D] accumulator
     tiles cleanly; block_size at least sublane width so the [H, bs]
     score tile is a legal VMEM shape."""
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except RuntimeError:
+    if jax.default_backend() != "tpu":
         return False
     return head_dim % 8 == 0 and block_size % 8 == 0 and num_heads >= 1
 
@@ -78,26 +75,28 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0].astype(jnp.float32)      # [H, D]
         k = k_ref[0].astype(jnp.float32)      # [bs, H, D]
         v = v_ref[0].astype(jnp.float32)      # [bs, H, D]
-        # scores[h, s] = scale * sum_d q[h, d] k[s, h, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * jnp.float32(scale)
-        # mask positions at/past the row length (2D iota: TPU requires it)
+        # One query token per row leaves the MXU nothing to tile, and
+        # Mosaic refuses a dot_general batched over the middle dim of the
+        # [bs, H, D] tile (no non-contracting lhs dim). So both products
+        # are VPU multiplies with a reduce, kept 3-D in the tile's own
+        # layout: bs untiled, H on sublanes, D (or the keepdims 1) on lanes.
+        # scores[s, h, 0] = scale * sum_d q[h, d] k[s, h, d]
+        s = jnp.sum(q[None] * k, axis=2, keepdims=True) \
+            * jnp.float32(scale)                             # [bs, H, 1]
+        # mask positions at/past the row length
         pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+            jnp.int32, s.shape, 0)
         s = jnp.where(pos < length, s, jnp.float32(NEG_INF))
 
         m_prev = m_ref[:, :1]                                # [H, 1]
         l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_cur = jnp.max(s, axis=0)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                               # [H, bs]
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        # out[h, d] = sum_s p[h, s] v[s, h, d]
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)              # [H, D]
+        p = jnp.exp(s - m_new[None])                         # [bs, H, 1]
+        l_new = alpha * l_prev + jnp.sum(p, axis=0)
+        # out[h, d] = sum_s p[s, h, 0] v[s, h, d]
+        pv = jnp.sum(p * v, axis=0)                          # [H, D]
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -160,13 +159,12 @@ def ragged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                                num_blocks_kv=num_blocks_kv,
                                scale=float(scale))
     # int32 grid arithmetic (same reason flash_attention scopes x64 off)
-    from jax.experimental import disable_x64
-    with disable_x64():
+    with jax.enable_x64(False):
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((n, h, d), q.dtype),
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
@@ -197,15 +195,17 @@ def ragged_attention_reference(q, k_pool, v_pool, block_tables, lengths,
         safe = jnp.where(live_blk, idx, 0)
         k = k_pool[safe].astype(jnp.float32)          # [N, bs, H, D]
         v = v_pool[safe].astype(jnp.float32)
-        s = jnp.einsum("nhd,nshd->nhs", qf, k) * scale
-        pos = j * bs + jnp.arange(bs, dtype=jnp.int32)[None, None, :]
-        s = jnp.where(pos < lens[:, None, None], s, NEG_INF)
-        m_cur = jnp.max(s, axis=2, keepdims=True)
+        s = jnp.sum(qf[:, None] * k, axis=3, keepdims=True) \
+            * jnp.float32(scale)                      # [N, bs, H, 1]
+        pos = j * bs + jnp.arange(bs, dtype=jnp.int32)[None, :, None, None]
+        s = jnp.where(pos < lens[:, None, None, None], s,
+                      jnp.float32(NEG_INF))
+        m_cur = jnp.max(s, axis=1)                    # [N, H, 1]
         m_new = jnp.maximum(m, m_cur)
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l + jnp.sum(p, axis=2, keepdims=True)
-        pv = jnp.einsum("nhs,nshd->nhd", p, v)
+        p = jnp.exp(s - m_new[:, None])
+        l_new = alpha * l + jnp.sum(p, axis=1)
+        pv = jnp.sum(p * v, axis=1)                   # [N, H, D]
         acc_new = acc * alpha + pv
         # skipped blocks leave the carry untouched, exactly like @pl.when
         keep = live_blk[:, None, None]

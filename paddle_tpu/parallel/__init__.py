@@ -11,4 +11,4 @@ from .api import (  # noqa: F401
     param_spec,
 )
 from .ring_attention import ring_attention, ulysses_attention  # noqa: F401
-from .compat import shard_map  # noqa: F401  (version-tolerant shim)
+from jax import shard_map  # noqa: F401  (the one import point)

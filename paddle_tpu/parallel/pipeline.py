@@ -32,7 +32,7 @@ from ..core.dispatch import op
 from ..core.tensor import Tensor
 from . import mesh as _mesh
 
-from .compat import shard_map  # version-tolerant shim (parallel/compat.py)
+from jax import shard_map
 
 
 def pipeline_spmd(stage_fn, mesh, num_stages: int, num_micro: int,

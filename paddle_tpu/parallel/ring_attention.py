@@ -27,8 +27,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .compat import shard_map  # noqa: F401  (re-export: the version-
-# tolerant shim callers pair with ring/ulysses attention)
+from jax import shard_map  # noqa: F401  (re-export: what callers pair
+# with ring/ulysses attention)
 
 __all__ = ["ring_attention", "shard_map", "ulysses_attention"]
 
@@ -75,9 +75,15 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True,
         v_nxt = jax.lax.ppermute(v_cur, axis_name, rot)
         return (k_nxt, v_nxt, m_new, l_new, acc_new)
 
-    m0 = jnp.full(q.shape[:3], -jnp.inf, jnp.float32)
-    l0 = jnp.zeros(q.shape[:3], jnp.float32)
-    acc0 = jnp.zeros(q.shape, jnp.float32)
+    # the carry leaves the loop varying over the ring axis (it mixes in
+    # q and the rotating k/v), so it has to enter varying too:
+    # shard_map's type check rejects a fori_loop whose carry changes type
+    def varying(x):
+        return jax.lax.pcast(x, (axis_name,), to="varying")
+
+    m0 = varying(jnp.full(q.shape[:3], -jnp.inf, jnp.float32))
+    l0 = varying(jnp.zeros(q.shape[:3], jnp.float32))
+    acc0 = varying(jnp.zeros(q.shape, jnp.float32))
     _, _, m, l, acc = jax.lax.fori_loop(
         0, n, body, (k, v, m0, l0, acc0))
     # fully-masked rows (can't happen with causal self-attention over own

@@ -8,7 +8,7 @@ Lint fixture — parsed by ptlint, never executed.
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from paddle_tpu.parallel.compat import shard_map
+from paddle_tpu.parallel import shard_map
 
 
 def constrain(x, mesh):

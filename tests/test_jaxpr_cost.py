@@ -93,7 +93,7 @@ def test_psum_tree_comm_exact():
     """Grad-sync shape: per-leaf psum over a 4-device dp axis under
     shard_map. Per-device shards: w [2,8]=64 B, b [1]=4 B; psum moves
     2x input bytes (reduce-scatter + all-gather) -> 2*68 = 136."""
-    from jax.experimental.shard_map import shard_map
+    from paddle_tpu.parallel import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     devs = jax.devices()
@@ -107,7 +107,7 @@ def test_psum_tree_comm_exact():
     pt = shard_map(psum_tree, mesh=mesh,
                    in_specs=({"w": P("dp", None), "b": P("dp")},),
                    out_specs={"w": P(None, None), "b": P(None)},
-                   check_rep=False)
+                   check_vma=False)
     cost = jaxcost.estimate_fn(pt, tree, name="pt")
     assert cost.flops == 0
     assert cost.comm_bytes == 136

@@ -28,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddle_tpu.analysis import jaxshard
 from paddle_tpu.parallel import set_global_mesh
-from paddle_tpu.parallel.compat import shard_map
+from paddle_tpu.parallel import shard_map
 
 pytestmark = pytest.mark.lint
 

@@ -282,6 +282,27 @@ def test_attention_path_routing_by_seq_len():
         attn_mod._warned_fallback = False
 
 
+@pytest.mark.parametrize("packed", [False, True],
+                         ids=["flash", "packed_pairs"])
+def test_attention_kernel_failure_raises(monkeypatch, packed):
+    """A kernel that fails on a geometry its gate accepted is a broken
+    kernel: scaled_dot_product_attention raises, it does not hand back the
+    composed result (which would pass every test at ~1.5x the time)."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def broken(*a, **k):
+        raise RuntimeError("Mosaic refused the kernel")
+    monkeypatch.setattr(fa, "supported", lambda *a, **k: True)
+    monkeypatch.setattr(fa, "flash_attention", broken)
+    monkeypatch.setattr(fa, "_packed_flash", broken)
+    # [B, H, T, D] heads-major, T past flash_attention_min_seq
+    q = paddle.to_tensor(np.zeros((1, 2, 512, 128), "float32"))
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        F.scaled_dot_product_attention(q, q, q, is_causal=True,
+                                       _heads_major=True,
+                                       _packed_pairs=packed)
+
+
 def test_losses_match_torch():
     logits = np.random.randn(8, 5).astype("float32")
     labels = np.random.randint(0, 5, 8)

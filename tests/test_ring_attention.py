@@ -45,7 +45,7 @@ def test_ring_attention_matches_full(causal):
     f = shard_map(
         lambda q, k, v: ring_attention(q, k, v, "sp", causal=causal),
         mesh=mesh, in_specs=P(None, None, "sp", None),
-        out_specs=P(None, None, "sp", None), check_vma=False)
+        out_specs=P(None, None, "sp", None))
     got = np.asarray(f(q, k, v))
     want = np.asarray(_full_attention(q, k, v, causal))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
@@ -66,7 +66,7 @@ def test_ring_attention_gradients_match_full():
         f = shard_map(
             lambda q, k, v: ring_attention(q, k, v, "sp", causal=True),
             mesh=mesh, in_specs=P(None, None, "sp", None),
-            out_specs=P(None, None, "sp", None), check_vma=False)
+            out_specs=P(None, None, "sp", None))
         return jnp.sum(f(q, k, v) * w)
 
     def full_loss(q, k, v):
@@ -150,7 +150,7 @@ def test_ring_attention_bf16_long_sequence():
     f = shard_map(
         lambda q, k, v: ring_attention(q, k, v, "sp", causal=True),
         mesh=mesh, in_specs=P(None, None, "sp", None),
-        out_specs=P(None, None, "sp", None), check_vma=False)
+        out_specs=P(None, None, "sp", None))
     got = np.asarray(f(q, k, v)).astype(np.float32)
     want = np.asarray(_full_attention(
         jnp.asarray(qf), jnp.asarray(kf), jnp.asarray(vf), True))
@@ -174,7 +174,7 @@ def test_ulysses_attention_matches_full(causal):
     f = shard_map(
         lambda q, k, v: ulysses_attention(q, k, v, "sp", causal=causal),
         mesh=mesh, in_specs=P(None, None, "sp", None),
-        out_specs=P(None, None, "sp", None), check_vma=False)
+        out_specs=P(None, None, "sp", None))
     got = np.asarray(f(q, k, v))
     want = np.asarray(_full_attention(q, k, v, causal))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
@@ -195,7 +195,7 @@ def test_ulysses_gradients_match_full():
         f = shard_map(
             lambda q, k, v: ulysses_attention(q, k, v, "sp", causal=True),
             mesh=mesh, in_specs=P(None, None, "sp", None),
-            out_specs=P(None, None, "sp", None), check_vma=False)
+            out_specs=P(None, None, "sp", None))
         return jnp.sum(f(q, k, v) * w)
 
     def full_loss(q, k, v):

@@ -70,7 +70,7 @@ def test_cummax_negative_axis(axis):
     np.testing.assert_allclose(take, out.numpy(), rtol=1e-6)
 
 
-def test_scaling_anchor_reads_bench_detail(tmp_path):
+def test_scaling_anchor_reads_bench_line(tmp_path):
     """ADVICE round 5: the projection anchor must read the headline's
     `value` key (and verify the metric name), not a metric-named key."""
     import sys
@@ -80,27 +80,26 @@ def test_scaling_anchor_reads_bench_detail(tmp_path):
     finally:
         sys.path.pop(0)
 
-    # live headline → anchor derived from it, labeled live
-    (tmp_path / "BENCH_DETAIL.json").write_text(json.dumps(
+    # a bench.py line measured on a chip → anchor derived from it
+    line = tmp_path / "bench_line.json"
+    line.write_text(json.dumps(
         {"metric": FLAGSHIP_METRIC, "value": 163840.0}))
-    step_s, src = read_flagship_anchor(str(tmp_path))
+    step_s, src = read_flagship_anchor(str(line))
     assert step_s == pytest.approx(32 * 1024 / 163840.0, abs=1e-4)
-    assert "live" in src
+    assert "bench_line.json" in src
 
-    # wrong metric (re-pointed headline) → raises LOUDLY; before the REVIEW
-    # fix this ValueError was swallowed by the function's own except and
-    # silently pinned the fallback
-    (tmp_path / "BENCH_DETAIL.json").write_text(json.dumps(
+    # wrong metric (re-pointed headline) → raises LOUDLY
+    line.write_text(json.dumps(
         {"metric": "resnet_imgs_per_sec", "value": 9999.0}))
     with pytest.raises(ValueError, match="headline metric"):
-        read_flagship_anchor(str(tmp_path))
+        read_flagship_anchor(str(line))
 
-    # right metric but malformed value → also loud, not fallback
-    (tmp_path / "BENCH_DETAIL.json").write_text(json.dumps(
-        {"metric": FLAGSHIP_METRIC}))
+    # right metric but malformed value → also loud
+    line.write_text(json.dumps({"metric": FLAGSHIP_METRIC}))
     with pytest.raises(KeyError):
-        read_flagship_anchor(str(tmp_path))
+        read_flagship_anchor(str(line))
 
-    # missing file → fallback (the only silent path left)
-    step_s, src = read_flagship_anchor(str(tmp_path / "nope"))
-    assert step_s == 0.1996 and "fallback" in src
+    # missing file → no silent fallback constant: there is no projection
+    # without a step measured on a chip
+    with pytest.raises(OSError):
+        read_flagship_anchor(str(tmp_path / "nope.json"))

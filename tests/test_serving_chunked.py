@@ -217,7 +217,11 @@ def test_eos_mid_chunk_stops_exactly_at_eos(model):
     reserved past it (freed with the table, zero leaks)."""
     p = np.arange(1, 6, dtype=np.int32)
     ref = _reference_tokens(model, p, 8)
-    eos = int(ref[3])                 # greedy emits this 4th -> mid-chunk
+    # a token greedy emits mid-chunk (index 0 is the prefill's, the first
+    # chunk's scan emits 1..8) and has not emitted before: the engine
+    # rightly stops at an eos's FIRST occurrence
+    at = next(i for i in range(2, 7) if ref[i] not in ref[:i])
+    eos = int(ref[at])
     eng = _engine(model, decode_chunk_size=8)
     rid = eng.add_request(p, SamplingParams(max_tokens=8,
                                             eos_token_id=eos))
@@ -225,7 +229,8 @@ def test_eos_mid_chunk_stops_exactly_at_eos(model):
     while eng.has_unfinished():
         outs.extend(eng.step())
     req = eng.get_request(rid)
-    np.testing.assert_array_equal(np.asarray(req.output_ids), ref[:4])
+    np.testing.assert_array_equal(np.asarray(req.output_ids),
+                                  ref[:at + 1])
     assert outs[-1].finished and outs[-1].finish_reason == "stop"
     assert eng.cache.num_free() == eng.config.num_blocks
     eng.cache.check_integrity()

@@ -1,0 +1,152 @@
+"""The chip's compiler, asked without the chip.
+
+The kernels of the two main paths, compiled at the real widths of
+chip_smoke.py for a described (not attached) v5e:2x2: the flash forward
+and backward of the 6-head flagship, the packed-pair kernels of the
+12-head one at both backward branches, the ragged decode kernel at both
+head geometries, and the flash kernel per shard under a 2x2 mesh. Interpret
+mode accepts what Mosaic refuses (an unaligned slice, a batched dot with no
+free lhs dim, too much VMEM); these compiles do not. Nothing runs, so they
+say nothing about results or times.
+
+One file on purpose: only one process at a time may load the TPU's
+library, so the topology is described inside a module-scoped fixture, by
+the one xdist worker that is handed this file, and every compile happens
+in the test's own process.
+"""
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+import paddle_tpu  # noqa: F401  (x64 on, as every kernel caller has it)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _as_the_program_compiles():
+    """Two settings of the test harness that the program does not run
+    under. A compile for a described chip writes cache entries that a
+    chipless process cannot read back, so the persistent cache is off
+    around it. And conftest.py pins matmul precision to "highest" for its
+    f64 oracles, under which upstream's flash kernel asks Mosaic for an
+    fp32 contraction of bf16 tiles and is refused; the program runs at
+    the default."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    cache = jax.config.jax_enable_compilation_cache
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache)
+    jax.config.update("jax_default_matmul_precision", precision)
+    cc.reset_cache()
+
+
+def _kernel_calls(fn, *args) -> int:
+    """Compile for the described chip (raises what its compiler would
+    raise) and count the Mosaic kernels left in the program."""
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call")
+
+
+def _flash_loss(q, k, v):
+    from paddle_tpu.ops.pallas.flash_attention import _fa_core
+    return _fa_core(q, k, v, True, 1.0 / float(np.sqrt(q.shape[-1]))) \
+        .astype(jnp.float32).sum()
+
+
+# [B, H, T, D] bf16 causal: the flagship's attention, and one long shape
+@pytest.mark.parametrize("shape", [(32, 6, 1024, 128), (4, 6, 8192, 128)],
+                         ids=["flagship", "long_8k"])
+def test_flash_fwd_bwd_compiles(one_chip, shape):
+    from paddle_tpu.ops.pallas.flash_attention import applied_patch
+    assert applied_patch() == "lmdi_width1"   # the patched text is compiled
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    grad = jax.value_and_grad(_flash_loss, argnums=(0, 1, 2))
+    assert _kernel_calls(grad, x, x, x) == 3      # fwd, dq, dkv
+
+
+def test_flash_primal_compiles(one_chip):
+    """The no-grad forward (eval, generate's prefill) skips the residuals
+    and is a program of its own."""
+    x = jax.ShapeDtypeStruct((32, 6, 1024, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    assert _kernel_calls(_flash_loss, x, x, x) == 1
+
+
+# 12 heads of 64 packed in pairs -> [B, 6, T, 128]; T=2048 takes the
+# FA2-style backward (packed_flash.BWD_SINGLE_MAX)
+@pytest.mark.parametrize("shape,calls", [((32, 6, 1024, 128), 2),
+                                         ((16, 6, 2048, 128), 3)],
+                         ids=["bwd_single", "bwd_fa2"])
+def test_packed_flash_fwd_bwd_compiles(one_chip, shape, calls):
+    from paddle_tpu.ops.pallas.packed_flash import (BWD_SINGLE_MAX,
+                                                    packed_flash_attention)
+    assert (shape[2] <= BWD_SINGLE_MAX) == (calls == 2)
+
+    def loss(q, k, v):
+        return packed_flash_attention(q, k, v, True, 0.125) \
+            .astype(jnp.float32).sum()
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    assert _kernel_calls(grad, x, x, x) == calls
+
+
+# the serving shape: 8 rows, float32 pools of 512 blocks x 32 tokens,
+# 32 blocks per sequence (max_seq_len 1024)
+@pytest.mark.parametrize("heads,head_dim", [(6, 128), (12, 64)])
+def test_ragged_decode_compiles(one_chip, heads, head_dim):
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_decode_attention
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    pool = sds((512, 32, heads, head_dim), jnp.float32)
+    assert _kernel_calls(
+        ragged_decode_attention, sds((8, heads, head_dim), jnp.float32),
+        pool, pool, sds((8, 32), jnp.int32), sds((8,), jnp.int32)) == 1
+
+
+def test_flash_compiles_per_shard_under_a_mesh(topo):
+    """GSPMD refuses to partition a Mosaic kernel; under a multi-device
+    mesh the flash kernel runs per shard (flash_attention._fa_sharded).
+    GPT-medium's attention on sharding=2 x tp=2, chip_smoke's four-chip
+    layout: batch over sharding, heads over tp."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.parallel import build_mesh, get_global_mesh, \
+        set_global_mesh
+    mesh = build_mesh(sharding=2, tp=2, devices=topo.devices)
+    before = get_global_mesh()
+    set_global_mesh(mesh)
+    try:
+        x = jax.ShapeDtypeStruct(
+            (8, 16, 1024, 64), jnp.bfloat16,
+            sharding=NamedSharding(mesh, P(("dp", "sharding"), "tp")))
+
+        def loss(q, k, v):
+            return fa._fa_sharded(q, k, v, True, 0.125) \
+                .astype(jnp.float32).sum()
+        grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+        assert _kernel_calls(grad, x, x, x) == 3
+    finally:
+        set_global_mesh(before)

@@ -1,8 +1,9 @@
 """Long-context single-chip probe: flagship GPT at T=2048/4096/8192.
 
-Extends the BENCH_DETAIL long_context series (flash attention keeps HBM
-O(T), so MFU RISES with sequence while the attention-flops share grows):
-T=2048 MFU 0.650, T=4096 0.688, T=8192 0.749 on one v5e chip.
+Long-context series (flash attention keeps HBM O(T), so MFU should RISE
+with sequence while the attention-flops share grows). An earlier builder
+reported T=2048 MFU 0.650, T=4096 0.688, T=8192 0.749 on one v5e chip;
+not re-measured on today's code.
 Run: python tools/gpt_long_probe.py [T] [bs]
 """
 import os
