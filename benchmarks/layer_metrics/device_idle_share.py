@@ -1,0 +1,7 @@
+"""Share of the traced span in which no operation ran on the device."""
+
+
+def compute(record, trace):
+    if not trace or not trace["window_s"]:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
