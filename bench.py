@@ -78,16 +78,6 @@ def _static_entry(cost, tokens_per_call: int, dev=None) -> dict:
     return entry
 
 
-def _publish_roofline(program: str) -> None:
-    """Mirror a _STATIC_EST roofline into the obs registry
-    (static_roofline_tokens_per_sec{program}) so per-step
-    measured_vs_roofline gauges can read it while the bench runs."""
-    roof = _STATIC_EST.get(program, {}).get("roofline_tokens_per_sec")
-    if roof:
-        from paddle_tpu import obs
-        obs.set_roofline(program, roof)
-
-
 def _best_of(run_window, windows: int) -> float:
     """Best (min) wall time over `windows` runs of run_window().
     run_window must drain the device before returning. (ROADMAP A1(e)
@@ -162,9 +152,6 @@ def bench_gpt(on_tpu: bool, num_heads: int = 6, iters: int = 30):
     _STATIC_EST["train_step"] = _static_entry(
         estimate_train_step(step, x, y), batch * seq,
         jax.devices()[0] if on_tpu else None)
-    # publish the static ceiling so TrainStep's per-step
-    # train_measured_vs_roofline gauge is live during the timed loop
-    _publish_roofline("train_step")
 
     # warmup/compile
     step(x, y)
@@ -498,7 +485,6 @@ def bench_decode(on_tpu: bool):
     _STATIC_EST["decode_step"] = _static_entry(
         estimate_decode_step(extract_params(model), geom, bs), bs,
         jax.devices()[0] if on_tpu else None)
-    _publish_roofline("decode_step")
     rng = np.random.RandomState(0)
     ids = rng.randint(0, cfg.vocab_size, (bs, prompt), dtype=np.int32)
     short = new // 3

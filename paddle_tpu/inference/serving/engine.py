@@ -47,7 +47,13 @@ obs.trace span — the step itself is cat="serving", the phases carry
 their own categories (cat="schedule"/"prefill"/"decode") — so a chrome
 trace exported with profiler.export_chrome_tracing (or obs.trace
 .export_chrome) shows schedule/prefill/decode per engine step with
-request counts in args. EngineStats is a thin view over the obs
+request counts in args. The same spans, with the pieces of each phase
+as children (serving.prefill.forward/.write_cache/.fetch/.sample,
+serving.decode.pack/.dispatch/.fetch/.drain) and serving.add_request,
+appear in any open jax.profiler session on the device trace's clock,
+with request_id / tokens / context_tokens as stats: the catalog in
+docs/observability.md is the contract with the benchmark's readers.
+EngineStats is a thin view over the obs
 metrics registry, and the step loop additionally records TTFT /
 inter-token / request-latency / step-time histograms plus queue and
 cache-occupancy gauges — all host-side on values the step already
@@ -55,6 +61,7 @@ fetched, so instrumentation adds ZERO device syncs (PT-T007 clean).
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -531,6 +538,22 @@ for _f in _STAT_REQ_SUMS:
 del _f
 
 
+def _context_tokens(reqs, k: int) -> int:
+    """KV positions a k-trip chunk attends to, summed over rows and trips:
+    a row whose first reserved position is p reads p + j + 1 at trip j
+    (the fused chunk's `att_lens`), for as many trips as its prompt feed
+    and its max_tokens leave it. A row that stops early at EOS is counted
+    to its max_tokens: the host cannot know before the fetch."""
+    total = 0
+    for req in reqs:
+        feed = max(0, req.pf_target - req.prefill_pos)
+        left = req.params.max_tokens - len(req.output_ids)
+        # the trip that eats the last prompt token also samples
+        trips = min(k, max(0, feed - 1) + left)
+        total += trips * req.slot[2] + trips * (trips + 1) // 2
+    return total
+
+
 def _bucket(n: int, cap: int) -> int:
     b = 1
     while b < n:
@@ -683,10 +706,15 @@ class LLMEngine:
             # unknown tenant ids are caller bugs, refused loudly before
             # any engine state is touched
             tenants.resolve(sampling.tenant)
-        with self._lock:
+        with self._lock, contextlib.ExitStack() as scope:
             if request_id is None:
                 request_id = f"req-{self._next_id}"
                 self._next_id += 1
+            # entered here and not round the lock: the id is minted above
+            scope.enter_context(RecordEvent(
+                "serving.add_request", cat="serving",
+                args={"request_id": request_id,
+                      "prompt_tokens": int(ids.size)}))
             old = self._requests.get(request_id)
             if old is not None and old.state != RequestState.MIGRATED:
                 # a migrated-out tombstone does NOT block re-admission:
@@ -1274,7 +1302,8 @@ class LLMEngine:
         self.stats.steps += 1
         step_no = self.stats.steps
         self._step_start = time.perf_counter()
-        with RecordEvent("serving.engine_step", cat="serving") as step_ev:
+        with RecordEvent("serving.engine_step", cat="serving",
+                         args={"step": step_no}) as step_ev:
             # ptlint: disable=PT-C004  fault injector: inert no-op in
             # production (env-gated); chaos tests NEED it inside the lock
             # to corrupt state at the exact point a real fault would
@@ -1302,35 +1331,37 @@ class LLMEngine:
             for req in batch.prefill:
                 t0 = time.perf_counter()
                 tokens = req.all_token_ids()
-                with RecordEvent("serving.prefill", cat="prefill") as ev:
-                    ev.args = {"request_id": req.request_id,
-                               "tokens": int(tokens.size)}
+                with RecordEvent("serving.prefill", cat="prefill",
+                                 args={"request_id": req.request_id,
+                                       "tokens": int(tokens.size)}):
                     try:
                         logits = self._prefill(req, tokens)
                     except Exception as e:
                         self._quarantine(req, outs, f"prefill raised: {e}")
                         continue
-                self.stats.prefill_tokens += int(tokens.size)
-                prefill_spend += int(tokens.size)
-                self.stats.time_prefill += time.perf_counter() - t0
-                # ptlint: disable=PT-C004  fault injector (see step())
-                logits = self.faults.poison_logits(step_no, logits)
-                # logits are already host numpy (_prefill fetched them);
-                # the host-side check avoids re-uploading them through a
-                # jnp reduction every step (ptlint PT-T002's defect
-                # class: a device round-trip per prefill)
-                if anomaly.any_not_finite_host(logits):
-                    self._quarantine(req, outs,
-                                     "non-finite prefill logits")
-                    continue
-                obs.reqtrace.record("prefill", req.tid, req.request_id,
-                                    tokens=int(tokens.size))
-                self._emit(req, self._sample(req, logits), outs)
-                if not req.finished and self._wedged():
-                    # prefill attribution is exact: the request whose
-                    # forward blew the budget is the one in hand
-                    self.stats.watchdog_trips += 1
-                    self._quarantine(req, outs, "wedged prefill")
+                    self.stats.prefill_tokens += int(tokens.size)
+                    prefill_spend += int(tokens.size)
+                    self.stats.time_prefill += time.perf_counter() - t0
+                    # ptlint: disable=PT-C004  fault injector (see step())
+                    logits = self.faults.poison_logits(step_no, logits)
+                    # logits are already host numpy (_prefill fetched
+                    # them); the host-side check avoids re-uploading them
+                    # through a jnp reduction every step (ptlint PT-T002's
+                    # defect class: a device round-trip per prefill)
+                    if anomaly.any_not_finite_host(logits):
+                        self._quarantine(req, outs,
+                                         "non-finite prefill logits")
+                        continue
+                    obs.reqtrace.record("prefill", req.tid, req.request_id,
+                                        tokens=int(tokens.size))
+                    with RecordEvent("serving.prefill.sample",
+                                     cat="prefill"):
+                        self._emit(req, self._sample(req, logits), outs)
+                    if not req.finished and self._wedged():
+                        # prefill attribution is exact: the request whose
+                        # forward blew the budget is the one in hand
+                        self.stats.watchdog_trips += 1
+                        self._quarantine(req, outs, "wedged prefill")
 
             # requests finished right at prefill release their blocks
             # before the decode gather builds its tables
@@ -1338,8 +1369,9 @@ class LLMEngine:
             if decode:
                 t0 = time.perf_counter()
                 k = self.config.decode_chunk_size
-                with RecordEvent("serving.decode", cat="decode") as ev:
-                    ev.args = {"num_seqs": len(decode), "chunk": k}
+                with RecordEvent("serving.decode", cat="decode", args={
+                        "num_seqs": len(decode), "chunk": k,
+                        "context_tokens": _context_tokens(decode, k)}):
                     # ptlint: disable=PT-C004  fault injector: stalls ON
                     # PURPOSE under the lock to exercise the watchdog
                     self.faults.stall(step_no)
@@ -1349,57 +1381,11 @@ class LLMEngine:
                         toks = None
                         self._recover(decode, [decode[0]], outs,
                                       f"decode raised: {e}")
-                dt = time.perf_counter() - t0
-                self.stats.time_decode += dt
-                self.stats.observe_decode_chunk(dt)
-                if toks is not None:
-                    # the not-finite flags were computed IN-SCAN and
-                    # arrived with the chunk fetch — anomaly attribution
-                    # costs no extra sync (and no host re-reduction)
-                    # ptlint: disable=PT-C004  fault injector (see step())
-                    bad = self.faults.poison_chunk(step_no, bad)
-                    if bad.any():
-                        # a bad row poisons the whole chunk: every
-                        # emission is discarded, offenders quarantined,
-                        # survivors requeued — replay is bitwise because
-                        # sampling keys depend only on request progress
-                        self._recover(
-                            decode,
-                            [r for i, r in enumerate(decode) if bad[i]],
-                            outs, "non-finite decode logits in chunk")
-                    elif self._wedged():
-                        # a wedged batched chunk cannot be attributed;
-                        # quarantine its head (deterministic) and rebuild
-                        # the rest — the whole chunk's tokens are dropped
-                        # so survivors stay bitwise on the replay
-                        self.stats.watchdog_trips += 1
-                        self._recover(decode, [decode[0]], outs,
-                                      "wedged decode chunk (watchdog)")
-                    else:
-                        # step-major drain of the fetched chunk: row j of
-                        # toks is scan step j; -1 marks a frozen row.
-                        # _emit re-derives eos/max_tokens terminals on
-                        # host — the same conditions the device froze on
-                        # — so telemetry and finish_reason stay exact.
-                        emitted: Dict[str, int] = {}
-                        for j in range(toks.shape[0]):
-                            for i, req in enumerate(decode):
-                                t = int(toks[j, i])
-                                if t >= 0 and not req.finished:
-                                    self._emit(req, t, outs)
-                                    emitted[req.request_id] = \
-                                        emitted.get(req.request_id, 0) + 1
-                        # chunk-boundary trace events: tokens emitted
-                        # per row + the finish latch (host values only)
-                        for req in decode:
-                            n_emit = emitted.get(req.request_id, 0)
-                            if n_emit:
-                                obs.reqtrace.record(
-                                    "decode_chunk", req.tid,
-                                    req.request_id, n=n_emit,
-                                    total=len(req.output_ids),
-                                    finished=req.finished,
-                                    **(self._rev_tag or {}))
+                    dt = time.perf_counter() - t0
+                    self.stats.time_decode += dt
+                    self.stats.observe_decode_chunk(dt)
+                    if toks is not None:
+                        self._drain_chunk(step_no, decode, toks, bad, outs)
             step_ev.args = {"step": step_no, "outputs": len(outs),
                             "errors": self.stats.errors,
                             "expired": self.stats.expired,
@@ -1429,20 +1415,78 @@ class LLMEngine:
         return outs
 
     @holds_lock("_lock")
+    def _drain_chunk(self, step_no: int, decode: List[Request], toks, bad,
+                     outs: List[RequestOutput]) -> None:
+        """What the step does with a fetched chunk: discard it and recover
+        if a row went bad or the watchdog tripped, else emit its tokens."""
+        # the not-finite flags were computed IN-SCAN and
+        # arrived with the chunk fetch — anomaly attribution
+        # costs no extra sync (and no host re-reduction)
+        # ptlint: disable=PT-C004  fault injector (see step())
+        bad = self.faults.poison_chunk(step_no, bad)
+        if bad.any():
+            # a bad row poisons the whole chunk: every
+            # emission is discarded, offenders quarantined,
+            # survivors requeued — replay is bitwise because
+            # sampling keys depend only on request progress
+            self._recover(
+                decode,
+                [r for i, r in enumerate(decode) if bad[i]],
+                outs, "non-finite decode logits in chunk")
+        elif self._wedged():
+            # a wedged batched chunk cannot be attributed;
+            # quarantine its head (deterministic) and rebuild
+            # the rest — the whole chunk's tokens are dropped
+            # so survivors stay bitwise on the replay
+            self.stats.watchdog_trips += 1
+            self._recover(decode, [decode[0]], outs,
+                          "wedged decode chunk (watchdog)")
+        else:
+            # step-major drain of the fetched chunk: row j of
+            # toks is scan step j; -1 marks a frozen row.
+            # _emit re-derives eos/max_tokens terminals on
+            # host — the same conditions the device froze on
+            # — so telemetry and finish_reason stay exact.
+            emitted: Dict[str, int] = {}
+            with RecordEvent("serving.decode.drain", cat="decode"):
+                for j in range(toks.shape[0]):
+                    for i, req in enumerate(decode):
+                        t = int(toks[j, i])
+                        if t >= 0 and not req.finished:
+                            self._emit(req, t, outs)
+                            emitted[req.request_id] = \
+                                emitted.get(req.request_id, 0) + 1
+                # chunk-boundary trace events: tokens emitted
+                # per row + the finish latch (host values only)
+                for req in decode:
+                    n_emit = emitted.get(req.request_id, 0)
+                    if n_emit:
+                        obs.reqtrace.record(
+                            "decode_chunk", req.tid,
+                            req.request_id, n=n_emit,
+                            total=len(req.output_ids),
+                            finished=req.finished,
+                            **(self._rev_tag or {}))
+
+    @holds_lock("_lock")
     def _prefill(self, req: Request, tokens: np.ndarray) -> np.ndarray:
         """Dense prefill (shared jitted program with generate()),
         scattered into the sequence's blocks. One upload (the prompt),
         one fetch (the last-position logits [V]) — already the minimal
         host/device traffic for a prompt forward."""
-        logits, dense_cache = gen.prefill(
-            self.params, jnp.asarray(tokens[None], jnp.int32), self.geom)
+        with RecordEvent("serving.prefill.forward", cat="prefill"):
+            logits, dense_cache = gen.prefill(
+                self.params, jnp.asarray(tokens[None], jnp.int32),
+                self.geom)
+        # span serving.prefill.write_cache is write_prefill's own
         self.cache.write_prefill(req.request_id, dense_cache, tokens.size)
         if self.cache.prefix_index is not None:
             # every prompt position's KV is now written — index the
             # full blocks immediately so template siblings queued
             # behind this request already hit
             self.cache.register_prefix(req.request_id, tokens)
-        out = np.asarray(logits[0])
+        with RecordEvent("serving.prefill.fetch", cat="prefill"):
+            out = np.asarray(logits[0])
         self.stats.inc_host_sync("prefill")
         return out
 
@@ -1465,37 +1509,40 @@ class LLMEngine:
         n = self.config.max_num_seqs if ragged \
             else _bucket(len(reqs), self.config.max_num_seqs)
         mb = self.max_blocks_per_seq
-        packed = np.zeros((n, PACK_COLS + k + mb), np.int32)
-        fed = []                             # (req, tokens consumed)
-        for i, req in enumerate(reqs):
-            p = req.params
-            packed[i, 0] = req.last_token
-            packed[i, 1] = req.slot[2]       # first reserved position
-            packed[i, 2] = 1                 # active (padding rows: 0)
-            packed[i, 3] = len(req.output_ids)
-            packed[i, 4] = p.max_tokens
-            packed[i, 5] = -1 if p.eos_token_id is None \
-                else int(p.eos_token_id)
-            packed[i, 6] = pack_f32(p.temperature)
-            packed[i, 7] = int(p.top_k)
-            packed[i, 8] = pack_f32(p.top_p)
-            packed[i, 9] = p.seed & 0x7FFFFFFF
-            if req.prefill_pos < req.pf_target:
-                pf_rem = req.pf_target - req.prefill_pos
-                f = min(k, pf_rem)
-                packed[i, 10] = f
-                packed[i, 11] = 1 if pf_rem > k else 0
-                prompt = req.all_token_ids()
-                packed[i, PACK_COLS:PACK_COLS + f] = \
-                    prompt[req.prefill_pos:req.prefill_pos + f]
-                fed.append((req, f))
-            table = self.cache.block_table(req.request_id)
-            packed[i, PACK_COLS + k:PACK_COLS + k + len(table)] = table
-        out, pools = fused_decode_chunk(
-            self.params, self.cache.pools, jnp.asarray(packed),
-            self.geom, k, self.config.kernel)
-        self.cache.pools = pools
-        fetched = np.asarray(out)            # the chunk's ONE host sync
+        with RecordEvent("serving.decode.pack", cat="decode"):
+            packed = np.zeros((n, PACK_COLS + k + mb), np.int32)
+            fed = []                         # (req, tokens consumed)
+            for i, req in enumerate(reqs):
+                p = req.params
+                packed[i, 0] = req.last_token
+                packed[i, 1] = req.slot[2]   # first reserved position
+                packed[i, 2] = 1             # active (padding rows: 0)
+                packed[i, 3] = len(req.output_ids)
+                packed[i, 4] = p.max_tokens
+                packed[i, 5] = -1 if p.eos_token_id is None \
+                    else int(p.eos_token_id)
+                packed[i, 6] = pack_f32(p.temperature)
+                packed[i, 7] = int(p.top_k)
+                packed[i, 8] = pack_f32(p.top_p)
+                packed[i, 9] = p.seed & 0x7FFFFFFF
+                if req.prefill_pos < req.pf_target:
+                    pf_rem = req.pf_target - req.prefill_pos
+                    f = min(k, pf_rem)
+                    packed[i, 10] = f
+                    packed[i, 11] = 1 if pf_rem > k else 0
+                    prompt = req.all_token_ids()
+                    packed[i, PACK_COLS:PACK_COLS + f] = \
+                        prompt[req.prefill_pos:req.prefill_pos + f]
+                    fed.append((req, f))
+                table = self.cache.block_table(req.request_id)
+                packed[i, PACK_COLS + k:PACK_COLS + k + len(table)] = table
+        with RecordEvent("serving.decode.dispatch", cat="decode"):
+            out, pools = fused_decode_chunk(
+                self.params, self.cache.pools, jnp.asarray(packed),
+                self.geom, k, self.config.kernel)
+            self.cache.pools = pools
+        with RecordEvent("serving.decode.fetch", cat="decode"):
+            fetched = np.asarray(out)        # the chunk's ONE host sync
         self.stats.inc_host_sync("decode")
         live = len(reqs)
         # padded-vs-live telemetry: the bucketed fallback burns compute

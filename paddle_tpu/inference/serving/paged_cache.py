@@ -69,6 +69,7 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ... import obs
 from . import kv_quant
 from .host_tier import HostTierStore
 from .prefix_cache import PrefixCacheIndex, PrefixNode
@@ -1144,7 +1145,6 @@ class PagedKVCache:
         ids = self._tables[seq_id]
         n_blocks, bs = len(ids), self.block_size
         t_pad = n_blocks * bs
-        idx = jnp.asarray(ids, jnp.int32)
 
         def scatter(pool, dense):
             # [H, S, D] -> [S, H, D] -> [n_blocks, bs, H, D]
@@ -1152,9 +1152,12 @@ class PagedKVCache:
             blk = blk.reshape(n_blocks, bs, self.num_heads, self.head_dim)
             return pool.at[idx].set(blk)
 
-        self.pools = tuple(
-            (scatter(kp, kc), scatter(vp, vc))
-            for (kp, vp), (kc, vc) in zip(self.pools, dense_cache))
+        with obs.span("serving.prefill.write_cache", cat="prefill",
+                      args={"blocks": n_blocks}):
+            idx = jnp.asarray(ids, jnp.int32)
+            self.pools = tuple(
+                (scatter(kp, kc), scatter(vp, vc))
+                for (kp, vp), (kc, vc) in zip(self.pools, dense_cache))
 
     def prefix_stats(self) -> dict:
         """Prefix-cache telemetry snapshot (engine gauges + load suite

@@ -476,8 +476,22 @@ class TrainStep:
         self._rng_expected = None
         self._rng_ctr = None
         self._key_root = None
-        # previous dispatch timestamp for the obs cadence metric
+        # previous dispatch timestamp for the obs cadence metric, and the
+        # metric children it feeds: looked up once, not every step
         self._prev_dispatch_t = None
+        from .. import obs
+        self._obs_step_seconds = obs.histogram(
+            "train_step_seconds",
+            "per-step train time via inter-dispatch cadence",
+            unit="seconds").labels()
+        self._obs_tokens = obs.counter(
+            "train_tokens_total",
+            "tokens consumed by dispatched train steps",
+            unit="tokens").labels()
+        self._obs_tokens_per_sec = obs.gauge(
+            "train_tokens_per_sec",
+            "training throughput over the last dispatch gap",
+            unit="tokens_per_second").labels()
 
     def invalidate(self):
         """Drop the cached parameter/buffer bindings. Call after changing
@@ -578,33 +592,17 @@ class TrainStep:
         step time (the runtime blocks on the previous step's donated
         buffers), so the cadence converges on it with zero added syncs.
         The first dispatch (compile) only arms the clock."""
-        from .. import obs
         now = time.perf_counter()
         prev = self._prev_dispatch_t
         self._prev_dispatch_t = now
         if prev is None:
             return
         interval = now - prev
-        obs.histogram(
-            "train_step_seconds",
-            "per-step train time via inter-dispatch cadence",
-            unit="seconds").observe(interval / draws)
+        self._obs_step_seconds.observe(interval / draws)
         tokens = _batch_tokens(arr_args)
         if tokens and interval > 0:
-            tps = tokens / interval
-            obs.counter("train_tokens_total",
-                        "tokens consumed by dispatched train steps",
-                        unit="tokens").inc(tokens)
-            obs.gauge("train_tokens_per_sec",
-                      "training throughput over the last dispatch gap",
-                      unit="tokens_per_second").set(tps)
-            roof = obs.get_roofline("train_step")
-            if roof:
-                # live MFU proxy: measured throughput over the jaxcost
-                # static-model roofline (bench/scaling publish it)
-                obs.gauge("train_measured_vs_roofline",
-                          "measured tokens/s over the jaxcost static "
-                          "roofline for train_step").set(tps / roof)
+            self._obs_tokens.inc(tokens)
+            self._obs_tokens_per_sec.set(tokens / interval)
 
     def __call__(self, *args):
         loss, extras = self._dispatch(self._step, 1, args)

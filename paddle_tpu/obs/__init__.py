@@ -45,8 +45,6 @@ __all__ = [
     # export
     "snapshot", "dump_snapshot", "to_prometheus", "export_chrome_trace",
     "SnapshotExporter", "export", "registry",
-    # roofline cross-link
-    "set_roofline", "get_roofline",
 ]
 
 
@@ -65,30 +63,3 @@ def histogram(name: str, help: str = "", labels=(), unit: str = "",
     """Get-or-create a histogram family in the default REGISTRY."""
     return REGISTRY.histogram(name, help=help, labels=labels, unit=unit,
                               buckets=buckets, sample_cap=sample_cap)
-
-
-# --------------------------------------------------------------- roofline
-# jaxcost's static model publishes per-program roofline tokens/s here
-# (bench.py / scaling_analysis set it); the training loop divides its
-# measured tokens/s by it into the `train_measured_vs_roofline` gauge so
-# MFU drift is a live metric, not just a benchmark column.
-
-def set_roofline(program: str, tokens_per_sec: float) -> None:
-    """Publish a static-model roofline (tokens/s) for `program`."""
-    gauge("static_roofline_tokens_per_sec",
-          "jaxcost static-model roofline throughput per program",
-          labels=("program",),
-          unit="tokens_per_second").labels(program=program).set(
-              float(tokens_per_sec))
-
-
-def get_roofline(program: str):
-    """Roofline tokens/s previously published for `program`, or None."""
-    fam = REGISTRY.get("static_roofline_tokens_per_sec")
-    if fam is None:
-        return None
-    child = fam.get(program=program)
-    if child is None:
-        return None
-    v = child.value
-    return v if v > 0 else None

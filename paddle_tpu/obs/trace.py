@@ -10,16 +10,21 @@ the phases the load suite and chaos runner care about — prefill /
 decode / schedule on the serving side, checkpoint / restart / train on
 the training side — instead of a flat op list.
 
-Spans are host wall-clock only (time.perf_counter on already-running
-host code); the optional jax.profiler.TraceAnnotation makes the same
-scope visible inside an XLA device trace but is entered lazily and
-only while tracing is enabled, so importing this module never pulls in
-jax and disabled spans cost two attribute reads.
+Two sinks, one switch each. The in-process table (`events()`,
+`export_chrome`, `profiler.summary`) fills while `enable()` is on: host
+wall-clock, time.perf_counter on already-running host code. The
+profiler's own trace needs no switch of ours: a span enters a
+jax.profiler.TraceAnnotation whenever `annotate` is true and jax is
+already imported, and the annotation records only while some caller has
+a profiler session open (`jax.profiler.start_trace`), on the device
+trace's clock. This module never imports jax itself, so `import
+paddle_tpu.obs` and a span in a jax-free process stay jax-free.
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import List, Optional
@@ -102,10 +107,11 @@ class Span:
     and the new obs ones record identically).
 
     Context manager or decorator. `cat` tags the chrome-trace category
-    (see CATEGORIES); `args` is an optional dict written into the trace
-    event — set at construction or mutate `span.args` inside the scope
-    (the serving engine records per-step request counts this way), it
-    is read at end(). `annotate=False` skips the
+    (see CATEGORIES). `args` given at construction reach both sinks: the
+    profiler's annotation takes its scalar values as event stats on the
+    clean name (`serving.decode` with stat `num_seqs`), the chrome
+    trace the whole dict. `span.args` set inside the scope is read at
+    end() and reaches the chrome trace only. `annotate=False` skips the
     jax.profiler.TraceAnnotation for spans that must stay jax-free.
     """
 
@@ -119,12 +125,16 @@ class Span:
         self._ann = None
 
     def begin(self):
+        # the one place that decides whether to annotate: the profiler
+        # session is the switch, and TraceAnnotation reads it itself
+        jax = sys.modules.get("jax") if self.annotate else None
+        if jax is not None:
+            stats = {k: v for k, v in (self.args or {}).items()
+                     if isinstance(v, (str, int, float))}
+            self._ann = jax.profiler.TraceAnnotation(self.name, **stats)
+            self._ann.__enter__()
         if _TraceState.enabled:
             self._t0 = time.perf_counter()
-            if self.annotate:
-                import jax
-                self._ann = jax.profiler.TraceAnnotation(self.name)
-                self._ann.__enter__()
             depth = getattr(_TraceState.tls, "depth", 0)
             _TraceState.tls.depth = depth + 1
 
@@ -137,10 +147,10 @@ class Span:
                     self.name, self._t0, t1,
                     threading.get_ident(), _TraceState.tls.depth,
                     self.cat, self.args))
-            if self._ann is not None:
-                self._ann.__exit__(None, None, None)
-                self._ann = None
             self._t0 = None
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
 
     def __enter__(self):
         self.begin()

@@ -234,6 +234,7 @@ def _fwd_call(q, k, v, causal, sm_scale, with_lse=False):
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name="packed_flash_fwd",
         )(q, k, v)
 
 
@@ -252,6 +253,7 @@ def _bwd_call(q, k, v, do, causal, sm_scale):
             out_shape=[shp, shp, shp],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
+            name="packed_flash_bwd",
         )(q, k, v, do)
 
 
@@ -400,6 +402,7 @@ def _bwd_call_fa2(q, k, v, do, o, lse, causal, sm_scale):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
+            name="packed_flash_bwd_dq",
         )(q, k, v, do, lse, delta)
         # dkv: swap grid roles — kv blocks parallel, q blocks innermost
         spec_q2 = pl.BlockSpec((1, 1, bq, d2),
@@ -419,6 +422,7 @@ def _bwd_call_fa2(q, k, v, do, o, lse, causal, sm_scale):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
+            name="packed_flash_bwd_dkv",
         )(q, k, v, do, lse, delta)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 

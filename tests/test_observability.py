@@ -260,12 +260,6 @@ def test_profiler_shim_shares_trace_table():
     assert [e.name for e in obs.trace.events()] == ["legacy"]
 
 
-def test_roofline_publish_and_read():
-    obs.set_roofline("test_prog", 1234.5)
-    assert obs.get_roofline("test_prog") == 1234.5
-    assert obs.get_roofline("never_published") is None
-
-
 # ----------------------------------------------------- engine step metrics
 def _tiny_engine():
     from paddle_tpu.models.gpt import GPT, GPTConfig

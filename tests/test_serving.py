@@ -387,10 +387,20 @@ def test_engine_steps_appear_in_chrome_trace(tmp_path):
     # attributable per phase in chrome://tracing
     by_cat = {e["name"]: e.get("cat") for e in events
               if e["name"].startswith("serving.")}
+    # PR 27: each phase's pieces are child spans of the phase's category
+    # (add_request ran before the profiler started)
     assert by_cat == {"serving.engine_step": "serving",
                       "serving.schedule": "schedule",
                       "serving.prefill": "prefill",
-                      "serving.decode": "decode"}
+                      "serving.prefill.forward": "prefill",
+                      "serving.prefill.write_cache": "prefill",
+                      "serving.prefill.fetch": "prefill",
+                      "serving.prefill.sample": "prefill",
+                      "serving.decode": "decode",
+                      "serving.decode.pack": "decode",
+                      "serving.decode.dispatch": "decode",
+                      "serving.decode.fetch": "decode",
+                      "serving.decode.drain": "decode"}
     sched = next(e for e in events if e["name"] == "serving.schedule")
     assert {"prefill", "decode", "free_blocks"} <= set(sched["args"])
     pre = next(e for e in events if e["name"] == "serving.prefill")

@@ -112,6 +112,56 @@ def test_packed_flash_fwd_bwd_compiles(one_chip, shape, calls):
     assert _kernel_calls(grad, x, x, x) == calls
 
 
+def _kernel_names(fn, *args) -> list:
+    """HLO names of the Mosaic kernels in the program compiled for the
+    described chip: what the device trace will call them."""
+    import re
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return sorted(re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", line)
+                  .group(1) for line in text.splitlines()
+                  if "tpu_custom_call" in line and " custom-call(" in line)
+
+
+# the names are the contract with the benchmark's kernel metrics
+# (benchmarks/lib/spans.py kernel_ops): JAX wraps a name given under jvp
+# or transpose in the transform's, `jvp(packed_flash_fwd)`, and XLA spells
+# that `jvp_packed_flash_fwd_`; a kernel without a name is `jvp__`
+@pytest.mark.parametrize("shape,names", [
+    ((32, 6, 1024, 128), ["jvp_packed_flash_fwd_",
+                          "transpose_jvp_packed_flash_bwd__"]),
+    ((16, 6, 2048, 128), ["jvp_packed_flash_fwd_",
+                          "transpose_jvp_packed_flash_bwd_dkv__",
+                          "transpose_jvp_packed_flash_bwd_dq__"])],
+    ids=["bwd_single", "bwd_fa2"])
+def test_packed_flash_kernels_carry_their_names(one_chip, shape, names):
+    from paddle_tpu.ops.pallas.packed_flash import packed_flash_attention
+
+    def loss(q, k, v):
+        return packed_flash_attention(q, k, v, True, 0.125) \
+            .astype(jnp.float32).sum()
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    assert _kernel_names(grad, x, x, x) == names
+
+
+def test_packed_flash_forward_alone_and_ragged_decode_are_named(one_chip):
+    from paddle_tpu.ops.pallas.packed_flash import packed_flash_attention
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_decode_attention
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x = sds((8, 6, 1024, 128), jnp.bfloat16)
+    assert _kernel_names(
+        lambda q, k, v: packed_flash_attention(q, k, v, True, 0.125),
+        x, x, x) == ["packed_flash_fwd"]
+    pool = sds((512, 32, 16, 64), jnp.float32)
+    assert _kernel_names(
+        ragged_decode_attention, sds((16, 16, 64), jnp.float32), pool, pool,
+        sds((16, 32), jnp.int32), sds((16,), jnp.int32)) == \
+        ["ragged_decode_attention"]
+
+
 # the serving shape: 8 rows, float32 pools of 512 blocks x 32 tokens,
 # 32 blocks per sequence (max_seq_len 1024)
 @pytest.mark.parametrize("heads,head_dim", [(6, 128), (12, 64)])
