@@ -516,6 +516,7 @@ def _serving_programs() -> List[_Program]:
     from ..inference.serving.attention import (PACK_COLS,
                                                fused_decode_chunk,
                                                paged_decode_step)
+    from ..inference.serving.paged_cache import write_prefill_scatter
     from ..models import generation as g
     from ..ops.pallas.ragged_paged_attention import \
         ragged_attention_reference
@@ -582,7 +583,22 @@ def _serving_programs() -> List[_Program]:
         getattr(fused_decode_chunk, "__wrapped__", fused_decode_chunk),
         (params, pools, pf_packed, geom, K, "ragged"),
         static_argnums=(3, 4, 5), donate_argnums=(1,))
-    return [prefill, paged, chunk, ragged, chunked_prefill]
+    # the dense-admission scatter (PagedKVCache.write_prefill): one
+    # program over all layers, keyed on pool geometry and dense-cache
+    # shape only — the block ids are always MB long, padded with the
+    # out-of-range id nb — with the pools donated (the cache rebinds
+    # them from the return value) and the dense cache NOT: batched
+    # callers scatter several rows of one dense cache
+    dense_leaf = jnp.zeros((N, H, S, D), dtype)
+    dense = tuple((dense_leaf, dense_leaf) for _ in range(L))
+    write_prefill = _Program(
+        "serving.write_prefill",
+        getattr(write_prefill_scatter, "__wrapped__",
+                write_prefill_scatter),
+        (pools, dense, jnp.full((MB,), nb, jnp.int32),
+         jnp.zeros((), jnp.int32)),
+        donate_argnums=(0,))
+    return [prefill, paged, chunk, ragged, chunked_prefill, write_prefill]
 
 
 def _collective_programs() -> List[_Program]:
@@ -651,6 +667,7 @@ _REGISTRY_NAMES = (
     "decode.attn", "decode.head",
     "serving.prefill", "serving.paged_decode", "serving.decode_chunk",
     "serving.ragged_attention", "serving.chunked_prefill",
+    "serving.write_prefill",
     "collective.ring_attention", "collective.ulysses_attention",
     "collective.psum_tree",
 )

@@ -9,7 +9,8 @@ outputs stream back token by token.
 Device work per step:
 - prefill: models.generation.prefill (the SAME jitted program the dense
   generate() path uses — one compilation per prompt-length bucket),
-  scattered into the sequence's blocks (PagedKVCache.write_prefill);
+  scattered into the sequence's blocks by ONE more program over all
+  layers with the pools donated (PagedKVCache.write_prefill);
 - decode: serving.attention.fused_decode_chunk — a jitted lax.scan that
   decodes, SAMPLES and tracks termination for up to decode_chunk_size
   tokens per running sequence entirely on device, padded to a
@@ -1486,7 +1487,10 @@ class LLMEngine:
             # behind this request already hit
             self.cache.register_prefix(req.request_id, tokens)
         with RecordEvent("serving.prefill.fetch", cat="prefill"):
-            out = np.asarray(logits[0])
+            # fetch, then index on the host: an eager logits[0] is one
+            # more device program, queued BEHIND the scatter, so the
+            # first token would wait for the scatter too
+            out = np.asarray(logits)[0]
         self.stats.inc_host_sync("prefill")
         return out
 

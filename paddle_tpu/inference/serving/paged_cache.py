@@ -56,16 +56,21 @@ unchanged.
 Host/device split: block accounting (free list, tables, lengths,
 refcounts, trie, counters) is plain Python — it feeds the scheduler
 and never traces. The pools themselves are jax arrays; `write_prefill`
-scatters a dense prefill cache into a sequence's blocks, and the
-decode step returns updated pools that the engine assigns back.
+scatters a dense prefill cache into a sequence's blocks with ONE
+jitted program over all layers (`write_prefill_scatter`, pools
+donated, one compilation per pool geometry and dense-cache shape —
+the host uploads the block ids and dispatches once per prefill), and
+the decode step returns updated pools that the engine assigns back.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import time
 from collections import Counter
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -75,6 +80,46 @@ from .host_tier import HostTierStore
 from .prefix_cache import PrefixCacheIndex, PrefixNode
 
 __all__ = ["PagedKVCache", "CacheExhausted"]
+
+
+# ptlint: disable=PT-T009  agrees with the committed plan entry
+# serving.write_prefill (donate=[0]); the jaxplan donation gate pins it
+@functools.partial(jax.jit, donate_argnums=(0,))
+def write_prefill_scatter(pools, dense_cache, block_ids, batch_index):
+    """Scatter row `batch_index` of a dense prefill cache (L-tuple of
+    (k, v) [B, H, S, D]) into `pools` (L-tuple of (k, v) [num_blocks,
+    block_size, H, D]) at `block_ids`, for every layer in one program.
+
+    Nothing the traffic varies is in the program's key: the WHOLE dense
+    row is cut into ceil(S / block_size) blocks (zero-padded past S),
+    `block_ids` always has that many entries — the sequence's table
+    padded with the out-of-range id num_blocks, whose updates the
+    scatter drops, the frozen-row idiom of the decode scan — and
+    `batch_index` is a traced scalar. The pools are donated (the caller
+    rebinds them from the return value), so the scatter is in place;
+    the dense cache is NOT: batched callers read it again."""
+    n_slots = block_ids.shape[0]
+
+    def scatter(pool, dense):
+        nb, bs, h, d = pool.shape
+        # [B, H, S, D] -> [H, S, D] -> [S, H, D] -> [n_slots, bs, H * D]
+        row = jax.lax.dynamic_index_in_dim(dense, batch_index, 0,
+                                           keepdims=False)
+        blk = row.transpose(1, 0, 2)
+        blk = jnp.pad(blk, ((0, n_slots * bs - blk.shape[0]),
+                            (0, 0), (0, 0)))
+        blk = blk.reshape(n_slots, bs, h * d)
+        # scattered as [nb, bs, H * D] rows: a v5e holds an f32 pool
+        # with the block id as its MINOR dimension (head_dim 64 is under
+        # the 128 lanes), so XLA relays the whole pool out around the
+        # scatter and back; with H * D minor that copy is not padded to
+        # 128 lanes (22 ms against 32 ms a prefill at 24 layers x 512
+        # blocks, PERF.md section 6, PR 28). The same values either way.
+        flat = pool.reshape(nb, bs, h * d)
+        return flat.at[block_ids].set(blk, mode="drop").reshape(pool.shape)
+
+    return tuple((scatter(kp, kc), scatter(vp, vc))
+                 for (kp, vp), (kc, vc) in zip(pools, dense_cache))
 
 
 class CacheExhausted(RuntimeError):
@@ -1136,28 +1181,30 @@ class PagedKVCache:
                       batch_index: int = 0):
         """Scatter one sequence's dense prefill cache (the L-tuple of
         (k [B, H, S, D], v) from models.generation.prefill) into its
-        allocated blocks. Positions past num_tokens inside the last
-        block stay zero (prefill zero-fills past the prompt), matching
-        a fresh pool block bit-for-bit. Must only run on PRIVATE tables
-        (dense admission never attaches shared blocks — any prefix hit
-        is admitted through the chunked path, which writes only the
-        uncached suffix positions)."""
+        allocated blocks: one dispatch of `write_prefill_scatter` for
+        all layers, whatever the prompt length. Positions past
+        num_tokens inside the last block stay zero (prefill zero-fills
+        past the prompt), matching a fresh pool block bit-for-bit. Must
+        only run on PRIVATE tables (dense admission never attaches
+        shared blocks — any prefix hit is admitted through the chunked
+        path, which writes only the uncached suffix positions)."""
         ids = self._tables[seq_id]
-        n_blocks, bs = len(ids), self.block_size
-        t_pad = n_blocks * bs
-
-        def scatter(pool, dense):
-            # [H, S, D] -> [S, H, D] -> [n_blocks, bs, H, D]
-            blk = dense[batch_index].transpose(1, 0, 2)[:t_pad]
-            blk = blk.reshape(n_blocks, bs, self.num_heads, self.head_dim)
-            return pool.at[idx].set(blk)
-
+        batch, _, seq, _ = dense_cache[0][0].shape
+        n_slots = self.blocks_needed(seq)
+        if len(ids) > n_slots:
+            raise ValueError(
+                f"sequence {seq_id!r} holds {len(ids)} blocks but a dense "
+                f"cache of {seq} positions fills at most {n_slots}")
+        if not 0 <= batch_index < batch:
+            raise IndexError(
+                f"batch_index {batch_index} out of range for a dense "
+                f"cache of batch {batch}")
         with obs.span("serving.prefill.write_cache", cat="prefill",
-                      args={"blocks": n_blocks}):
-            idx = jnp.asarray(ids, jnp.int32)
-            self.pools = tuple(
-                (scatter(kp, kc), scatter(vp, vc))
-                for (kp, vp), (kc, vc) in zip(self.pools, dense_cache))
+                      args={"blocks": len(ids)}):
+            padded = np.full((n_slots,), self.num_blocks, np.int32)
+            padded[:len(ids)] = ids
+            self.pools = write_prefill_scatter(
+                self.pools, dense_cache, padded, np.int32(batch_index))
 
     def prefix_stats(self) -> dict:
         """Prefix-cache telemetry snapshot (engine gauges + load suite

@@ -13,6 +13,7 @@ import json
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
@@ -107,6 +108,171 @@ def test_write_prefill_roundtrips_dense_cache():
                 got = np.asarray(gather_block_kv(pc.pools[i][j], table))
                 want = np.asarray(dense[i][j][b])[:, :got.shape[2]]
                 np.testing.assert_array_equal(got[0], want)
+
+
+def _eager_write_prefill(pc, seq_id, dense_cache, batch_index=0):
+    """The plain reference `PagedKVCache.write_prefill` is held to: the
+    per-layer eager loop it was before it became one jitted, donated
+    program (`write_prefill_scatter`). The one addition is the zero pad
+    for a table whose last block reaches past a max_seq that is no
+    multiple of block_size, where the loop's reshape raised."""
+    ids = pc.block_table(seq_id)
+    n_blocks, bs = len(ids), pc.block_size
+    t_pad = n_blocks * bs
+    idx = jnp.asarray(ids, jnp.int32)
+
+    def scatter(pool, dense):
+        # [H, S, D] -> [S, H, D] -> [n_blocks, bs, H, D]
+        blk = dense[batch_index].transpose(1, 0, 2)[:t_pad]
+        blk = jnp.pad(blk, ((0, t_pad - blk.shape[0]), (0, 0), (0, 0)))
+        blk = blk.reshape(n_blocks, bs, pc.num_heads, pc.head_dim)
+        return pool.at[idx].set(blk)
+
+    pc.pools = tuple(
+        (scatter(kp, kc), scatter(vp, vc))
+        for (kp, vp), (kc, vc) in zip(pc.pools, dense_cache))
+
+
+_WP_L, _WP_H, _WP_D, _WP_BS, _WP_NB = 2, 4, 8, 4, 16
+
+
+def _wp_dense(rng, num_tokens, max_seq, batch=2):
+    """A dense prefill cache as generation.prefill leaves it: values at
+    the prompt's positions, zeros past them."""
+    def leaf():
+        a = rng.standard_normal(
+            (batch, _WP_H, max_seq, _WP_D)).astype(np.float32)
+        a[:, :, num_tokens:] = 0.0
+        return jnp.asarray(a)
+    return tuple((leaf(), leaf()) for _ in range(_WP_L))
+
+
+def _wp_cache(fill_seed=None, **kw):
+    """A cache whose free list hands out blocks that are NOT contiguous
+    (three neighbours allocated, the outer two freed again) and, with
+    `fill_seed`, whose pools start as noise so that an untouched block
+    is told from a zeroed one."""
+    pc = PagedKVCache(_WP_L, _WP_H, _WP_D, num_blocks=_WP_NB,
+                      block_size=_WP_BS, **kw)
+    if fill_seed is not None:
+        rng = np.random.default_rng(fill_seed)
+        shape = (_WP_NB, _WP_BS, _WP_H, _WP_D)
+        pc.pools = tuple(
+            (jnp.asarray(rng.standard_normal(shape), jnp.float32),
+             jnp.asarray(rng.standard_normal(shape), jnp.float32))
+            for _ in range(_WP_L))
+    for sid, n in (("a", 1), ("keep", 2), ("c", 1)):
+        pc.allocate(sid, n * _WP_BS)
+    pc.free("a")
+    pc.free("c")
+    return pc
+
+
+def _pools_np(pc):
+    return [np.asarray(p) for kv in pc.pools for p in kv]
+
+
+@pytest.mark.parametrize("max_seq", [24, 22])
+@pytest.mark.parametrize("length", ["1", "bs-1", "bs", "bs+1", "max_seq"])
+def test_write_prefill_equals_eager_reference_bitwise(length, max_seq):
+    """Every pool, bit for bit, equals what the eager loop writes: for
+    both rows of one shared dense cache, on tables that are not
+    contiguous, with other sequences' blocks and the free blocks left
+    as they were — also where max_seq is no multiple of block_size."""
+    T = {"1": 1, "bs-1": _WP_BS - 1, "bs": _WP_BS, "bs+1": _WP_BS + 1,
+         "max_seq": max_seq}[length]
+    dense = _wp_dense(np.random.default_rng(T), T, max_seq)
+    got, want = _wp_cache(fill_seed=3), _wp_cache(fill_seed=3)
+    before = _pools_np(got)
+    for b, sid in enumerate(("s0", "s1")):
+        for pc in (got, want):
+            pc.allocate(sid, T)
+        assert got.block_table(sid) == want.block_table(sid)
+        got.write_prefill(sid, dense, T, batch_index=b)
+        _eager_write_prefill(want, sid, dense, batch_index=b)
+    t0 = got.block_table("s0")
+    assert len(t0) < 2 or np.any(np.diff(t0) != 1), t0   # not contiguous
+    for g, w in zip(_pools_np(got), _pools_np(want)):
+        np.testing.assert_array_equal(g, w)
+    written = got.block_table("s0") + got.block_table("s1")
+    others = sorted(set(range(_WP_NB)) - set(written))
+    assert set(got.block_table("keep")) <= set(others)
+    for g, was in zip(_pools_np(got), before):
+        np.testing.assert_array_equal(g[others], was[others])
+    # the shared dense cache is NOT donated: batched callers read it on
+    for kc, vc in dense:
+        assert not kc.is_deleted() and not vc.is_deleted()
+
+
+def test_write_prefill_compiles_once_for_every_length_and_block_count():
+    """One program per pool geometry and dense-cache shape: after one
+    warm call, other prompt lengths, block counts and batch rows compile
+    nothing (counted as benchmarks/lib/clock.py counts compilations in
+    the window, through jax.monitoring)."""
+    from jax import monitoring
+    compiles = []
+
+    def on_duration(name, secs, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(name)
+
+    max_seq = 24
+    rng = np.random.default_rng(0)
+    lengths = (1, _WP_BS - 1, _WP_BS, _WP_BS + 1, 13, max_seq)
+    denses = {T: _wp_dense(rng, T, max_seq) for T in lengths}
+    pc = _wp_cache()
+    pc.allocate("warm", 9)
+    pc.write_prefill("warm", denses[_WP_BS], 9)
+    pc.free("warm")
+    monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        for i, T in enumerate(lengths):
+            pc.allocate(T, T)
+            pc.write_prefill(T, denses[T], T, batch_index=i % 2)
+            pc.free(T)
+        jax.block_until_ready(pc.pools)
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+    assert compiles == []
+
+
+def test_write_prefill_int8_pools_equal_eager_reference():
+    """The same round trip through the `pools` property of int8 pools:
+    codes, scales and the dequantised view equal the eager loop's."""
+    T, max_seq = 9, 24
+    dense = _wp_dense(np.random.default_rng(5), T, max_seq)
+    got = _wp_cache(kv_cache_dtype="int8")
+    want = _wp_cache(kv_cache_dtype="int8")
+    for b, sid in enumerate(("s0", "s1")):
+        for pc in (got, want):
+            pc.allocate(sid, T)
+        got.write_prefill(sid, dense, T, batch_index=b)
+        _eager_write_prefill(want, sid, dense, batch_index=b)
+    for g, w in zip(_pools_np(got), _pools_np(want)):
+        np.testing.assert_array_equal(g, w)
+    for gl, wl in zip(got._qpools + got._scales, want._qpools + want._scales):
+        for g, w in zip(gl, wl):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # and it is the dense row that came back, to the codec's resolution
+    table = jnp.asarray([got.block_table("s1")], jnp.int32)
+    back = np.asarray(gather_block_kv(got.pools[0][0], table))[0]
+    np.testing.assert_allclose(back[:, :T], np.asarray(dense[0][0][1])[:, :T],
+                               atol=0.05)
+
+
+def test_write_prefill_donates_the_pools_and_rejects_bad_arguments():
+    pc = _wp_cache()
+    dense = _wp_dense(np.random.default_rng(1), 5, 24)
+    pc.allocate("s", 5)
+    old = pc.pools[0][0]
+    pc.write_prefill("s", dense, 5)
+    assert old.is_deleted() and not pc.pools[0][0].is_deleted()
+    with pytest.raises(IndexError):
+        pc.write_prefill("s", dense, 5, batch_index=2)
+    short = _wp_dense(np.random.default_rng(1), 4, 4)
+    with pytest.raises(ValueError, match="at most 1"):
+        pc.write_prefill("s", short, 4)
+    assert not pc.pools[0][0].is_deleted()   # a refused call donates nothing
 
 
 # ------------------------------------------------- bitwise decode parity

@@ -4,7 +4,8 @@ The kernels of the two main paths, compiled at the real widths of
 chip_smoke.py for a described (not attached) v5e:2x2: the flash forward
 and backward of the 6-head flagship, the packed-pair kernels of the
 12-head one at both backward branches, the ragged decode kernel at both
-head geometries, and the flash kernel per shard under a 2x2 mesh. Interpret
+head geometries, the flash kernel per shard under a 2x2 mesh, and the
+serving cell's dense-admission scatter with its pools donated. Interpret
 mode accepts what Mosaic refuses (an unaligned slice, a batched dot with no
 free lhs dim, too much VMEM); these compiles do not. Nothing runs, so they
 say nothing about results or times.
@@ -175,6 +176,29 @@ def test_ragged_decode_compiles(one_chip, heads, head_dim):
     assert _kernel_calls(
         ragged_decode_attention, sds((8, heads, head_dim), jnp.float32),
         pool, pool, sds((8, 32), jnp.int32), sds((8,), jnp.int32)) == 1
+
+
+def test_write_prefill_scatter_updates_the_pools_in_place(one_chip):
+    """The dense-admission scatter at the serving cell's geometry (two
+    layers of it): one XLA module under its own name, every pool aliased
+    to its output (the donation took) and no more than one pool of
+    temporaries (the relayout a v5e makes around each scatter, one pool
+    at a time)."""
+    from paddle_tpu.inference.serving.paged_cache import \
+        write_prefill_scatter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    layers, pool_bytes = 2, 512 * 32 * 16 * 64 * 4
+    pool = sds((512, 32, 16, 64), jnp.float32)
+    dense = sds((1, 16, 1024, 64), jnp.float32)
+    compiled = write_prefill_scatter.lower(
+        ((pool, pool),) * layers, ((dense, dense),) * layers,
+        sds((32,), jnp.int32), sds((), jnp.int32)).compile()
+    assert "HloModule jit_write_prefill_scatter" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * layers * pool_bytes
+    assert mem.temp_size_in_bytes < 1.25 * pool_bytes
 
 
 def test_flash_compiles_per_shard_under_a_mesh(topo):
