@@ -43,7 +43,7 @@ from ..parallel.api import mark_sharding
 from ..parallel import mesh as _mesh
 from ..ops import manipulation as M
 
-__all__ = ["MoEMLP", "moe_dispatch_combine"]
+__all__ = ["MoEMLP", "moe_dispatch_combine", "held_experts_mlp"]
 
 
 def _ep_constraint(x):
@@ -144,6 +144,59 @@ def _moe_mlp(x, wr, wu, bu, wd, bd, top_k, capacity_factor, min_capacity):
     out_e = _ep_constraint(out_e)             # <- ep->dp all-to-all here
     out = jnp.einsum("sec,ech->sh", combine.astype(x.dtype), out_e)
     return out.reshape(B, T, H), aux.astype(jnp.float32)
+
+
+def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
+                     scale, live=None):
+    """The dropless expert layer of ONE chip of an expert-parallel
+    deployment: x [T, h] -> (routed part [T, h] float32, counts int32 [3]).
+
+    The router is whole: `router_w` [h, E] scores every token over all E
+    experts in float32 (sigmoid), the `top_k` largest are chosen and their
+    scores normalised to sum to `scale`. This chip holds the experts
+    `first .. first + count - 1` (`held = (first, count)`, static;
+    `w_gate`, `w_up` [count, h, f] and `w_down` [count, f, h] are theirs)
+    and computes exactly the (token, held expert) pairs: the T * top_k
+    pairs are sorted by held expert (pairs of experts held elsewhere and of
+    rows that `live` [T] switches off sort behind the last group),
+    `jax.lax.ragged_dot` multiplies group by group, and each token sums
+    its weighted rows back. No capacity, so no token is dropped at any
+    imbalance; a token none of whose experts is held gets zeros. What the
+    absent experts would add is another chip's, and nothing here stands in
+    for them or for the exchange. Static shapes: the pair buffer always
+    has T * top_k rows (a token may send all its picks here); rows behind
+    the last group belong to no group and are not multiplied.
+
+    counts = (pairs multiplied, held experts with at least one token, most
+    tokens one held expert got): what the tracing reads, at no extra
+    fetch."""
+    first, count = held
+    tokens = x.shape[0]
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router_w,
+                                    precision="highest"))
+    top_s, top_i = jax.lax.top_k(scores, top_k)
+    weight = top_s / jnp.sum(top_s, axis=-1, keepdims=True) \
+        * jnp.float32(scale)
+    local = top_i.astype(jnp.int32) - first
+    mine = (local >= 0) & (local < count)
+    if live is not None:
+        mine = mine & live[:, None]
+    group = jnp.where(mine, local, count).reshape(-1)   # count = not here
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
+    rows = x[order // top_k]                            # [T * top_k, h]
+    act = jax.nn.silu(jax.lax.ragged_dot(
+        rows, w_gate, sizes, preferred_element_type=jnp.float32)) \
+        * jax.lax.ragged_dot(rows, w_up, sizes,
+                             preferred_element_type=jnp.float32)
+    out = jax.lax.ragged_dot(act.astype(x.dtype), w_down, sizes,
+                             preferred_element_type=jnp.float32)
+    here = (jnp.arange(tokens * top_k) < jnp.sum(sizes))[:, None]
+    out = jnp.where(here, out * weight.reshape(-1)[order][:, None], 0.0)
+    routed = out[jnp.argsort(order)].reshape(tokens, top_k, -1).sum(axis=1)
+    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
+                        jnp.max(sizes)]).astype(jnp.int32)
+    return routed, counts
 
 
 class MoEMLP(Layer):

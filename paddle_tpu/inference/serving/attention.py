@@ -48,12 +48,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from ...core.anomaly import rows_not_finite
+from ...models import generation as _gen
 from ...models.generation import (_attn_merge, _decode_attn, _decode_head,
                                   _decode_qkv, _token_embed)
+from ...models.spec import ModelSpec, merge_counts
 from ...ops.pallas import ragged_paged_attention as _ragged
 
 __all__ = ["gather_block_kv", "paged_decode_step", "fused_decode_chunk",
-           "PACK_COLS", "pack_f32"]
+           "PACK_COLS", "pack_f32", "gpt2_spec", "as_spec"]
 
 
 def gather_block_kv(pool, block_tables):
@@ -79,39 +81,98 @@ def _pool_write_gather(kp, vp, k_new, v_new, slot_blocks, slot_offsets,
             gather_block_kv(vp, block_tables))
 
 
+@jax.jit
+def _pool_write(kp, vp, k_new, v_new, slot_blocks, slot_offsets):
+    """Scatter-only variant of _pool_write_gather for the ragged kernel
+    path: the kernel reads the pools through the block table itself, so
+    no gathered context is materialised."""
+    kp = kp.at[slot_blocks, slot_offsets].set(k_new[:, :, 0], mode="drop")
+    vp = vp.at[slot_blocks, slot_offsets].set(v_new[:, :, 0], mode="drop")
+    return kp, vp
+
+
+@functools.lru_cache(maxsize=None)
+def gpt2_spec(geom) -> ModelSpec:
+    """models/generation.py as a ModelSpec, the first one: geom is its
+    static geometry (num_layers, num_heads, head_dim, max_seq_len). The
+    functions are the shared jitted sub-programs of generation.decode_step
+    themselves, in the order the decode programs always called them, so
+    the spec changes no program (tests/test_serving_spec.py compares the
+    lowered text's shape with the pre-seam form)."""
+    num_layers, num_heads, head_dim, max_seq = geom
+
+    def decode_layer(params, i, x, pool, slot_blocks, slot_offsets, tables,
+                     positions, att_lens, live, ragged):
+        kp, vp = pool
+        qkv = _decode_qkv(params, i, x, geom)     # [3, N, H, 1, D]
+        if ragged and _ragged.route_gate(head_dim, num_heads, kp.shape[1]):
+            kp, vp = _pool_write(
+                kp, vp, qkv[1], qkv[2], slot_blocks, slot_offsets)
+            att = _ragged.ragged_decode_attention(
+                qkv[0][:, :, 0, :], kp, vp, tables, att_lens)
+            x = _attn_merge(params, i, x, att[:, :, None, :], geom)
+        else:
+            kp, vp, kc, vc = _pool_write_gather(
+                kp, vp, qkv[1], qkv[2], slot_blocks, slot_offsets, tables)
+            x = _decode_attn(params, i, x, qkv[0], kc, vc, positions, geom)
+        return x, (kp, vp), None
+
+    return ModelSpec(
+        family="gpt2", num_layers=num_layers, max_seq_len=max_seq,
+        cache_layout="heads", cache_shape=(num_heads, head_dim),
+        cache_dtype="float32", embed=_token_embed,
+        decode_layer=decode_layer, head=_decode_head,
+        prefill=lambda params, ids: _gen.prefill(params, ids, geom)
+        + (None,),
+        config=geom)
+
+
+def as_spec(geom) -> ModelSpec:
+    """A ModelSpec as it is; the (L, H, D, S) tuple of models/generation.py
+    names that family's spec (`gpt2_spec`), so every caller that holds a
+    GPT geometry keeps working."""
+    return geom if isinstance(geom, ModelSpec) else gpt2_spec(tuple(geom))
+
+
+def _pool_geometry(pools):
+    """(num_blocks, block_size) of either cache layout."""
+    return jax.tree_util.tree_leaves(pools)[0].shape[:2]
+
+
 def paged_decode_step(params, pools, tokens, positions, block_tables,
                       slot_blocks, slot_offsets, geom):
     """One ragged decode step over the block pool.
 
     params: the models.generation.extract_params dict.
-    pools: L-tuple of (k_pool, v_pool) [num_blocks, bs, H, D].
+    pools: L-tuple of the spec's per-layer leaf: (k_pool, v_pool)
+        [num_blocks, bs, H, D], or one latent pool [num_blocks, bs, W].
     tokens [N] int32 — last sampled token per sequence.
     positions [N] int32 — cached length per sequence (the new token's
         position); padded rows use 0.
     block_tables [N, MB] int32 — block ids padded with 0.
     slot_blocks/slot_offsets [N] int32 — write slot for the new token's
-        K/V; padded rows point slot_blocks out of bounds (num_blocks) so
-        the scatter drops them.
-    geom: static (num_layers, num_heads, head_dim, max_seq_len), the
-        models.generation geometry tuple.
+        cache row; padded rows point slot_blocks out of bounds
+        (num_blocks) so the scatter drops them.
+    geom: static ModelSpec, or the (num_layers, num_heads, head_dim,
+        max_seq_len) tuple of models.generation (`as_spec`).
 
-    Returns (logits [N, V], updated pools). Composed of the shared
-    jitted sub-programs of generation.decode_step plus the pool
-    scatter/gather above — see the parity contract in the module
-    docstring.
+    Returns (logits [N, V], updated pools). Composed of the spec's
+    functions — for GPT-2 the shared jitted sub-programs of
+    generation.decode_step plus the pool scatter/gather above, see the
+    parity contract in the module docstring.
     """
+    spec = as_spec(geom)
     tokens = jnp.asarray(tokens, jnp.int32)
     positions = jnp.asarray(positions, jnp.int32)
-    x = _token_embed(params, tokens, positions)   # [N, 1, C]
+    x = spec.embed(params, tokens, positions)     # [N, 1, C]
+    live = jnp.ones(tokens.shape, bool)
     new_pools = []
-    for i, (kp, vp) in enumerate(pools):
-        qkv = _decode_qkv(params, i, x, geom)     # [3, N, H, 1, D]
-        kp, vp, kc, vc = _pool_write_gather(
-            kp, vp, qkv[1], qkv[2], slot_blocks, slot_offsets,
-            block_tables)
-        new_pools.append((kp, vp))
-        x = _decode_attn(params, i, x, qkv[0], kc, vc, positions, geom)
-    return _decode_head(params, x), tuple(new_pools)
+    for i, pool in enumerate(pools):
+        x, pool, _ = spec.decode_layer(
+            params, i, x, pool, slot_blocks, slot_offsets, block_tables,
+            positions, positions + 1, live, False)
+        new_pools.append(pool)
+    return spec.head(params, x), tuple(new_pools)
 
 
 # ----------------------------------- fused k-token decode + prefill chunks
@@ -175,16 +236,6 @@ def _sample_rows(logits, keys, temps, top_ks, top_ps):
     return jnp.where(temps <= 0.0, greedy, sampled)
 
 
-@jax.jit
-def _pool_write(kp, vp, k_new, v_new, slot_blocks, slot_offsets):
-    """Scatter-only variant of _pool_write_gather for the ragged kernel
-    path: the kernel reads the pools through the block table itself, so
-    no gathered context is materialised."""
-    kp = kp.at[slot_blocks, slot_offsets].set(k_new[:, :, 0], mode="drop")
-    vp = vp.at[slot_blocks, slot_offsets].set(v_new[:, :, 0], mode="drop")
-    return kp, vp
-
-
 # ptlint: disable=PT-T009  agrees with the committed plan entry
 # serving.decode_chunk (donate=[1]); the jaxplan donation gate pins it
 @functools.partial(jax.jit, static_argnums=(3, 4, 5), donate_argnums=(1,))
@@ -201,6 +252,17 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
         row  k+1      per-row not-finite flag, latched at the FIRST bad
                       step — the engine's anomaly attribution, computed
                       in-scan so quarantine needs no extra fetch
+        rows k+2..    one per name in the spec's `counters` (none for
+                      GPT-2): the count, repeated across the row. For the
+                      expert family: (token, held expert) pairs multiplied
+                      in the chunk, held experts hit summed over trips and
+                      layers, the largest load of one expert in one trip
+
+    `geom` (static) is the family's ModelSpec, or the (L, H, D, S) tuple
+    of models/generation.py, which names the GPT-2 spec: embedding, each
+    layer (cache write, attention through the block table, MLP) and the
+    head are the spec's; the carry, prompt feed, sampling, termination,
+    upload and fetch are this function's and the same for every family.
 
     Chunked prefill: rows with pf_feed > 0 spend their first pf_feed
     trips consuming prompt tokens from the feed columns — KV is written
@@ -233,13 +295,12 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
     the scan and the input buffers alias the output on TPU, so the k
     cache writes cost no extra copies of the pool.
 
-    Returns (out [k+2, N] int32, updated pools).
+    Returns (out [k+2+len(counters), N] int32, updated pools).
     """
-    num_layers, num_heads, head_dim, max_seq = geom
+    spec = as_spec(geom)
     tables = packed[:, PACK_COLS + k:]
     feed = packed[:, PACK_COLS:PACK_COLS + k].T      # [k, N] prompt feed
-    num_blocks = pools[0][0].shape[0]
-    block_size = pools[0][0].shape[1]
+    num_blocks, block_size = _pool_geometry(pools)
     n = packed.shape[0]
     active = packed[:, 2] > 0
     max_out = packed[:, 4]
@@ -249,11 +310,10 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
     top_ps = lax.bitcast_convert_type(packed[:, 8], jnp.float32)
     base_keys = jax.vmap(jax.random.PRNGKey)(packed[:, 9])
     pf_more = packed[:, 11] > 0
-    use_ragged = (kernel == "ragged"
-                  and _ragged.route_gate(head_dim, num_heads, block_size))
+    ragged = kernel == "ragged"
 
     def body(carry, feed_j):
-        pools, tok, pos, out_cnt, finished, bad, pf_left = carry
+        pools, tok, pos, out_cnt, finished, bad, pf_left, counts = carry
         run = active & ~finished & ~bad
         prefilling = run & (pf_left > 0)
         last_pf = prefilling & (pf_left == 1) & ~pf_more
@@ -265,24 +325,17 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
             jnp.take_along_axis(tables, blk_idx[:, None], axis=1)[:, 0],
             num_blocks)                      # frozen rows: scatter drops
         slot_offsets = pos % block_size
-        x = _token_embed(params, tok_in, pos)
+        x = spec.embed(params, tok_in, pos)
         att_lens = jnp.where(run, pos + 1, 0).astype(jnp.int32)
         new_pools = []
-        for i, (kp, vp) in enumerate(pools):
-            qkv = _decode_qkv(params, i, x, geom)
-            if use_ragged:
-                kp, vp = _pool_write(
-                    kp, vp, qkv[1], qkv[2], slot_blocks, slot_offsets)
-                att = _ragged.ragged_decode_attention(
-                    qkv[0][:, :, 0, :], kp, vp, tables, att_lens)
-                x = _attn_merge(params, i, x, att[:, :, None, :], geom)
-            else:
-                kp, vp, kc, vc = _pool_write_gather(
-                    kp, vp, qkv[1], qkv[2], slot_blocks, slot_offsets,
-                    tables)
-                x = _decode_attn(params, i, x, qkv[0], kc, vc, pos, geom)
-            new_pools.append((kp, vp))
-        logits = _decode_head(params, x)
+        for i, pool in enumerate(pools):
+            x, pool, layer_counts = spec.decode_layer(
+                params, i, x, pool, slot_blocks, slot_offsets, tables,
+                pos, att_lens, run, ragged)
+            new_pools.append(pool)
+            if spec.counters:
+                counts = merge_counts(counts, layer_counts)
+        logits = spec.head(params, x)
         row_bad = rows_not_finite(logits) & run
         bad = bad | row_bad
         keys = jax.vmap(jax.random.fold_in)(base_keys, out_cnt)
@@ -297,15 +350,19 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
         out_cnt = jnp.where(step_ok, out_cnt + 1, out_cnt)
         pf_left = jnp.where(ok & prefilling, pf_left - 1, pf_left)
         return (tuple(new_pools), tok, pos, out_cnt, finished, bad,
-                pf_left), emit
+                pf_left, counts), emit
 
     carry0 = (pools, packed[:, 0], packed[:, 1], packed[:, 3],
               jnp.zeros((n,), bool), jnp.zeros((n,), bool),
-              packed[:, 10])
-    (pools, _, _, _, finished, bad, _), toks = lax.scan(
+              packed[:, 10],
+              jnp.zeros((len(spec.counters),), jnp.int32)
+              if spec.counters else ())
+    (pools, _, _, _, finished, bad, _, counts), toks = lax.scan(
         body, carry0, feed, length=k)
     out = jnp.concatenate(
         [toks.astype(jnp.int32),
          finished[None].astype(jnp.int32),
-         bad[None].astype(jnp.int32)], axis=0)
+         bad[None].astype(jnp.int32)]
+        + [jnp.broadcast_to(counts[j], (1, n))
+           for j in range(len(spec.counters))], axis=0)
     return out, pools

@@ -77,7 +77,7 @@ from ...analysis import holds_lock
 from ...core import anomaly
 from ...models import generation as gen
 from ...profiler import RecordEvent
-from .attention import PACK_COLS, fused_decode_chunk, pack_f32
+from .attention import PACK_COLS, as_spec, fused_decode_chunk, pack_f32
 from .paged_cache import CacheExhausted, PagedKVCache
 from .scheduler import (EngineOverloaded, Request, RequestState,
                         SamplingParams, ScheduledBatch, Scheduler,
@@ -192,7 +192,12 @@ class RequestOutput:
 _STAT_EVENTS = ("steps", "prefill_tokens", "generated_tokens",
                 "preemptions", "completed", "cancelled", "expired",
                 "timeouts", "shed", "errors", "recoveries", "rebuilt",
-                "watchdog_trips", "rejected")
+                "watchdog_trips", "rejected",
+                # expert families (ModelSpec.counters): (token, held
+                # expert) pairs multiplied, held experts hit per trip/layer
+                "moe_pairs", "moe_experts_hit",
+                # the `context_tokens` stat of serving.decode, summed
+                "context_tokens")
 # float phase-time accumulators (serving_phase_seconds_total{engine,phase})
 _STAT_PHASES = {"time_schedule": "schedule", "time_prefill": "prefill",
                 "time_decode": "decode"}
@@ -301,6 +306,11 @@ class EngineStats:
             "fallback; 0 under the ragged kernel, whose per-row length "
             "gating makes dead rows cost zero kernel work",
             labels=("engine",)).labels(**lbl)
+        self._g_cache_bytes_per_token = obs.gauge(
+            "serving_cache_bytes_per_token",
+            "bytes of cache one position costs over all layers (the "
+            "model spec's layout and dtype)",
+            labels=("engine",), unit="bytes").labels(**lbl)
         self._g_running = g_run.labels(**lbl)
         self._g_waiting = g_wait.labels(**lbl)
         self._g_blocks_used = g_blk.labels(state="used", **lbl)
@@ -392,6 +402,13 @@ class EngineStats:
         self._g_waiting.set(waiting)
         self._g_blocks_used.set(blocks_used)
         self._g_blocks_free.set(blocks_free)
+
+    @property
+    def cache_bytes_per_token(self) -> int:
+        return int(self._g_cache_bytes_per_token.value)
+
+    def set_cache_bytes_per_token(self, n: int) -> None:
+        self._g_cache_bytes_per_token.set(n)
 
     def set_prefill_spend(self, tokens: int) -> None:
         self._g_prefill_spend.set(tokens)
@@ -563,8 +580,11 @@ def _bucket(n: int, cap: int) -> int:
 
 
 class LLMEngine:
-    """Continuous-batching engine over (params, geom) — the pure-JAX
-    decode substrate of models.generation, served paged.
+    """Continuous-batching engine over (params, spec): a parameter dict
+    and the `models.spec.ModelSpec` of its family, served paged. `geom`
+    may also be the (L, H, D, S) tuple of models.generation, which names
+    the GPT-2 spec (`attention.as_spec`). The layout and dtype of the
+    cache are the spec's, not the config's.
 
     Thread contract (checked by ptlint PT-C001 via _GUARDED_BY): the
     fields below are shared between the serving loop (step/run) and
@@ -588,7 +608,8 @@ class LLMEngine:
     def __init__(self, params, geom, config: EngineConfig = None,
                  faults=None):
         config = config or EngineConfig()
-        L, H, D, S = geom
+        spec = as_spec(geom)
+        S = spec.max_seq_len
         if S % config.block_size != 0:
             # divisibility keeps the gathered context bitwise-identical
             # to the dense cache layout (and write_prefill rectangular)
@@ -600,15 +621,20 @@ class LLMEngine:
                 f"decode_chunk_size must be >= 1, got "
                 f"{config.decode_chunk_size}")
         self.params = params
-        self.geom = geom
+        self.geom = geom                  # as given: what the programs key on
+        self.spec = spec
         self.config = config
         self.max_blocks_per_seq = S // config.block_size
+        latent = spec.cache_layout == "latent"
+        heads, head_dim = (0, 0) if latent else spec.cache_shape
         self.cache = PagedKVCache(
-            L, H, D, config.num_blocks, config.block_size,
+            spec.num_layers, heads, head_dim, config.num_blocks,
+            config.block_size, dtype=jnp.dtype(spec.cache_dtype),
             enable_prefix_cache=config.enable_prefix_cache,
             host_tier_blocks=config.host_tier_blocks,
             promote_timeout_s=config.promote_timeout_s,
-            kv_cache_dtype=config.kv_cache_dtype)
+            kv_cache_dtype=config.kv_cache_dtype,
+            latent_width=spec.cache_shape[0] if latent else None)
         cost_model = config.prefill_cost_model
         if cost_model == "auto":
             # committed-plan admission pricing; a repo without a plan
@@ -631,6 +657,7 @@ class LLMEngine:
         # helpers it calls re-enter (e.g. _emit under _recover)
         self._lock = threading.RLock()
         self.stats = EngineStats(config.obs_label)
+        self.stats.set_cache_bytes_per_token(spec.cache_bytes_per_token)
         # (model, revision) event tag (serving/deploy.py): emission and
         # terminal events carry the serving revision so the causality
         # checker can prove no token was emitted by a revision other
@@ -659,9 +686,12 @@ class LLMEngine:
 
     @classmethod
     def from_model(cls, model, config: EngineConfig = None, faults=None):
-        cfg = model.cfg
-        geom = (cfg.num_layers, cfg.num_heads,
-                cfg.hidden_size // cfg.num_heads, cfg.max_seq_len)
+        if hasattr(model, "serving_spec"):
+            geom = model.serving_spec()
+        else:
+            cfg = model.cfg
+            geom = (cfg.num_layers, cfg.num_heads,
+                    cfg.hidden_size // cfg.num_heads, cfg.max_seq_len)
         return cls(gen.extract_params(model), geom, config, faults=faults)
 
     # ------------------------------------------------------------ intake
@@ -697,7 +727,7 @@ class LLMEngine:
         ids = np.asarray(prompt_ids, np.int32).reshape(-1)
         if ids.size == 0:
             raise ValueError("empty prompt")
-        S = self.geom[3]
+        S = self.spec.max_seq_len
         if ids.size + sampling.max_tokens > S:
             raise ValueError(
                 f"prompt {ids.size} + max_tokens {sampling.max_tokens} "
@@ -1334,12 +1364,14 @@ class LLMEngine:
                 tokens = req.all_token_ids()
                 with RecordEvent("serving.prefill", cat="prefill",
                                  args={"request_id": req.request_id,
-                                       "tokens": int(tokens.size)}):
+                                       "tokens": int(tokens.size)}) as ev:
                     try:
-                        logits = self._prefill(req, tokens)
+                        logits, counts = self._prefill(req, tokens)
                     except Exception as e:
                         self._quarantine(req, outs, f"prefill raised: {e}")
                         continue
+                    if counts:
+                        ev.set_stats(moe_pairs=counts["moe_pairs"])
                     self.stats.prefill_tokens += int(tokens.size)
                     prefill_spend += int(tokens.size)
                     self.stats.time_prefill += time.perf_counter() - t0
@@ -1370,14 +1402,17 @@ class LLMEngine:
             if decode:
                 t0 = time.perf_counter()
                 k = self.config.decode_chunk_size
+                context = _context_tokens(decode, k)
+                self.stats.context_tokens += context
                 with RecordEvent("serving.decode", cat="decode", args={
                         "num_seqs": len(decode), "chunk": k,
-                        "context_tokens": _context_tokens(decode, k)}):
+                        "context_tokens": context}) as ev:
                     # ptlint: disable=PT-C004  fault injector: stalls ON
                     # PURPOSE under the lock to exercise the watchdog
                     self.faults.stall(step_no)
                     try:
-                        toks, bad = self._decode_chunk(decode, k)
+                        toks, bad, counts = self._decode_chunk(decode, k)
+                        ev.set_stats(**counts)
                     except Exception as e:
                         toks = None
                         self._recover(decode, [decode[0]], outs,
@@ -1470,15 +1505,16 @@ class LLMEngine:
                             **(self._rev_tag or {}))
 
     @holds_lock("_lock")
-    def _prefill(self, req: Request, tokens: np.ndarray) -> np.ndarray:
-        """Dense prefill (shared jitted program with generate()),
-        scattered into the sequence's blocks. One upload (the prompt),
-        one fetch (the last-position logits [V]) — already the minimal
-        host/device traffic for a prompt forward."""
+    def _prefill(self, req: Request, tokens: np.ndarray):
+        """Dense prefill (the spec's; for GPT-2 the jitted program shared
+        with generate()), scattered into the sequence's blocks. One
+        upload (the prompt), one fetch (the last-position logits [V], and
+        with them the spec's counts where it has any) — already the
+        minimal host/device traffic for a prompt forward. Returns
+        (logits, {counter: value})."""
         with RecordEvent("serving.prefill.forward", cat="prefill"):
-            logits, dense_cache = gen.prefill(
-                self.params, jnp.asarray(tokens[None], jnp.int32),
-                self.geom)
+            logits, dense_cache, counts = self.spec.prefill(
+                self.params, jnp.asarray(tokens[None], jnp.int32))
         # span serving.prefill.write_cache is write_prefill's own
         self.cache.write_prefill(req.request_id, dense_cache, tokens.size)
         if self.cache.prefix_index is not None:
@@ -1491,8 +1527,21 @@ class LLMEngine:
             # more device program, queued BEHIND the scatter, so the
             # first token would wait for the scatter too
             out = np.asarray(logits)[0]
+            if counts is not None:
+                counts = np.asarray(counts)
         self.stats.inc_host_sync("prefill")
-        return out
+        return out, self._count(counts)
+
+    @holds_lock("_lock")
+    def _count(self, counts) -> dict:
+        """The spec's counts of one program (a prefill, a chunk) into the
+        engine's counters; returns them by name for the span."""
+        if counts is None:
+            return {}
+        named = {n: int(c) for n, c in zip(self.spec.counters, counts)}
+        self.stats.moe_pairs += named["moe_pairs"]
+        self.stats.moe_experts_hit += named["moe_experts_hit"]
+        return named
 
     @holds_lock("_lock")
     def _decode_chunk(self, reqs: List[Request], k: int):
@@ -1508,7 +1557,7 @@ class LLMEngine:
         their next min(k, remaining-prompt) tokens packed into the feed
         columns and advance prefill_pos iff the chunk came back clean.
         Returns (tokens [k, len(reqs)] int32 with -1 on frozen rows,
-        bad [len(reqs)] bool)."""
+        bad [len(reqs)] bool, the spec's counts of the chunk by name)."""
         ragged = self.config.kernel == "ragged"
         n = self.config.max_num_seqs if ragged \
             else _bucket(len(reqs), self.config.max_num_seqs)
@@ -1571,7 +1620,9 @@ class LLMEngine:
                     self.cache.register_prefix(
                         req.request_id,
                         req.all_token_ids()[:req.prefill_pos])
-        return fetched[:k, :live], bad
+        counts = self._count(fetched[k + 2:, 0]) if self.spec.counters \
+            else {}
+        return fetched[:k, :live], bad, counts
 
     # ------------------------------------------------------- convenience
     def run(self, max_steps: int = None) -> Dict[str, np.ndarray]:

@@ -89,6 +89,8 @@ def write_prefill_scatter(pools, dense_cache, block_ids, batch_index):
     """Scatter row `batch_index` of a dense prefill cache (L-tuple of
     (k, v) [B, H, S, D]) into `pools` (L-tuple of (k, v) [num_blocks,
     block_size, H, D]) at `block_ids`, for every layer in one program.
+    The latent layout is the same program over one pool a layer: dense
+    rows [B, S, W] into [num_blocks, block_size, W], no transpose.
 
     Nothing the traffic varies is in the program's key: the WHOLE dense
     row is cut into ceil(S / block_size) blocks (zero-padded past S),
@@ -101,10 +103,16 @@ def write_prefill_scatter(pools, dense_cache, block_ids, batch_index):
     n_slots = block_ids.shape[0]
 
     def scatter(pool, dense):
-        nb, bs, h, d = pool.shape
-        # [B, H, S, D] -> [H, S, D] -> [S, H, D] -> [n_slots, bs, H * D]
         row = jax.lax.dynamic_index_in_dim(dense, batch_index, 0,
                                            keepdims=False)
+        if pool.ndim == 3:                 # latent: [S, W] rows as they are
+            bs = pool.shape[1]
+            blk = jnp.pad(row, ((0, n_slots * bs - row.shape[0]), (0, 0)))
+            return pool.at[block_ids].set(
+                blk.reshape(n_slots, bs, -1).astype(pool.dtype),
+                mode="drop")
+        nb, bs, h, d = pool.shape
+        # [B, H, S, D] -> [H, S, D] -> [S, H, D] -> [n_slots, bs, H * D]
         blk = row.transpose(1, 0, 2)
         blk = jnp.pad(blk, ((0, n_slots * bs - blk.shape[0]),
                             (0, 0), (0, 0)))
@@ -118,8 +126,7 @@ def write_prefill_scatter(pools, dense_cache, block_ids, batch_index):
         flat = pool.reshape(nb, bs, h * d)
         return flat.at[block_ids].set(blk, mode="drop").reshape(pool.shape)
 
-    return tuple((scatter(kp, kc), scatter(vp, vc))
-                 for (kp, vp), (kc, vc) in zip(pools, dense_cache))
+    return jax.tree_util.tree_map(scatter, pools, dense_cache)
 
 
 class CacheExhausted(RuntimeError):
@@ -162,9 +169,24 @@ class PagedKVCache:
                  enable_prefix_cache: bool = False,
                  host_tier_blocks: int = 0,
                  promote_timeout_s: Optional[float] = None,
-                 kv_cache_dtype: str = "float32"):
+                 kv_cache_dtype: str = "float32",
+                 latent_width: Optional[int] = None):
         if num_blocks <= 0 or block_size <= 0:
             raise ValueError("num_blocks and block_size must be positive")
+        if latent_width is not None:
+            # one pool a layer; what still assumes (k, v) pairs of heads
+            # refuses here, by name, and never falls back
+            for feature, asked in (
+                    ("int8 KV pools (kv_cache_dtype='int8')",
+                     kv_cache_dtype != "float32"),
+                    ("the prefix cache (enable_prefix_cache)",
+                     enable_prefix_cache),
+                    ("the host tier (host_tier_blocks)",
+                     host_tier_blocks > 0)):
+                if asked:
+                    raise NotImplementedError(
+                        f"the latent cache layout does not support "
+                        f"{feature} yet")
         if kv_cache_dtype not in ("float32", "int8"):
             raise ValueError(
                 f"kv_cache_dtype must be 'float32' or 'int8', got "
@@ -175,8 +197,14 @@ class PagedKVCache:
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.kv_cache_dtype = kv_cache_dtype
+        self.latent_width = latent_width
         shape = (num_blocks, block_size, num_heads, head_dim)
-        if kv_cache_dtype == "int8":
+        if latent_width is not None:
+            self._qpools = None
+            self._pools = tuple(
+                jnp.zeros((num_blocks, block_size, latent_width), dtype)
+                for _ in range(num_layers))
+        elif kv_cache_dtype == "int8":
             # quantized pool mode (module docstring): int8 codes +
             # per-(block, head) scales; the `pools` property is the
             # dequantized f32 view every consumer reads and writes
@@ -242,7 +270,8 @@ class PagedKVCache:
     # ------------------------------------------------ pool storage view
     @property
     def pools(self) -> Tuple[Tuple[jnp.ndarray, jnp.ndarray], ...]:
-        """L-tuple of (k, v) [num_blocks, block_size, H, D] in the
+        """L-tuple of (k, v) [num_blocks, block_size, H, D] (latent
+        layout: of one [num_blocks, block_size, W] array) in the
         LOGICAL f32 layout — what the attention gather, write_prefill
         scatter, migration and scrub paths all read and assign. In f32
         mode this is the storage itself (bit-for-bit the historical
@@ -885,6 +914,7 @@ class PagedKVCache:
         (payload, num_tokens); num_tokens is the sequence's current
         length — at a clean step boundary every one of those positions
         holds written KV."""
+        self._heads_layout_only("block migration (export_blocks)")
         table = self._tables[seq_id]
         if not table:
             return tuple((None, None) for _ in self.pools), \
@@ -902,6 +932,7 @@ class PagedKVCache:
         hold the table — migration aborts and the request keeps running
         at the source. The caller registers clean prefixes afterwards
         (register_prefix) so cached-prefix hit rates survive the hop."""
+        self._heads_layout_only("block migration (import_blocks)")
         if seq_id in self._tables:
             raise ValueError(f"seq {seq_id!r} already allocated")
         n = 0 if payload[0][0] is None else int(payload[0][0].shape[0])
@@ -918,6 +949,11 @@ class PagedKVCache:
         self._tables[seq_id] = ids
         self._lens[seq_id] = num_tokens
         return ids
+
+    def _heads_layout_only(self, feature: str) -> None:
+        if self.latent_width is not None:
+            raise NotImplementedError(
+                f"the latent cache layout does not support {feature} yet")
 
     def payload_bytes(self, payload) -> int:
         """Wire size of an export_blocks payload (obs histogram food)."""
@@ -1099,9 +1135,8 @@ class PagedKVCache:
         if not block_ids:
             return
         idx = jnp.asarray(list(block_ids), jnp.int32)
-        self.pools = tuple(
-            (kp.at[idx].set(0.0), vp.at[idx].set(0.0))
-            for kp, vp in self.pools)
+        self.pools = jax.tree_util.tree_map(
+            lambda pool: pool.at[idx].set(0), self.pools)
 
     def check_integrity(self) -> dict:
         """Invariant audit for the chaos harness: the free list and the
@@ -1189,7 +1224,9 @@ class PagedKVCache:
         shared blocks — any prefix hit is admitted through the chunked
         path, which writes only the uncached suffix positions)."""
         ids = self._tables[seq_id]
-        batch, _, seq, _ = dense_cache[0][0].shape
+        # [B, H, S, D] a (k, v) leaf, [B, S, W] a latent one
+        leaf = jax.tree_util.tree_leaves(dense_cache)[0]
+        batch, seq = leaf.shape[0], leaf.shape[-2]
         n_slots = self.blocks_needed(seq)
         if len(ids) > n_slots:
             raise ValueError(

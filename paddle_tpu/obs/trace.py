@@ -111,7 +111,8 @@ class Span:
     profiler's annotation takes its scalar values as event stats on the
     clean name (`serving.decode` with stat `num_seqs`), the chrome
     trace the whole dict. `span.args` set inside the scope is read at
-    end() and reaches the chrome trace only. `annotate=False` skips the
+    end() and reaches the chrome trace only; `span.set_stats(**kw)` inside
+    the scope reaches both. `annotate=False` skips the
     jax.profiler.TraceAnnotation for spans that must stay jax-free.
     """
 
@@ -137,6 +138,17 @@ class Span:
             self._t0 = time.perf_counter()
             depth = getattr(_TraceState.tls, "depth", 0)
             _TraceState.tls.depth = depth + 1
+
+    def set_stats(self, **stats):
+        """Stats known only inside the scope (a count the device returned
+        with the result): merged into `args` for the chrome trace and
+        appended to the open profiler annotation, so they land on the same
+        event as the stats given at construction."""
+        if not stats:
+            return
+        self.args = {**(self.args or {}), **stats}
+        if self._ann is not None:
+            self._ann.set_metadata(**stats)
 
     def end(self):
         if self._t0 is not None:

@@ -238,7 +238,8 @@ def test_every_new_metric_has_its_reader_and_its_entry():
     assert len(readers) == 11 and readers <= set(listed)
     for name in readers:
         assert listed[name]["source"] in ("device_trace", "program_span")
-        assert len(listed[name]["workloads"]) == 1
+        # one cell when PR 27 wrote them; later cells append their names
+        assert len(listed[name]["workloads"]) >= 1
     shares = [listed[n] for n in readers if n.startswith("serve_idle_")]
     assert {m["moves"] for m in shares} == {"serve_tokens_per_s"}
 

@@ -1,0 +1,344 @@
+"""openPangu-Ultra-MoE at a small size on the CPU (float32): the family
+against the plain reference, the latent paged cache, the dropless expert
+layer and its shares, and the family through `LLMEngine`."""
+import functools
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.distributed.moe import held_experts_mlp
+from paddle_tpu.inference.serving import (EngineConfig, LLMEngine,
+                                          SamplingParams)
+from paddle_tpu.inference.serving.attention import (PACK_COLS,
+                                                    fused_decode_chunk,
+                                                    paged_decode_step)
+from paddle_tpu.inference.serving.paged_cache import PagedKVCache
+from paddle_tpu.models import pangu_moe as pm
+from paddle_tpu.models.generation import extract_params
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
+from lib import reference_pangu_moe as ref  # noqa: E402
+
+SMALL = dict(vocab_size=256, hidden_size=64, num_hidden_layers=3,
+             first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=32,
+             kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+             v_head_dim=16, intermediate_size=128, moe_intermediate_size=32,
+             n_routed_experts=8, num_experts_per_tok=2, max_seq_len=64)
+
+
+def _family(held=None, seed=5):
+    cfg = pm.PanguMoEConfig(**SMALL, held_experts=held)
+    paddle.seed(seed)
+    model = pm.PanguMoE(cfg)
+    return model, cfg, extract_params(model)
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_fn(cfg):
+    return jax.jit(functools.partial(ref.logits, size=ref.sizes(cfg)))
+
+
+def _reference_logits(params, ids, cfg):
+    """One compilation a configuration: ids padded to max_seq_len (causal,
+    so the padding changes nothing before it)."""
+    padded = np.zeros((cfg.max_seq_len,), np.int32)
+    padded[:len(ids)] = ids
+    return np.asarray(_reference_fn(cfg)(params, jnp.asarray(padded)))[
+        :len(ids)]
+
+
+# ------------------------------------------------------------ the family
+@pytest.mark.parametrize("held", [None, (2, 2), (6, 2)])
+def test_forward_matches_the_plain_reference(held):
+    model, cfg, params = _family(held)
+    ids = np.random.default_rng(0).integers(0, 256, (2, 24)).astype(np.int32)
+    got = model(paddle.to_tensor(ids)).numpy()
+    assert got.shape == (2, 24, 256) and got.dtype == np.float32
+    for b in range(2):
+        np.testing.assert_allclose(got[b], _reference_logits(
+            params, ids[b], cfg), atol=1e-5, rtol=0)
+
+
+def test_absorbed_attention_equals_expanded_attention():
+    """Decode's latent-space form against the published per-head form, on
+    the same queries and cached rows (ragged lengths)."""
+    _, cfg, params = _family()
+    rng = np.random.default_rng(1)
+    n, s, pre = 3, 20, "layers.1."
+    h = jnp.asarray(rng.normal(size=(n, s, cfg.hidden_size)), jnp.float32)
+    pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (n, s))
+    q_nope, q_pe = pm.mla_queries(params, pre, h, pos, cfg)
+    rows = pm.mla_latent(params, pre, h, pos, cfg)
+    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+    expanded = np.asarray(pm.mla_expanded(params, pre, q_nope, q_pe, rows,
+                                          causal, cfg))
+    lens = np.asarray([20, 7, 13], np.int32)
+    last = lens - 1
+    take = np.arange(n)
+    absorbed = np.asarray(pm.mla_absorbed(
+        params, pre, q_nope[take, last], q_pe[take, last], rows,
+        jnp.asarray(lens), cfg))
+    np.testing.assert_allclose(absorbed, expanded[take, last], atol=1e-5,
+                               rtol=0)
+
+
+# ------------------------------------------------------- the expert layer
+def _expert_weights(rng, experts, h=32, f=16):
+    return (jnp.asarray(rng.normal(size=(h, 8)), jnp.float32),
+            *(jnp.asarray(rng.normal(size=shape) * 0.2, jnp.float32)
+              for shape in ((experts, h, f), (experts, h, f),
+                            (experts, f, h))))
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """8 experts over 4 ranks: the routed parts of all ranks, with the
+    shared expert counted once, equal the uncut reference layer."""
+    rng = np.random.default_rng(2)
+    router, wg, wu, wd = _expert_weights(rng, 8)
+    x = jnp.asarray(rng.normal(size=(40, 32)), jnp.float32)
+    shared = [jnp.asarray(rng.normal(size=s) * 0.2, jnp.float32)
+              for s in ((32, 16), (32, 16), (16, 32))]
+    parts, pairs = [], 0
+    for rank in range(4):
+        held = (2 * rank, 2)
+        routed, counts = held_experts_mlp(
+            x, router, wg[2 * rank:2 * rank + 2], wu[2 * rank:2 * rank + 2],
+            wd[2 * rank:2 * rank + 2], held, 2, 2.5)
+        parts.append(np.asarray(routed))
+        pairs += int(counts[0])
+    assert pairs == 40 * 2                      # every pair, exactly once
+    p = {"moe.router.weight": router, "moe.experts.gate.weight": wg,
+         "moe.experts.up.weight": wu, "moe.experts.down.weight": wd,
+         "moe.shared.gate.weight": shared[0],
+         "moe.shared.up.weight": shared[1],
+         "moe.shared.down.weight": shared[2]}
+    size = {"held": (0, 8), "num_experts_per_tok": 2,
+            "routed_scaling_factor": 2.5}
+    with jax.default_matmul_precision("highest"):
+        whole, ref_pairs = ref._experts(p, "", x, size, None)
+        once = ref._mlp(x, *shared, None)
+    assert int(ref_pairs) == pairs
+    np.testing.assert_allclose(sum(parts) + np.asarray(once),
+                               np.asarray(whole), atol=1e-5, rtol=0)
+
+
+def test_no_token_is_dropped_when_all_route_to_one_held_expert():
+    """Every token picks expert 3 first: its group holds all T tokens and
+    every one of them is multiplied (no capacity)."""
+    rng = np.random.default_rng(3)
+    router, wg, wu, wd = _expert_weights(rng, 2)
+    x = jnp.abs(jnp.asarray(rng.normal(size=(50, 32)), jnp.float32))
+    router = router.at[:, 3].set(5.0)           # x >= 0: column 3 wins
+    routed, counts = held_experts_mlp(x, router, wg, wu, wd, (3, 2), 2, 1.0)
+    assert int(counts[2]) == 50 and int(counts[0]) >= 50
+    scores = jax.nn.sigmoid(jnp.dot(x, router, precision="highest"))
+    top_s, top_i = jax.lax.top_k(scores, 2)
+    assert bool((top_i[:, 0] == 3).all())
+    want = np.zeros((50, 32), np.float32)
+    for e in (3, 4):
+        w = np.asarray(jnp.sum(jnp.where(top_i == e, top_s, 0.0), -1)
+                       / jnp.sum(top_s, -1))
+        with jax.default_matmul_precision("highest"):
+            want += w[:, None] * np.asarray(
+                ref._mlp(x, wg[e - 3], wu[e - 3], wd[e - 3], None))
+    np.testing.assert_allclose(np.asarray(routed), want, atol=1e-5, rtol=0)
+    assert np.abs(np.asarray(routed)).min(axis=1).max() > 0
+
+
+def test_a_token_with_no_held_expert_gets_zeros_and_dead_rows_count_nothing():
+    rng = np.random.default_rng(4)
+    router, wg, wu, wd = _expert_weights(rng, 2)
+    x = jnp.asarray(rng.normal(size=(30, 32)), jnp.float32)
+    routed, counts = held_experts_mlp(x, router, wg, wu, wd, (0, 2), 2, 2.5)
+    _, top_i = jax.lax.top_k(jax.nn.sigmoid(
+        jnp.dot(x, router, precision="highest")), 2)
+    none_held = np.asarray((top_i >= 2).all(axis=1))
+    assert none_held.any()
+    assert not np.asarray(routed)[none_held].any()
+    assert int(counts[0]) == int((np.asarray(top_i) < 2).sum())
+    live = jnp.arange(30) < 10
+    _, few = held_experts_mlp(x, router, wg, wu, wd, (0, 2), 2, 2.5, live)
+    assert int(few[0]) == int((np.asarray(top_i)[:10] < 2).sum())
+
+
+# ------------------------------------------------- the latent paged cache
+def test_latent_cache_is_one_pool_a_layer_and_counts_its_bytes():
+    _, cfg, _ = _family()
+    spec = pm.serving_spec(cfg)
+    assert spec.cache_layout == "latent" and spec.cache_shape == (24,)
+    assert spec.cache_bytes_per_token == 3 * 24 * 4
+    full = pm.serving_spec(pm.PanguMoEConfig(
+        num_hidden_layers=5, dtype="bfloat16"))
+    assert full.cache_shape == (576,)
+    assert full.cache_bytes_per_token == 5760
+    pc = PagedKVCache(3, 0, 0, 16, 4, latent_width=24)
+    assert [p.shape for p in pc.pools] == [(16, 4, 24)] * 3
+
+
+@pytest.mark.parametrize("length", [1, 3, 4, 5, 23])
+def test_write_prefill_on_the_latent_layout_equals_an_eager_loop(length):
+    rng = np.random.default_rng(length)
+    dense = tuple(jnp.asarray(np.where(
+        np.arange(24)[None, :, None] < length,
+        rng.normal(size=(2, 24, 12)), 0.0), jnp.float32) for _ in range(2))
+    got = PagedKVCache(2, 0, 0, 16, 4, latent_width=12)
+    want = PagedKVCache(2, 0, 0, 16, 4, latent_width=12)
+    for pc in (got, want):
+        pc.allocate("keep", 6)
+        pc.pools = tuple(p + 1.0 for p in pc.pools)
+    for b, sid in enumerate(("s0", "s1")):
+        for pc in (got, want):
+            pc.allocate(sid, length)
+        got.write_prefill(sid, dense, length, batch_index=b)
+        ids = want.block_table(sid)
+        n = len(ids) * 4
+        want.pools = tuple(
+            pool.at[jnp.asarray(ids)].set(
+                jnp.pad(d[b], ((0, max(0, n - 24)), (0, 0)))[:n]
+                .reshape(len(ids), 4, 12))
+            for pool, d in zip(want.pools, dense))
+    for g, w in zip(got.pools, want.pools):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    got.free("s0", scrub=True)
+    assert not any(got.check_integrity().values())
+
+
+def test_prefill_then_paged_decode_matches_the_reference_forward():
+    """The engine's prefill program, the scatter into the latent pool and
+    ragged `paged_decode_step`s against the reference's full forward."""
+    _, cfg, params = _family((0, 4))
+    spec = pm.serving_spec(cfg)
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, 256, (n,)).astype(np.int32)
+               for n in (5, 11, 8)]
+    pc = PagedKVCache(cfg.num_hidden_layers, 0, 0, 32, 4,
+                      latent_width=cfg.latent_width)
+    toks, seqs = [], []
+    for b, p in enumerate(prompts):
+        logits, dense, counts = spec.prefill(params,
+                                             jnp.asarray(p[None], jnp.int32))
+        np.testing.assert_allclose(
+            np.asarray(logits)[0], _reference_logits(params, p, cfg)[-1],
+            atol=1e-5, rtol=0)
+        assert counts.shape == (3,)
+        pc.allocate(b, len(p))
+        pc.write_prefill(b, dense, len(p))
+        toks.append(int(np.asarray(logits)[0].argmax()))
+        seqs.append(list(p))
+    tables = np.zeros((3, cfg.max_seq_len // 4), np.int32)
+    step = jax.jit(functools.partial(paged_decode_step, geom=spec))
+    for _ in range(6):
+        slots = [pc.append_slot(b) for b in range(3)]
+        for b in range(3):
+            seqs[b].append(toks[b])
+            t = pc.block_table(b)
+            tables[b, :len(t)] = t
+        logits, pc.pools = step(
+            params, pc.pools, np.asarray(toks, np.int32),
+            np.asarray([s[2] for s in slots], np.int32), tables,
+            np.asarray([s[0] for s in slots], np.int32),
+            np.asarray([s[1] for s in slots], np.int32))
+        for b in range(3):
+            np.testing.assert_allclose(
+                np.asarray(logits)[b],
+                _reference_logits(params, np.asarray(seqs[b]), cfg)[-1],
+                atol=1e-5, rtol=0)
+        toks = [int(r.argmax()) for r in np.asarray(logits)]
+    assert not any(pc.check_integrity().values())
+
+
+# ------------------------------------------------------ through LLMEngine
+def _engine(model, k, **kw):
+    return LLMEngine.from_model(model, EngineConfig(
+        block_size=8, num_blocks=48, max_num_seqs=4, decode_chunk_size=k,
+        **kw))
+
+
+def _serve(model, k, prompts, **kw):
+    eng = _engine(model, k, **kw)
+    for i, p in enumerate(prompts):
+        eng.add_request(p, SamplingParams(max_tokens=12 + i),
+                        request_id=f"r{i}")
+    out = eng.run()
+    assert not any(eng.cache.check_integrity().values())
+    return eng, [out[f"r{i}"].tolist() for i in range(len(prompts))]
+
+
+def test_engine_streams_are_bit_equal_for_chunks_of_8_and_of_1():
+    model, cfg, params = _family((0, 4))
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, 256, (n,)).astype(np.int32)
+               for n in (5, 17, 9, 30, 3)]
+    eng8, got8 = _serve(model, 8, prompts)
+    eng1, got1 = _serve(model, 1, prompts)
+    assert got8 == got1
+    # and each token is the reference's best, to float32 resolution
+    for p, toks in zip(prompts, got8):
+        ids = np.concatenate([p, toks]).astype(np.int32)
+        lg = _reference_logits(params, ids, cfg)[len(p) - 1:-1]
+        assert (lg.max(-1) - lg[np.arange(len(toks)), toks]).max() < 1e-5
+    assert eng8.stats.moe_pairs == eng1.stats.moe_pairs > 0
+    assert eng8.stats.moe_experts_hit > 0
+    assert eng8.stats.cache_bytes_per_token == 3 * 24 * 4
+    assert eng8.stats.as_dict()["moe_pairs"] == eng8.stats.moe_pairs
+
+
+def test_chunked_prefill_rides_the_shared_prompt_feed():
+    model, _, _ = _family((0, 4))
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(0, 256, (n,)).astype(np.int32)
+               for n in (21, 6, 28)]
+    _, dense = _serve(model, 8, prompts)
+    eng, chunked = _serve(model, 8, prompts, prefill_chunk_threshold=8)
+    assert chunked == dense and eng.stats.prefill_chunks() > 0
+
+
+def test_the_chunk_returns_its_counts_as_extra_rows():
+    _, cfg, params = _family()                  # all 8 experts held
+    spec = pm.serving_spec(cfg)
+    pc = PagedKVCache(3, 0, 0, 16, 8, latent_width=cfg.latent_width)
+    k, n, mb = 4, 2, cfg.max_seq_len // 8
+    pc.allocate(0, 3)
+    pc.reserve_slots(0, k)
+    packed = np.zeros((n, PACK_COLS + k + mb), np.int32)
+    packed[0, :5] = (7, 3, 1, 1, 100)
+    packed[0, 5] = -1
+    table = pc.block_table(0)
+    packed[0, PACK_COLS + k:PACK_COLS + k + len(table)] = table
+    out, _ = fused_decode_chunk(params, pc.pools, jnp.asarray(packed), spec,
+                                k)
+    out = np.asarray(out)
+    assert out.shape == (k + 2 + 3, n)
+    pairs, hit, load = out[k + 2:, 0]
+    # one live row, 2 expert layers, top-2 with every expert held: 2 pairs
+    # a layer and trip on 2 experts, and the dead row routes nowhere
+    assert pairs == k * 2 * 2 and hit == pairs and load == 1
+    assert (out[k + 2:, 1] == out[k + 2:, 0]).all()
+
+
+@pytest.mark.parametrize("config, feature", [
+    (dict(kv_cache_dtype="int8"), "int8 KV pools"),
+    (dict(enable_prefix_cache=True), "prefix cache"),
+    (dict(enable_prefix_cache=True, host_tier_blocks=4), "prefix cache"),
+])
+def test_what_the_latent_layout_lacks_raises_by_name(config, feature):
+    model, _, _ = _family()
+    with pytest.raises(NotImplementedError, match=feature):
+        _engine(model, 8, **config)
+
+
+def test_the_host_tier_and_migration_raise_by_name_on_the_latent_layout():
+    with pytest.raises(NotImplementedError, match="host tier"):
+        PagedKVCache(1, 0, 0, 8, 4, latent_width=12, host_tier_blocks=2)
+    model, _, _ = _family()
+    eng = _engine(model, 8)
+    rid = eng.add_request(np.arange(5, dtype=np.int32),
+                          SamplingParams(max_tokens=20))
+    eng.step()
+    with pytest.raises(NotImplementedError, match="migration"):
+        eng.export_request(rid)

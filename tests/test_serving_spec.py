@@ -1,0 +1,114 @@
+"""The model seam of the serving engine (`models.spec.ModelSpec`): the
+GPT-2 spec wraps models/generation.py unchanged, so its three programs keep
+their names, arguments and text; the tuple geometry still names it; a span
+takes stats from inside its scope."""
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.inference.serving import EngineConfig, LLMEngine
+from paddle_tpu.inference.serving.attention import (PACK_COLS, as_spec,
+                                                    fused_decode_chunk,
+                                                    gpt2_spec)
+from paddle_tpu.inference.serving.paged_cache import (PagedKVCache,
+                                                      write_prefill_scatter)
+from paddle_tpu.models import generation as gen
+from paddle_tpu.models.gpt import GPT, GPTConfig
+from paddle_tpu.models.spec import ModelSpec
+
+GEOM = (2, 2, 64, 128)
+
+
+@pytest.fixture(scope="module")
+def gpt():
+    model = GPT(GPTConfig(vocab_size=512, hidden_size=128, num_layers=2,
+                          num_heads=2, max_seq_len=128))
+    return model, gen.extract_params(model)
+
+
+def _lowered(params):
+    pc = PagedKVCache(2, 2, 64, 64, 8)
+    k = 8
+    packed = np.zeros((4, PACK_COLS + k + 16), np.int32)
+    ids = jnp.zeros((1, 24), jnp.int32)
+    _, dense = gen.prefill(params, ids, GEOM)
+    return {
+        "jit_fused_decode_chunk": fused_decode_chunk.lower(
+            params, pc.pools, packed, GEOM, k, "ragged"),
+        "jit_prefill": gen.prefill.lower(params, ids, GEOM),
+        "jit_write_prefill_scatter": write_prefill_scatter.lower(
+            pc.pools, dense, np.zeros((16,), np.int32), np.int32(0)),
+    }
+
+
+@pytest.mark.parametrize("name, args, results", [
+    # arrays beside the parameters: (pools + packed) in, (out, pools) out
+    ("jit_fused_decode_chunk", 4 + 1, 1 + 4),
+    ("jit_prefill", 1, 1 + 4),
+    ("jit_write_prefill_scatter", 4 + 4 + 2, 4),
+])
+def test_gpt2_programs_keep_names_and_argument_shapes(gpt, name, args,
+                                                      results):
+    """The three programs of the GPT-2 cells, as the benchmark's readers
+    find them (`XLA Modules` names) and as the parent commit lowered them:
+    on the tree of PR 29 their text was byte-equal to PR 28's (sha1
+    compared on a scratch copy of the parent); what stays checkable here is
+    the name, the argument and result counts, and that the spec and the
+    tuple lower to the same text."""
+    lowered = _lowered(gpt[1])[name]
+    text = lowered.as_text()
+    assert f"module @{name} " in text
+    flat_in = jax.tree_util.tree_leaves(lowered.in_avals)
+    held = 0 if name == "jit_write_prefill_scatter" else len(gpt[1])
+    assert len(flat_in) == held + args
+    assert len(jax.tree_util.tree_leaves(lowered.out_info)) == results
+
+
+def test_the_tuple_and_the_spec_lower_the_chunk_to_the_same_text(gpt):
+    params = gpt[1]
+    pc = PagedKVCache(2, 2, 64, 64, 8)
+    packed = np.zeros((4, PACK_COLS + 8 + 16), np.int32)
+    texts = [fused_decode_chunk.lower(params, pc.pools, packed, g, 8,
+                                      "ragged").as_text()
+             for g in (GEOM, gpt2_spec(GEOM))]
+    assert hashlib.sha1(texts[0].encode()).hexdigest() \
+        == hashlib.sha1(texts[1].encode()).hexdigest()
+    assert texts[0].count("stablehlo.sort") == texts[1].count(
+        "stablehlo.sort") > 0
+
+
+def test_a_geometry_tuple_names_the_gpt2_spec(gpt):
+    spec = as_spec(GEOM)
+    assert isinstance(spec, ModelSpec) and spec is gpt2_spec(GEOM)
+    assert as_spec(spec) is spec
+    assert (spec.family, spec.cache_layout, spec.cache_shape,
+            spec.pools_per_layer, spec.counters) \
+        == ("gpt2", "heads", (2, 64), 2, ())
+    assert spec.cache_bytes_per_token == 2 * 2 * 2 * 64 * 4
+    eng = LLMEngine.from_model(gpt[0], EngineConfig(block_size=8,
+                                                    num_blocks=32))
+    assert eng.geom == GEOM and eng.spec is spec
+    assert eng.stats.cache_bytes_per_token == spec.cache_bytes_per_token
+    assert [p.shape for p in eng.cache.pools[0]] == [(32, 8, 2, 64)] * 2
+    # the counters of an expert family stay at zero for GPT-2
+    assert eng.stats.moe_pairs == 0 and eng.stats.moe_experts_hit == 0
+
+
+def test_a_span_takes_stats_from_inside_its_scope(tmp_path):
+    from jax.profiler import ProfileData
+    from paddle_tpu import obs
+    jax.profiler.start_trace(str(tmp_path))
+    with obs.span("serving.decode", args={"chunk": 8}) as ev:
+        jnp.ones(3).block_until_ready()
+        ev.set_stats(moe_pairs=7, moe_experts_hit=3)
+        ev.set_stats()
+    jax.profiler.stop_trace()
+    assert ev.args == {"chunk": 8, "moe_pairs": 7, "moe_experts_hit": 3}
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    found = [dict(e.stats) for pl in ProfileData.from_file(str(path)).planes
+             for ln in pl.lines for e in ln.events
+             if e.name == "serving.decode"]
+    assert found == [{"chunk": 8, "moe_pairs": 7, "moe_experts_hit": 3}]

@@ -224,3 +224,53 @@ def test_flash_compiles_per_shard_under_a_mesh(topo):
         assert _kernel_calls(grad, x, x, x) == 3
     finally:
         set_global_mesh(before)
+
+
+def test_the_held_experts_layer_is_a_grouped_matmul_kernel(one_chip):
+    """The dropless expert layer at the published widths (16 held experts
+    of width 2048 on hidden 7680, 128 decode rows, top-8 of 256):
+    `jax.lax.ragged_dot` becomes Mosaic grouped matmuls (three products and
+    their group metadata), not a dense product over every (token, expert),
+    and the pair buffer is the only large temporary."""
+    from paddle_tpu.distributed.moe import held_experts_mlp
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(x, router, wg, wu, wd):
+        return held_experts_mlp(x, router, wg, wu, wd, (0, 16), 8, 2.5)
+    compiled = jax.jit(layer).lower(
+        sds((128, 7680), jnp.bfloat16), sds((7680, 256), jnp.float32),
+        sds((16, 7680, 2048), jnp.bfloat16),
+        sds((16, 7680, 2048), jnp.bfloat16),
+        sds((16, 2048, 7680), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 3
+    assert text.count("tpu_custom_call") >= 3
+    # 1,024 pair rows of 7,680 float32 are 31 MB; a dense [16, 128, ...]
+    # expansion of the weights or the rows would be hundreds
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+
+def test_the_latent_pool_is_scattered_in_place_at_the_cell_size(one_chip):
+    """`write_prefill_scatter` on the latent layout of the expert cell (5
+    layers of bf16 [8192, 32, 576], 2,048 dense positions): one module
+    under the shared name, every pool aliased to its output. What it also
+    shows (PERF.md section 5): a v5e keeps that shape with the block id
+    MINOR, so each pool is copied out of that layout and back (two
+    whole-pool copies a layer), within half a pool of temporaries a
+    layer."""
+    from paddle_tpu.inference.serving.paged_cache import \
+        write_prefill_scatter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    layers, pool_bytes = 5, 8192 * 32 * 576 * 2
+    compiled = write_prefill_scatter.lower(
+        (sds((8192, 32, 576), jnp.bfloat16),) * layers,
+        (sds((1, 2048, 576), jnp.bfloat16),) * layers,
+        sds((64,), jnp.int32), sds((), jnp.int32)).compile()
+    assert "HloModule jit_write_prefill_scatter" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= layers * pool_bytes
+    assert mem.temp_size_in_bytes < 1.25 * pool_bytes
