@@ -149,7 +149,7 @@ def _moe_mlp(x, wr, wu, bu, wd, bd, top_k, capacity_factor, min_capacity):
 def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
                      scale, live=None):
     """The dropless expert layer of ONE chip of an expert-parallel
-    deployment: x [T, h] -> (routed part [T, h] float32, counts int32 [3]).
+    deployment: x [T, h] -> (routed part [T, h] float32, counts int32 [4]).
 
     The router is whole: `router_w` [h, E] scores every token over all E
     experts in float32 (sigmoid), the `top_k` largest are chosen and their
@@ -163,20 +163,28 @@ def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
     its weighted rows back. No capacity, so no token is dropped at any
     imbalance; a token none of whose experts is held gets zeros. What the
     absent experts would add is another chip's, and nothing here stands in
-    for them or for the exchange. Static shapes: the pair buffer always
-    has T * top_k rows (a token may send all its picks here); rows behind
-    the last group belong to no group and are not multiplied.
+    for them or for the exchange.
 
-    counts = (pairs multiplied, held experts with at least one token, most
-    tokens one held expert got): what the tracing reads, at no extra
+    The buffer `ragged_dot` multiplies has the size the batch needs, chosen
+    on the device (the kernel's row tile is min(rows, 512) whatever the
+    groups hold, so rows behind the last group are paid for as padding): R
+    rows, twice what uniform routing sends here rounded up to the 128 rows
+    of an MXU pass (static, from shapes), when the pairs routed here fit in
+    them; all T * top_k otherwise (a token MAY send all its picks here).
+    Both are in the program, under one `jax.lax.cond`.
+
+    counts = (pairs routed here, held experts with at least one token, 1
+    if they did not fit in R rows and the full buffer was multiplied, most
+    tokens one held expert got): sums first, the maximum last
+    (`models.spec.merge_counts`); what the tracing reads, at no extra
     fetch."""
     first, count = held
-    tokens = x.shape[0]
+    tokens, pairs = x.shape[0], x.shape[0] * top_k
     scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router_w,
                                     precision="highest"))
     top_s, top_i = jax.lax.top_k(scores, top_k)
-    weight = top_s / jnp.sum(top_s, axis=-1, keepdims=True) \
-        * jnp.float32(scale)
+    weight = (top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+              * jnp.float32(scale)).reshape(-1)
     local = top_i.astype(jnp.int32) - first
     mine = (local >= 0) & (local < count)
     if live is not None:
@@ -184,17 +192,32 @@ def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
     group = jnp.where(mine, local, count).reshape(-1)   # count = not here
     order = jnp.argsort(group, stable=True)
     sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
-    rows = x[order // top_k]                            # [T * top_k, h]
-    act = jax.nn.silu(jax.lax.ragged_dot(
-        rows, w_gate, sizes, preferred_element_type=jnp.float32)) \
-        * jax.lax.ragged_dot(rows, w_up, sizes,
-                             preferred_element_type=jnp.float32)
-    out = jax.lax.ragged_dot(act.astype(x.dtype), w_down, sizes,
-                             preferred_element_type=jnp.float32)
-    here = (jnp.arange(tokens * top_k) < jnp.sum(sizes))[:, None]
-    out = jnp.where(here, out * weight.reshape(-1)[order][:, None], 0.0)
-    routed = out[jnp.argsort(order)].reshape(tokens, top_k, -1).sum(axis=1)
-    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
+    routed_here = jnp.sum(sizes)
+    place = jnp.argsort(order)            # of each (token, pick), sorted
+
+    def multiply(n):
+        """The first n sorted pairs through the experts, into [T, h]."""
+        rows = x[order[:n] // top_k]
+        act = jax.nn.silu(jax.lax.ragged_dot(
+            rows, w_gate, sizes, preferred_element_type=jnp.float32)) \
+            * jax.lax.ragged_dot(rows, w_up, sizes,
+                                 preferred_element_type=jnp.float32)
+        out = jax.lax.ragged_dot(act.astype(x.dtype), w_down, sizes,
+                                 preferred_element_type=jnp.float32)
+        out = jnp.where(group[:, None] < count,
+                        out[jnp.minimum(place, n - 1)] * weight[:, None],
+                        0.0)
+        return out.reshape(tokens, top_k, -1).sum(axis=1)
+
+    compact = 128 * math.ceil(2 * pairs * count
+                              / (128 * router_w.shape[1]))
+    full = routed_here > compact          # never where compact >= pairs
+    if compact >= pairs:
+        routed = multiply(pairs)
+    else:
+        routed = jax.lax.cond(full, lambda: multiply(pairs),
+                              lambda: multiply(compact))
+    counts = jnp.stack([routed_here, jnp.sum(sizes > 0), full,
                         jnp.max(sizes)]).astype(jnp.int32)
     return routed, counts
 

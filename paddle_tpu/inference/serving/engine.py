@@ -194,8 +194,9 @@ _STAT_EVENTS = ("steps", "prefill_tokens", "generated_tokens",
                 "timeouts", "shed", "errors", "recoveries", "rebuilt",
                 "watchdog_trips", "rejected",
                 # expert families (ModelSpec.counters): (token, held
-                # expert) pairs multiplied, held experts hit per trip/layer
-                "moe_pairs", "moe_experts_hit",
+                # expert) pairs routed, held experts hit per trip/layer,
+                # layers and trips that multiplied the full pair buffer
+                "moe_pairs", "moe_experts_hit", "moe_full_buffer_layers",
                 # the `context_tokens` stat of serving.decode, summed
                 "context_tokens")
 # float phase-time accumulators (serving_phase_seconds_total{engine,phase})
@@ -1371,7 +1372,10 @@ class LLMEngine:
                         self._quarantine(req, outs, f"prefill raised: {e}")
                         continue
                     if counts:
-                        ev.set_stats(moe_pairs=counts["moe_pairs"])
+                        ev.set_stats(
+                            moe_pairs=counts["moe_pairs"],
+                            moe_full_buffer_layers=counts[
+                                "moe_full_buffer_layers"])
                     self.stats.prefill_tokens += int(tokens.size)
                     prefill_spend += int(tokens.size)
                     self.stats.time_prefill += time.perf_counter() - t0
@@ -1541,6 +1545,7 @@ class LLMEngine:
         named = {n: int(c) for n, c in zip(self.spec.counters, counts)}
         self.stats.moe_pairs += named["moe_pairs"]
         self.stats.moe_experts_hit += named["moe_experts_hit"]
+        self.stats.moe_full_buffer_layers += named["moe_full_buffer_layers"]
         return named
 
     @holds_lock("_lock")

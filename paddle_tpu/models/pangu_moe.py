@@ -75,7 +75,8 @@ __all__ = ["PanguMoEConfig", "PanguMoE", "param_shapes", "forward",
            "prefill", "serving_spec", "mla_expanded", "mla_absorbed"]
 
 #: the counts an expert layer returns (ModelSpec.counters)
-COUNTERS = ("moe_pairs", "moe_experts_hit", "moe_max_load")
+COUNTERS = ("moe_pairs", "moe_experts_hit", "moe_full_buffer_layers",
+            "moe_max_load")
 
 
 @dataclass(frozen=True)
@@ -260,8 +261,8 @@ def layer_tail(p, i, x, attn, cfg, live=None):
     """Everything of layer i after its attention: the out-projection, the
     two inner norms and the MLP or the expert layer. x, attn [..., .];
     `live` [tokens] switches rows off in the routing (frozen rows of a
-    decode batch). Returns (y, counts int32 [3]; zeros for a dense
-    layer)."""
+    decode batch). Returns (y, counts int32 [len(COUNTERS)]; zeros for a
+    dense layer)."""
     pre, eps = f"layers.{i}.", cfg.rms_norm_eps
     a = x + rms_norm(_mm(attn, p[pre + "attn.o.weight"]),
                      p[pre + "norm2.weight"], eps)
@@ -269,7 +270,7 @@ def layer_tail(p, i, x, attn, cfg, live=None):
     if i < cfg.first_k_dense_replace:
         m = gated_mlp(h, p[pre + "mlp.gate.weight"],
                       p[pre + "mlp.up.weight"], p[pre + "mlp.down.weight"])
-        counts = jnp.zeros((3,), jnp.int32)
+        counts = jnp.zeros((len(COUNTERS),), jnp.int32)
     else:
         flat = h.reshape(-1, h.shape[-1])
         routed, counts = held_experts_mlp(
@@ -293,7 +294,7 @@ def _dense_layers(p, ids, cfg):
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), ids.shape)
     causal = jnp.arange(T)[:, None] >= jnp.arange(T)[None, :]
     x = p["embed.weight"][ids]
-    rows_of, total = [], jnp.zeros((3,), jnp.int32)
+    rows_of, total = [], jnp.zeros((len(COUNTERS),), jnp.int32)
     for i in range(cfg.num_hidden_layers):
         pre = f"layers.{i}."
         h = rms_norm(x, p[pre + "norm1.weight"], cfg.rms_norm_eps)

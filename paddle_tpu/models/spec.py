@@ -28,10 +28,10 @@ __all__ = ["ModelSpec", "merge_counts"]
 
 def merge_counts(total, counts):
     """Fold one layer's (or one program's) counts into a running total:
-    the first two are sums, the third a maximum (`ModelSpec.counters`)."""
+    sums, and the last a maximum (`ModelSpec.counters`)."""
     import jax.numpy as jnp
-    return jnp.concatenate([total[:2] + counts[:2],
-                            jnp.maximum(total[2:], counts[2:])])
+    return jnp.concatenate([total[:-1] + counts[:-1],
+                            jnp.maximum(total[-1:], counts[-1:])])
 
 
 @dataclass(frozen=True)
@@ -58,8 +58,8 @@ class ModelSpec:
     #: (params, ids [B, T]) -> (last-position logits [B, V], dense
     #: per-layer cache rows for `write_prefill_scatter`, counts or None)
     prefill: Callable
-    #: names of the int32 counts `decode_layer` and `prefill` return: the
-    #: first two are summed over layers and trips, the third is a maximum
+    #: names of the int32 counts `decode_layer` and `prefill` return:
+    #: summed over layers and trips, but for the last, a maximum
     counters: Tuple[str, ...] = ()
     #: what the functions above were built from (a config, a geometry)
     config: Hashable = None

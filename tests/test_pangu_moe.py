@@ -135,7 +135,7 @@ def test_no_token_is_dropped_when_all_route_to_one_held_expert():
     x = jnp.abs(jnp.asarray(rng.normal(size=(50, 32)), jnp.float32))
     router = router.at[:, 3].set(5.0)           # x >= 0: column 3 wins
     routed, counts = held_experts_mlp(x, router, wg, wu, wd, (3, 2), 2, 1.0)
-    assert int(counts[2]) == 50 and int(counts[0]) >= 50
+    assert int(counts[3]) == 50 and int(counts[0]) >= 50
     scores = jax.nn.sigmoid(jnp.dot(x, router, precision="highest"))
     top_s, top_i = jax.lax.top_k(scores, 2)
     assert bool((top_i[:, 0] == 3).all())
@@ -164,6 +164,72 @@ def test_a_token_with_no_held_expert_gets_zeros_and_dead_rows_count_nothing():
     live = jnp.arange(30) < 10
     _, few = held_experts_mlp(x, router, wg, wu, wd, (0, 2), 2, 2.5, live)
     assert int(few[0]) == int((np.asarray(top_i)[:10] < 2).sum())
+
+
+def _routed_by_class(rng, both, one, tokens=256):
+    """x [tokens, 32] and a router over 64 experts that sends `both` tokens'
+    two picks to the held experts 8 and 9, `one` tokens' first pick to the
+    held expert 10 (the second to 40, held elsewhere) and the rest to 50
+    and 51: the class is the token's first three features, the router
+    reads nothing else, and the tokens are shuffled."""
+    kind = rng.permutation(np.repeat(
+        [0, 1, 2], [both, one, tokens - both - one]))
+    x = rng.normal(size=(tokens, 32))
+    x[:, :3] = 4.0 * np.eye(3)[kind]
+    router = np.zeros((32, 64))
+    router[:3] = rng.uniform(-2.0, -1.0, size=(3, 64))
+    for k, picks in enumerate(((8, 9), (10, 40), (50, 51))):
+        router[k, picks] = (3.0, 2.0)
+    return jnp.asarray(x, jnp.float32), jnp.asarray(router, jnp.float32)
+
+
+@pytest.mark.parametrize("both, one, live, full", [
+    pytest.param(None, None, None, 0, id="uniform-compact"),
+    pytest.param(256, 0, None, 1, id="every-pick-here-full"),
+    pytest.param(60, 8, None, 0, id="pairs-equal-R-compact"),
+    pytest.param(60, 9, None, 1, id="pairs-R-plus-1-full"),
+    pytest.param(256, 0, 50, 0, id="rows-switched-off-compact"),
+    pytest.param(0, 0, None, 0, id="no-held-expert-zeros"),
+])
+def test_the_compact_and_the_full_buffer_equal_a_per_token_loop(
+        both, one, live, full):
+    """256 tokens, top-2 of 64 experts, 4 held: 512 pairs against a compact
+    buffer of R = 128 rows. Whichever branch the routed pairs choose, the
+    routed part and the counts are those of a loop over every token's
+    picks."""
+    rng = np.random.default_rng(6)
+    if both is None:
+        x = jnp.asarray(rng.normal(size=(256, 32)), jnp.float32)
+        router = jnp.asarray(rng.normal(size=(32, 64)), jnp.float32)
+    else:
+        x, router = _routed_by_class(rng, both, one)
+    wg, wu, wd = _expert_weights(rng, 4)[1:]
+    alive = None if live is None else jnp.arange(256) < live
+    routed, counts = held_experts_mlp(x, router, wg, wu, wd, (8, 4), 2, 2.5,
+                                      alive)
+    top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(
+        jnp.dot(x, router, precision="highest")), 2)
+    top_s, top_i = np.asarray(top_s, np.float64), np.asarray(top_i)
+    x64 = np.asarray(x, np.float64)
+    want, load = np.zeros((256, 32)), np.zeros(4, int)
+    for t in range(256 if live is None else live):
+        for s, e in zip(top_s[t], top_i[t] - 8):
+            if 0 <= e < 4:
+                a = x64[t] @ np.asarray(wg[e], np.float64)
+                act = a / (1 + np.exp(-a)) * (x64[t] @ np.asarray(wu[e]))
+                want[t] += 2.5 * s / top_s[t].sum() * (act @ np.asarray(wd[e]))
+                load[e] += 1
+    np.testing.assert_allclose(np.asarray(routed), want, rtol=1e-5,
+                               atol=1e-5)
+    assert dict(zip(pm.COUNTERS, np.asarray(counts))) == {
+        "moe_pairs": load.sum(), "moe_experts_hit": (load > 0).sum(),
+        "moe_full_buffer_layers": full, "moe_max_load": load.max()}
+    assert (load.sum() > 128) == bool(full)
+    if both is not None and live is None:
+        assert load.sum() == 2 * both + one
+        none_held = np.asarray(x[:, 2] > 0)     # the third class
+        assert not np.asarray(routed)[none_held].any()
+        assert none_held.sum() == 256 - both - one
 
 
 # ------------------------------------------------- the latent paged cache
@@ -225,7 +291,7 @@ def test_prefill_then_paged_decode_matches_the_reference_forward():
         np.testing.assert_allclose(
             np.asarray(logits)[0], _reference_logits(params, p, cfg)[-1],
             atol=1e-5, rtol=0)
-        assert counts.shape == (3,)
+        assert counts.shape == (len(pm.COUNTERS),)
         pc.allocate(b, len(p))
         pc.write_prefill(b, dense, len(p))
         toks.append(int(np.asarray(logits)[0].argmax()))
@@ -286,6 +352,8 @@ def test_engine_streams_are_bit_equal_for_chunks_of_8_and_of_1():
     assert eng8.stats.moe_experts_hit > 0
     assert eng8.stats.cache_bytes_per_token == 3 * 24 * 4
     assert eng8.stats.as_dict()["moe_pairs"] == eng8.stats.moe_pairs
+    # 8 of 8 experts held: the compact buffer is the whole one, no fallback
+    assert eng8.stats.as_dict()["moe_full_buffer_layers"] == 0
 
 
 def test_chunked_prefill_rides_the_shared_prompt_feed():
@@ -313,11 +381,12 @@ def test_the_chunk_returns_its_counts_as_extra_rows():
     out, _ = fused_decode_chunk(params, pc.pools, jnp.asarray(packed), spec,
                                 k)
     out = np.asarray(out)
-    assert out.shape == (k + 2 + 3, n)
-    pairs, hit, load = out[k + 2:, 0]
+    assert out.shape == (k + 2 + 4, n)
+    pairs, hit, full, load = out[k + 2:, 0]
     # one live row, 2 expert layers, top-2 with every expert held: 2 pairs
     # a layer and trip on 2 experts, and the dead row routes nowhere
     assert pairs == k * 2 * 2 and hit == pairs and load == 1
+    assert full == 0
     assert (out[k + 2:, 1] == out[k + 2:, 0]).all()
 
 

@@ -15,6 +15,9 @@ library, so the topology is described inside a module-scoped fixture, by
 the one xdist worker that is handed this file, and every compile happens
 in the test's own process.
 """
+import functools
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -116,7 +119,6 @@ def test_packed_flash_fwd_bwd_compiles(one_chip, shape, calls):
 def _kernel_names(fn, *args) -> list:
     """HLO names of the Mosaic kernels in the program compiled for the
     described chip: what the device trace will call them."""
-    import re
     text = jax.jit(fn).lower(*args).compile().as_text()
     return sorted(re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", line)
                   .group(1) for line in text.splitlines()
@@ -226,12 +228,11 @@ def test_flash_compiles_per_shard_under_a_mesh(topo):
         set_global_mesh(before)
 
 
-def test_the_held_experts_layer_is_a_grouped_matmul_kernel(one_chip):
+@functools.lru_cache(maxsize=None)
+def _held_experts_compiled(one_chip, rows):
     """The dropless expert layer at the published widths (16 held experts
-    of width 2048 on hidden 7680, 128 decode rows, top-8 of 256):
-    `jax.lax.ragged_dot` becomes Mosaic grouped matmuls (three products and
-    their group metadata), not a dense product over every (token, expert),
-    and the pair buffer is the only large temporary."""
+    of width 2048 on hidden 7680, top-8 of 256), compiled for `rows`
+    tokens."""
     from paddle_tpu.distributed.moe import held_experts_mlp
 
     def sds(shape, dtype):
@@ -239,17 +240,45 @@ def test_the_held_experts_layer_is_a_grouped_matmul_kernel(one_chip):
 
     def layer(x, router, wg, wu, wd):
         return held_experts_mlp(x, router, wg, wu, wd, (0, 16), 8, 2.5)
-    compiled = jax.jit(layer).lower(
-        sds((128, 7680), jnp.bfloat16), sds((7680, 256), jnp.float32),
+    return jax.jit(layer).lower(
+        sds((rows, 7680), jnp.bfloat16), sds((7680, 256), jnp.float32),
         sds((16, 7680, 2048), jnp.bfloat16),
         sds((16, 7680, 2048), jnp.bfloat16),
         sds((16, 2048, 7680), jnp.bfloat16)).compile()
+
+
+def test_the_held_experts_layer_is_a_grouped_matmul_kernel(one_chip):
+    """At 128 decode rows `jax.lax.ragged_dot` becomes Mosaic grouped
+    matmuls (three products and their group metadata), not a dense product
+    over every (token, expert), and the pair buffer is the only large
+    temporary."""
+    compiled = _held_experts_compiled(one_chip, 128)
     text = compiled.as_text()
     assert text.count("ragged-dot") >= 3
     assert text.count("tpu_custom_call") >= 3
     # 1,024 pair rows of 7,680 float32 are 31 MB; a dense [16, 128, ...]
     # expansion of the weights or the rows would be hundreds
     assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+
+@pytest.mark.parametrize("rows, compact_tile", [(128, "128,512,512"),
+                                                (1024, "512,512,512")])
+def test_the_pair_buffer_has_a_compact_and_a_full_branch(one_chip, rows,
+                                                         compact_tile):
+    """The expert layer at the cell's decode shape (128 rows: 1,024 pairs,
+    a compact buffer of 128) and at its longest prompt (1,024 rows: 8,192
+    pairs against 1,024): one `conditional` with the three products in each
+    branch. The kernel's row tile is min(buffer rows, 512), so the compact
+    branch of a decode trip multiplies tiles of 128 rows where the full one
+    multiplies 512."""
+    compiled = _held_experts_compiled(one_chip, rows)
+    text = compiled.as_text()
+    assert len(re.findall(r" conditional\(", text)) == 1
+    assert sorted(re.findall(r'ragged_dot_tiling="([0-9,]+)"', text)) \
+        == sorted([compact_tile] * 3 + ["512,512,512"] * 3)
+    # the full branch's 8 x rows pair rows of 7,680 float32, twice
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (200e6 if rows == 128 else 600e6)
 
 
 def test_the_latent_pool_is_scattered_in_place_at_the_cell_size(one_chip):
