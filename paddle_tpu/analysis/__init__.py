@@ -19,7 +19,7 @@ see until they break in production (docs/static_analysis.md):
   from its jaxpr, gate them against jaxcost_budget.json, and audit
   buffer donation (docs/static_cost.md); hlo_bytes.py is the shared
   HLO-text byte accounting used by tools/hlo_bytes.py and
-  tools/scaling_analysis.py.
+  jaxcost.py.
 
 The lint core (ast_core + rules + hlo_bytes) is stdlib-only so
 `tools/ptlint.py` and `tools/hlo_bytes.py` run without importing jax;

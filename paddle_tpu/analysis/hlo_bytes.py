@@ -10,7 +10,7 @@ initializing a backend. Three entry points:
   fusion internals intentionally uncounted — they live in VMEM);
 - `allreduce_payload(hlo)`: total payload bytes and op count over
   `all-reduce` / `all-reduce-start` defining lines of a partitioned
-  module (the per-device wire-volume invariant scaling_analysis gates).
+  module (the per-device wire volume of a sharded step).
 
 tools/hlo_bytes.py is a thin CLI wrapper over this module, and
 analysis/jaxcost.py re-exports `shape_bytes` so jaxpr-level and

@@ -27,7 +27,7 @@ equation (recursing through pjit/scan/cond/while sub-jaxprs) for:
 Entry points: `audit_fn` on any callable, `audit_train_step` on a
 jit.TrainStep, `audit_decode_programs` on the four decode sub-programs
 that serve both the dense and paged paths (models/generation.py).
-bench.py calls these before timing so a perf run fails loudly instead
+Call these before timing, so that a perf run fails loudly instead
 of quietly timing a host round-trip.
 """
 from __future__ import annotations

@@ -1,28 +1,16 @@
-"""Ragged paged-attention decode step (pure-JAX reference).
+"""The decode programs every served family shares: the scan, the packed
+upload, the sampler.
 
-One decode step for N sequences at DIFFERENT positions against the
-block-pooled KV cache (serving/paged_cache.py): per layer, the new
-token's K/V is scattered into each sequence's reserved (block, offset)
-slot, the sequence's context is gathered back through its block table,
-and attention is masked per-sequence by length. This is the reference
-semantics of the TPU Ragged Paged Attention kernel (PAPERS.md, arxiv
-2604.15464) — block-table gather + ragged length masking — kept in
-plain jnp so XLA owns the schedule; a pallas kernel can swap in under
-the same signature later.
+A family is a `models.spec.ModelSpec` (its layers and the layout of what
+it caches); the pools' format is `paged_cache`'s. This module owns what
+is the same for all of them:
 
-Parity contract: the math is NOT re-implemented — embedding, per-layer
-qkv, the attention block and the LM head are the SAME top-level jitted
-sub-programs generation.decode_step is composed of (_token_embed,
-_decode_qkv, _decode_attn, _decode_head). When max_blocks_per_seq *
-block_size == max_seq_len the gathered context has the exact dense
-cache layout (position p = block p//bs, slot p%bs) and the same shape,
-so XLA reuses the identical compiled executables for both paths; since
-out-of-length positions are masked to -1e30 before softmax (erasing
-pool garbage exactly: masked probs are exact zeros), the logits are
-bitwise-identical to generation.decode_step (tests/test_serving.py
-pins this). Padded bucket rows write out of bounds (dropped) and
-attend only to block-table padding that their mask erases; their
-logits are garbage and the engine ignores them.
+- `paged_decode_step`: one decode step for N sequences at DIFFERENT
+  positions against the block pools, composed of the spec's functions.
+- `fused_decode_chunk`: k such steps as ONE `lax.scan` on the device
+  with sampling and termination in the carry. The host uploads one packed
+  int32 array (layout at `PACK_COLS`) and fetches one int32 result.
+- `_sample_rows`: the branchless per-row sampler inside the scan.
 
 Batch shape: everything here is shape-polymorphic only in
 (N, max_blocks_per_seq, num_blocks). Under the default ragged kernel
@@ -38,6 +26,10 @@ sample), and the trip that consumes the last prompt token samples the
 request's first output in-scan. Long prompts therefore never
 monopolise a step: they are split into k-token chunks admitted
 alongside decode slots (scheduler.prefill_chunk_threshold).
+
+No model module is imported here: the (L, H, D, S) tuple of
+models/generation.py still names that family's spec (`as_spec`), looked
+up when a tuple is given.
 """
 from __future__ import annotations
 
@@ -48,95 +40,28 @@ import jax.numpy as jnp
 from jax import lax
 
 from ...core.anomaly import rows_not_finite
-from ...models import generation as _gen
-from ...models.generation import (_attn_merge, _decode_attn, _decode_head,
-                                  _decode_qkv, _token_embed)
 from ...models.spec import ModelSpec, merge_counts
-from ...ops.pallas import ragged_paged_attention as _ragged
+from .paged_cache import gather_rows, pool_geometry
 
 __all__ = ["gather_block_kv", "paged_decode_step", "fused_decode_chunk",
-           "PACK_COLS", "pack_f32", "gpt2_spec", "as_spec"]
+           "PACK_COLS", "pack_f32", "as_spec"]
 
 
 def gather_block_kv(pool, block_tables):
     """[num_blocks, bs, H, D] pool + [N, MB] tables -> [N, H, MB*bs, D]
-    contiguous per-sequence context, positions in block-table order."""
-    n, mb = block_tables.shape
-    bs, h, d = pool.shape[1], pool.shape[2], pool.shape[3]
-    ctx = pool[block_tables]                     # [N, MB, bs, H, D]
-    return ctx.reshape(n, mb * bs, h, d).transpose(0, 2, 1, 3)
-
-
-@jax.jit
-def _pool_write_gather(kp, vp, k_new, v_new, slot_blocks, slot_offsets,
-                       block_tables):
-    """Scatter the new token's K/V [N, H, 1, D] into each sequence's
-    (block, offset) slot — out-of-range slot_blocks (padded rows) are
-    dropped — then gather each sequence's context back through its
-    block table."""
-    kp = kp.at[slot_blocks, slot_offsets].set(k_new[:, :, 0], mode="drop")
-    vp = vp.at[slot_blocks, slot_offsets].set(v_new[:, :, 0], mode="drop")
-    return (kp, vp,
-            gather_block_kv(kp, block_tables),
-            gather_block_kv(vp, block_tables))
-
-
-@jax.jit
-def _pool_write(kp, vp, k_new, v_new, slot_blocks, slot_offsets):
-    """Scatter-only variant of _pool_write_gather for the ragged kernel
-    path: the kernel reads the pools through the block table itself, so
-    no gathered context is materialised."""
-    kp = kp.at[slot_blocks, slot_offsets].set(k_new[:, :, 0], mode="drop")
-    vp = vp.at[slot_blocks, slot_offsets].set(v_new[:, :, 0], mode="drop")
-    return kp, vp
-
-
-@functools.lru_cache(maxsize=None)
-def gpt2_spec(geom) -> ModelSpec:
-    """models/generation.py as a ModelSpec, the first one: geom is its
-    static geometry (num_layers, num_heads, head_dim, max_seq_len). The
-    functions are the shared jitted sub-programs of generation.decode_step
-    themselves, in the order the decode programs always called them, so
-    the spec changes no program (tests/test_serving_spec.py compares the
-    lowered text's shape with the pre-seam form)."""
-    num_layers, num_heads, head_dim, max_seq = geom
-
-    def decode_layer(params, i, x, pool, slot_blocks, slot_offsets, tables,
-                     positions, att_lens, live, ragged):
-        kp, vp = pool
-        qkv = _decode_qkv(params, i, x, geom)     # [3, N, H, 1, D]
-        if ragged and _ragged.route_gate(head_dim, num_heads, kp.shape[1]):
-            kp, vp = _pool_write(
-                kp, vp, qkv[1], qkv[2], slot_blocks, slot_offsets)
-            att = _ragged.ragged_decode_attention(
-                qkv[0][:, :, 0, :], kp, vp, tables, att_lens)
-            x = _attn_merge(params, i, x, att[:, :, None, :], geom)
-        else:
-            kp, vp, kc, vc = _pool_write_gather(
-                kp, vp, qkv[1], qkv[2], slot_blocks, slot_offsets, tables)
-            x = _decode_attn(params, i, x, qkv[0], kc, vc, positions, geom)
-        return x, (kp, vp), None
-
-    return ModelSpec(
-        family="gpt2", num_layers=num_layers, max_seq_len=max_seq,
-        cache_layout="heads", cache_shape=(num_heads, head_dim),
-        cache_dtype="float32", embed=_token_embed,
-        decode_layer=decode_layer, head=_decode_head,
-        prefill=lambda params, ids: _gen.prefill(params, ids, geom)
-        + (None,),
-        config=geom)
+    contiguous per-sequence context, positions in block-table order: the
+    dense cache's heads-major layout."""
+    return gather_rows(pool, block_tables).transpose(0, 2, 1, 3)
 
 
 def as_spec(geom) -> ModelSpec:
     """A ModelSpec as it is; the (L, H, D, S) tuple of models/generation.py
-    names that family's spec (`gpt2_spec`), so every caller that holds a
-    GPT geometry keeps working."""
-    return geom if isinstance(geom, ModelSpec) else gpt2_spec(tuple(geom))
-
-
-def _pool_geometry(pools):
-    """(num_blocks, block_size) of either cache layout."""
-    return jax.tree_util.tree_leaves(pools)[0].shape[:2]
+    names that family's spec, so every caller that holds a GPT geometry
+    keeps working."""
+    if isinstance(geom, ModelSpec):
+        return geom
+    from ...models.generation import serving_spec
+    return serving_spec(tuple(geom))
 
 
 def paged_decode_step(params, pools, tokens, positions, block_tables,
@@ -158,8 +83,8 @@ def paged_decode_step(params, pools, tokens, positions, block_tables,
 
     Returns (logits [N, V], updated pools). Composed of the spec's
     functions — for GPT-2 the shared jitted sub-programs of
-    generation.decode_step plus the pool scatter/gather above, see the
-    parity contract in the module docstring.
+    generation.decode_step around the pool's write and gather, see the
+    parity contract at `models.generation.serving_spec`.
     """
     spec = as_spec(geom)
     tokens = jnp.asarray(tokens, jnp.int32)
@@ -300,7 +225,7 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
     spec = as_spec(geom)
     tables = packed[:, PACK_COLS + k:]
     feed = packed[:, PACK_COLS:PACK_COLS + k].T      # [k, N] prompt feed
-    num_blocks, block_size = _pool_geometry(pools)
+    num_blocks, block_size = pool_geometry(pools)
     n = packed.shape[0]
     active = packed[:, 2] > 0
     max_out = packed[:, 4]

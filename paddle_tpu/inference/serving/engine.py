@@ -626,16 +626,13 @@ class LLMEngine:
         self.spec = spec
         self.config = config
         self.max_blocks_per_seq = S // config.block_size
-        latent = spec.cache_layout == "latent"
-        heads, head_dim = (0, 0) if latent else spec.cache_shape
         self.cache = PagedKVCache(
-            spec.num_layers, heads, head_dim, config.num_blocks,
+            spec.num_layers, spec.cache_shape, config.num_blocks,
             config.block_size, dtype=jnp.dtype(spec.cache_dtype),
             enable_prefix_cache=config.enable_prefix_cache,
             host_tier_blocks=config.host_tier_blocks,
             promote_timeout_s=config.promote_timeout_s,
-            kv_cache_dtype=config.kv_cache_dtype,
-            latent_width=spec.cache_shape[0] if latent else None)
+            kv_cache_dtype=config.kv_cache_dtype)
         cost_model = config.prefill_cost_model
         if cost_model == "auto":
             # committed-plan admission pricing; a repo without a plan
@@ -687,13 +684,10 @@ class LLMEngine:
 
     @classmethod
     def from_model(cls, model, config: EngineConfig = None, faults=None):
-        if hasattr(model, "serving_spec"):
-            geom = model.serving_spec()
-        else:
-            cfg = model.cfg
-            geom = (cfg.num_layers, cfg.num_heads,
-                    cfg.hidden_size // cfg.num_heads, cfg.max_seq_len)
-        return cls(gen.extract_params(model), geom, config, faults=faults)
+        """Serve `model`: the family says how (`model.serving_spec()`), its
+        named parameters are the programs' `params`."""
+        return cls(gen.extract_params(model), model.serving_spec(), config,
+                   faults=faults)
 
     # ------------------------------------------------------------ intake
     def add_request(self, prompt_ids, sampling: SamplingParams = None,
@@ -1543,9 +1537,11 @@ class LLMEngine:
         if counts is None:
             return {}
         named = {n: int(c) for n, c in zip(self.spec.counters, counts)}
-        self.stats.moe_pairs += named["moe_pairs"]
-        self.stats.moe_experts_hit += named["moe_experts_hit"]
-        self.stats.moe_full_buffer_layers += named["moe_full_buffer_layers"]
+        for name, count in named.items():
+            # a count the engine keeps no counter for (a maximum, as
+            # `moe_max_load`) goes to the span alone
+            if name in _STAT_EVENTS:
+                setattr(self.stats, name, getattr(self.stats, name) + count)
         return named
 
     @holds_lock("_lock")

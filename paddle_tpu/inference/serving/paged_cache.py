@@ -79,7 +79,36 @@ from . import kv_quant
 from .host_tier import HostTierStore
 from .prefix_cache import PrefixCacheIndex, PrefixNode
 
-__all__ = ["PagedKVCache", "CacheExhausted"]
+__all__ = ["PagedKVCache", "CacheExhausted", "write_rows", "gather_rows",
+           "pool_geometry"]
+
+
+#: len(cache_shape) -> the layout (models/spec.py): (H, D) is a (k, v) pair
+#: of pools a layer, (W,) one pool
+_LAYOUTS = {2: "heads", 1: "latent"}
+
+
+def pool_geometry(pools):
+    """(num_blocks, block_size) of the pools of either layout."""
+    return jax.tree_util.tree_leaves(pools)[0].shape[:2]
+
+
+def write_rows(pool, rows, slot_blocks, slot_offsets):
+    """Write rows [N, ...] into pool [num_blocks, block_size, ...] at
+    (slot_blocks[n], slot_offsets[n]): the decode layer's cache write, for
+    any per-position shape. Out-of-range block ids (num_blocks: padded and
+    frozen rows) are dropped."""
+    return pool.at[slot_blocks, slot_offsets].set(rows.astype(pool.dtype),
+                                                  mode="drop")
+
+
+def gather_rows(pool, tables):
+    """pool [num_blocks, block_size, ...] through block tables [N, MB] ->
+    [N, MB * block_size, ...]: each sequence's context, positions in
+    block-table order (position p = block p // block_size, slot
+    p % block_size), live or not."""
+    n, mb = tables.shape
+    return pool[tables].reshape((n, mb * pool.shape[1]) + pool.shape[2:])
 
 
 # ptlint: disable=PT-T009  agrees with the committed plan entry
@@ -149,10 +178,17 @@ class CacheExhausted(RuntimeError):
 class PagedKVCache:
     """Fixed-size per-layer KV block pools with alloc/free accounting.
 
-    Pools: L-tuple of (k_pool, v_pool), each [num_blocks, block_size, H,
-    D]. Token position p of a sequence lives in its block table entry
-    p // block_size at slot offset p % block_size — the identity layout
-    that makes the gathered context bitwise-match the dense cache.
+    Pools: an L-tuple of the layer's cached leaf, built from
+    `cache_shape`, the per-position shape of ONE pool (what
+    `ModelSpec.cache_shape` says): (H, D) gives a (k_pool, v_pool) pair,
+    each [num_blocks, block_size, H, D] (`layout` "heads"); (W,) gives one
+    pool [num_blocks, block_size, W] (`layout` "latent"). This module is
+    the format's one owner: a decode layer writes and reads a pool through
+    `write_rows` / `gather_rows`, a prefill through
+    `write_prefill_scatter`. Token position p of a sequence lives in its
+    block table entry p // block_size at slot offset p % block_size — the
+    identity layout that makes the gathered context bitwise-match the
+    dense cache.
 
     Block lifecycle: free list -> owned (refcount = number of tables
     holding the block) -> either back to the free list at refcount 0,
@@ -164,16 +200,22 @@ class PagedKVCache:
     allocated == freed zero-leak reconciliation.
     """
 
-    def __init__(self, num_layers: int, num_heads: int, head_dim: int,
+    def __init__(self, num_layers: int, cache_shape: Tuple[int, ...],
                  num_blocks: int, block_size: int, dtype=jnp.float32,
                  enable_prefix_cache: bool = False,
                  host_tier_blocks: int = 0,
                  promote_timeout_s: Optional[float] = None,
-                 kv_cache_dtype: str = "float32",
-                 latent_width: Optional[int] = None):
+                 kv_cache_dtype: str = "float32"):
         if num_blocks <= 0 or block_size <= 0:
             raise ValueError("num_blocks and block_size must be positive")
-        if latent_width is not None:
+        cache_shape = tuple(cache_shape)
+        if len(cache_shape) not in _LAYOUTS:
+            raise ValueError(
+                f"cache_shape must be (num_heads, head_dim) or "
+                f"(latent_width,), got {cache_shape!r}")
+        #: the layout's name as models/spec.py has it (`cache_layout`)
+        self.layout = _LAYOUTS[len(cache_shape)]
+        if self.layout == "latent":
             # one pool a layer; what still assumes (k, v) pairs of heads
             # refuses here, by name, and never falls back
             for feature, asked in (
@@ -192,18 +234,15 @@ class PagedKVCache:
                 f"kv_cache_dtype must be 'float32' or 'int8', got "
                 f"{kv_cache_dtype!r}")
         self.num_layers = num_layers
-        self.num_heads = num_heads
-        self.head_dim = head_dim
+        self.cache_shape = cache_shape
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.kv_cache_dtype = kv_cache_dtype
-        self.latent_width = latent_width
-        shape = (num_blocks, block_size, num_heads, head_dim)
-        if latent_width is not None:
+        shape = (num_blocks, block_size) + cache_shape
+        if self.layout == "latent":
             self._qpools = None
-            self._pools = tuple(
-                jnp.zeros((num_blocks, block_size, latent_width), dtype)
-                for _ in range(num_layers))
+            self._pools = tuple(jnp.zeros(shape, dtype)
+                                for _ in range(num_layers))
         elif kv_cache_dtype == "int8":
             # quantized pool mode (module docstring): int8 codes +
             # per-(block, head) scales; the `pools` property is the
@@ -211,9 +250,10 @@ class PagedKVCache:
             self._qpools = tuple(
                 (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8))
                 for _ in range(num_layers))
+            scales = (num_blocks, cache_shape[0])      # per (block, head)
             self._scales = tuple(
-                (jnp.zeros((num_blocks, num_heads), jnp.float32),
-                 jnp.zeros((num_blocks, num_heads), jnp.float32))
+                (jnp.zeros(scales, jnp.float32),
+                 jnp.zeros(scales, jnp.float32))
                 for _ in range(num_layers))
         else:
             self._qpools = None
@@ -951,7 +991,7 @@ class PagedKVCache:
         return ids
 
     def _heads_layout_only(self, feature: str) -> None:
-        if self.latent_width is not None:
+        if self.layout != "heads":
             raise NotImplementedError(
                 f"the latent cache layout does not support {feature} yet")
 

@@ -212,8 +212,8 @@ def bert_pretrain_loss_fn(model, input_ids, token_type_ids, mlm_labels,
 
 def make_bert_pretrain_batch(rng, vocab_size, bs, seq, mask_rate=0.15):
     """Synthetic MLM+NSP pretraining batch in the masked-position layout
-    the head expects (bench.py, examples/bert_pretrain.py, tools/bert_cost
-    all share this recipe — keep the contract in one place).
+    the head expects (examples/bert_pretrain.py and the tests share this
+    recipe — keep the contract in one place).
 
     Returns numpy arrays (input_ids, token_type_ids, mlm_labels,
     nsp_labels, masked_positions); P = round(mask_rate*seq) positions per

@@ -24,8 +24,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas import ragged_paged_attention as _ragged
+from .spec import ModelSpec
+
 __all__ = ["extract_params", "prefill", "decode_step", "generate",
-           "beam_search_generate"]
+           "beam_search_generate", "serving_spec"]
 
 
 def extract_params(model) -> dict:
@@ -65,7 +68,7 @@ def _block(p, i, x, q, k_cache, v_cache, pos_mask, geom):
     q [B, H, t, D] against the cache, then the MLP.
     k_cache/v_cache: [B, H, S, D]; pos_mask True=attend — [t, S] shared
     across the batch (dense decode) or [B, 1, t, S] per-sequence (the
-    ragged paged-attention path, inference/serving/attention.py)."""
+    ragged paged-attention path, `serving_spec`)."""
     _, H, D, _ = geom
     pre = f"blocks.{i}."
     B, t = x.shape[0], x.shape[1]
@@ -137,7 +140,7 @@ def prefill(params, input_ids, geom):
 
 # --------------------------------------------------------------------------
 # The decode step is DECOMPOSED into top-level jitted sub-programs shared
-# with the paged serving path (inference/serving/attention.py): embed,
+# with the paged serving path (`serving_spec` below): embed,
 # per-layer qkv, per-layer attention+MLP, final head. Two monolithic jits
 # (dense decode_step vs paged decode) fuse differently and drift by ~1e-7
 # per step (measured on the CPU backend); routing BOTH paths through the
@@ -211,6 +214,64 @@ def decode_step(params, cache, token, pos, geom):
         new_cache.append((kc, vc))
         x = _decode_attn(params, i, x, qkv[0], kc, vc, positions, geom)
     return _decode_head(params, x), tuple(new_cache)
+
+
+@functools.lru_cache(maxsize=None)
+def serving_spec(geom) -> ModelSpec:
+    """This family as the `ModelSpec` `LLMEngine` serves it through, the
+    first one: geom is the static geometry (num_layers, num_heads,
+    head_dim, max_seq_len), and the (L, H, D, S) tuple names this spec
+    wherever the serving layer takes a spec (`serving.attention.as_spec`).
+
+    Parity contract: the math is NOT re-implemented. Embedding, per-layer
+    qkv, the attention block and the LM head are the SAME top-level jitted
+    sub-programs `decode_step` is composed of (_token_embed, _decode_qkv,
+    _decode_attn, _decode_head); between them a layer writes the new
+    token's K/V into the pool and reads the context back through the block
+    table (`paged_cache.write_rows` / `gather_rows`). When
+    max_blocks_per_seq * block_size == max_seq_len the gathered context,
+    transposed to heads-major, has the exact dense cache layout (position
+    p = block p // bs, slot p % bs) and the same shape, so XLA reuses the
+    identical compiled executables for both paths; since out-of-length
+    positions are masked to -1e30 before softmax (erasing pool garbage
+    exactly: masked probs are exact zeros), the logits are
+    bitwise-identical to `decode_step` (tests/test_serving.py pins this).
+    Padded rows write out of bounds (dropped) and attend only to
+    block-table padding that their mask erases; their logits are garbage
+    and the engine ignores them. Under `ragged` on a backend that has the
+    kernel (ops/pallas/ragged_paged_attention.route_gate) the pools are
+    read through the block table inside the kernel and no context is
+    gathered; off-TPU both modes lower to the gather + composed attention.
+    tests/test_serving_spec.py holds the three programs' names and
+    argument counts."""
+    # at call time: the serving package imports this module
+    from ..inference.serving.paged_cache import gather_rows, write_rows
+    num_layers, num_heads, head_dim, max_seq = geom
+
+    def decode_layer(params, i, x, pool, slot_blocks, slot_offsets, tables,
+                     positions, att_lens, live, ragged):
+        kp, vp = pool
+        qkv = _decode_qkv(params, i, x, geom)     # [3, N, H, 1, D]
+        kp = write_rows(kp, qkv[1][:, :, 0], slot_blocks, slot_offsets)
+        vp = write_rows(vp, qkv[2][:, :, 0], slot_blocks, slot_offsets)
+        if ragged and _ragged.route_gate(head_dim, num_heads, kp.shape[1]):
+            att = _ragged.ragged_decode_attention(
+                qkv[0][:, :, 0, :], kp, vp, tables, att_lens)
+            x = _attn_merge(params, i, x, att[:, :, None, :], geom)
+        else:
+            # heads-major [N, H, S, D]: the dense cache _decode_attn takes
+            kc, vc = (gather_rows(p, tables).transpose(0, 2, 1, 3)
+                      for p in (kp, vp))
+            x = _decode_attn(params, i, x, qkv[0], kc, vc, positions, geom)
+        return x, (kp, vp), None
+
+    return ModelSpec(
+        family="gpt2", num_layers=num_layers, max_seq_len=max_seq,
+        cache_layout="heads", cache_shape=(num_heads, head_dim),
+        cache_dtype="float32", embed=_token_embed,
+        decode_layer=decode_layer, head=_decode_head,
+        prefill=lambda params, ids: prefill(params, ids, geom) + (None,),
+        config=geom)
 
 
 @functools.lru_cache(maxsize=32)

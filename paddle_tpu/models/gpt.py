@@ -299,6 +299,15 @@ class GPT(nn.Layer):
                                             has_bias=False,
                                             gather_output=True)
 
+    def serving_spec(self):
+        """The `ModelSpec` `LLMEngine.from_model` serves this model
+        through (models/generation.py is the family's decode path)."""
+        from .generation import serving_spec
+        cfg = self.cfg
+        return serving_spec((cfg.num_layers, cfg.num_heads,
+                             cfg.hidden_size // cfg.num_heads,
+                             cfg.max_seq_len))
+
     def forward(self, input_ids):
         B, T = input_ids.shape[0], input_ids.shape[1]
         import jax.numpy as jnp

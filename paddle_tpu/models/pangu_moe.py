@@ -68,6 +68,7 @@ import numpy as np
 from .. import nn
 from ..core.dispatch import dispatch
 from ..distributed.moe import held_experts_mlp
+from ..inference.serving.paged_cache import gather_rows, write_rows
 from ..nn import initializer as I
 from .spec import ModelSpec, merge_counts
 
@@ -340,19 +341,17 @@ def _token_embed(params, tokens, positions):
 
 def _decode_layer(cfg, params, i, x, pool, slot_blocks, slot_offsets,
                   tables, positions, att_lens, live, ragged):
-    """One layer for N rows of one token each against the latent pool
-    [num_blocks, block_size, W]: write the token's row at its slot
-    (out-of-range block ids are dropped), gather each row's blocks through
-    its table, attend in the latent space. Composed of XLA operations
-    (`ragged` has no kernel to choose here yet)."""
+    """One layer for N rows of one token each against the latent pool:
+    write the token's row at its slot, gather each row's blocks through
+    its table (`paged_cache.write_rows` / `gather_rows`), attend in the
+    latent space. Composed of XLA operations (`ragged` has no kernel to
+    choose here yet)."""
     pre = f"layers.{i}."
     h = rms_norm(x[:, 0], params[pre + "norm1.weight"], cfg.rms_norm_eps)
     q_nope, q_pe = mla_queries(params, pre, h, positions, cfg)
     row = mla_latent(params, pre, h, positions, cfg)
-    pool = pool.at[slot_blocks, slot_offsets].set(row.astype(pool.dtype),
-                                                  mode="drop")
-    n, mb = tables.shape
-    ctx = pool[tables].reshape(n, mb * pool.shape[1], pool.shape[2])
+    pool = write_rows(pool, row, slot_blocks, slot_offsets)
+    ctx = gather_rows(pool, tables)
     attn = mla_absorbed(params, pre, q_nope, q_pe, ctx.astype(h.dtype),
                         att_lens, cfg)
     y, counts = layer_tail(params, i, x[:, 0], attn, cfg, live)

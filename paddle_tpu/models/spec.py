@@ -2,15 +2,20 @@
 
 `LLMEngine`, `fused_decode_chunk`, `paged_decode_step` and
 `PagedKVCache` own the scheduler, the scan (carry, prompt feed, sampling,
-termination, the packed upload and the one fetch), the allocator and the
-block tables; a family owns its layers and the layout of what it caches.
-The spec is the seam between the two: one frozen (hashable, so usable as a
-jit static argument) object per (family, configuration). Builders are
-`serving.attention.gpt2_spec` (models/generation.py, the first spec) and
-`models.pangu_moe.serving_spec`; both are cached, so the same
-configuration always gives the same object and the same compiled programs.
+termination, the packed upload and the one fetch), the allocator, the
+block tables and the pools' format; a family owns its layers and says
+what one position caches. The spec is the seam between the two: one
+frozen (hashable, so usable as a jit static argument) object per (family,
+configuration). Every family builds its own, `serving_spec(...)` beside
+the functions it wraps (`models.generation.serving_spec`, the first, and
+`models.pangu_moe.serving_spec`); both are cached, so the same
+configuration always gives the same object and the same compiled
+programs. The serving layer imports this module and no family.
 
-Cache layouts:
+Cache layouts (`PagedKVCache` builds the pools from `cache_shape` and
+`cache_dtype`; a decode layer writes and reads them through
+`paged_cache.write_rows` / `gather_rows`, a prefill hands its dense rows
+to `write_prefill_scatter`):
 - "heads":  two pools a layer, (k, v) [num_blocks, block_size, H, D];
             `cache_shape` is (H, D); dense prefill rows are [B, H, S, D];
 - "latent": ONE pool a layer, [num_blocks, block_size, W] (multi-head

@@ -167,11 +167,11 @@ def _bn_train(x, weight, bias, eps, c_axis):
 # scale/shift folded, one bf16-bandwidth pass that XLA fuses into the
 # dx epilogue). Forward never materialises the pre-relu BN output at all.
 #
-# Measured on v5e (ResNet-50 bs128 O2, tools/resnet_sweep.py): throughput
-# NEUTRAL vs the composed path (2518-2544 vs 2509-2540 imgs/s, within the
-# shared-chip ±2% noise) — XLA's scheduler already avoids double-storing
-# the elementwise chain. The op is kept for (a) reference op parity and
-# (b) the smaller residual set (peak-memory headroom at larger batches).
+# An earlier round read it throughput NEUTRAL vs the composed path on a
+# v5e (ResNet-50 bs128 O2; no cell of the benchmark measures it) — XLA's
+# scheduler already avoids double-storing the elementwise chain. The op is
+# kept for (a) reference op parity and (b) the smaller residual set
+# (peak-memory headroom at larger batches).
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))

@@ -4,7 +4,7 @@ The system-metrics half of the unified telemetry layer (docs/
 observability.md). Every hot path in the repo — LLMEngine.step, the
 scheduler, jit.TrainStep, the checkpoint manager, the elastic
 supervisor — records into ONE registry through labeled metric families,
-so the load suite, the chaos runner and bench.py all read the same
+so the load suite, the chaos runner and the benchmark all read the same
 numbers the same way instead of each keeping private accumulator dicts
 (the pre-PR-6 state: EngineStats, profiler tables and bench-local
 timers that could silently disagree).

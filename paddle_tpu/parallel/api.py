@@ -339,7 +339,8 @@ class ShardedTrainStep:
     def compiled_step(self, *args):
         """Compiled step executable — exposes cost_analysis() (per-device
         flops/bytes from XLA's own cost model) and as_text() (partitioned
-        HLO) for compile-level scaling receipts (tools/scaling_analysis.py)."""
+        HLO) for compile-level scaling receipts (chip_smoke.py reads its
+        collectives and its bytes)."""
         return self._lowered(*args).compile()
 
     def compiled_text(self, *args) -> str:

@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives.
 
-Entry points (chip_smoke.py, bench.py, the examples' mains) call
+Entry points (benchmarks/run.py, chip_smoke.py, the examples' mains) call
 `enable_compile_cache()` first thing. It is never called at package
 import: the tests run several workers, and a compile for a described
 (not attached) TPU writes entries a chipless process cannot read back.

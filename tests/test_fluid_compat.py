@@ -2,6 +2,7 @@
 (`import paddle.fluid as fluid` style, reference python/paddle/fluid/).
 """
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.fluid as fluid
@@ -133,6 +134,8 @@ def test_dynamic_decode_minimal_decoder_and_impute():
 def _reference_fluid_layers_names():
     import ast, os
     base = "/root/reference/python/paddle/fluid/layers"
+    if not os.path.isdir(base):
+        pytest.skip(f"no reference checkout at {base}")
     names = set()
     for fn in os.listdir(base):
         if not fn.endswith(".py"):

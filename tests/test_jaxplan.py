@@ -243,7 +243,7 @@ def _scheduler(cost_model, max_prefill_tokens, max_num_seqs=16):
     from paddle_tpu.inference.serving.paged_cache import PagedKVCache
     from paddle_tpu.inference.serving.scheduler import (
         Scheduler, SchedulerConfig)
-    cache = PagedKVCache(1, 1, 4, 256, 4)
+    cache = PagedKVCache(1, (1, 4), 256, 4)
     return Scheduler(
         SchedulerConfig(max_num_seqs=max_num_seqs,
                         max_prefill_tokens=max_prefill_tokens,

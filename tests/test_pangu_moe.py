@@ -19,6 +19,7 @@ from paddle_tpu.inference.serving.attention import (PACK_COLS,
                                                     paged_decode_step)
 from paddle_tpu.inference.serving.paged_cache import PagedKVCache
 from paddle_tpu.models import pangu_moe as pm
+from paddle_tpu.models import generation as gen
 from paddle_tpu.models.generation import extract_params
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
@@ -242,8 +243,36 @@ def test_latent_cache_is_one_pool_a_layer_and_counts_its_bytes():
         num_hidden_layers=5, dtype="bfloat16"))
     assert full.cache_shape == (576,)
     assert full.cache_bytes_per_token == 5760
-    pc = PagedKVCache(3, 0, 0, 16, 4, latent_width=24)
+    pc = PagedKVCache(3, (24,), 16, 4)
     assert [p.shape for p in pc.pools] == [(16, 4, 24)] * 3
+
+
+@pytest.mark.parametrize("family", ["pangu_ultra_moe", "gpt2"])
+def test_the_cache_is_built_from_what_the_spec_says_of_a_position(family):
+    """`PagedKVCache` takes the spec's `cache_shape` and `cache_dtype` and
+    nothing else about layout; `layout` is what the spec calls it. The
+    latent pools are those the `latent_width=` call of PR 29 made."""
+    if family == "gpt2":
+        spec = gen.serving_spec((2, 4, 8, 32))
+        leaf_shapes = [(16, 4, 4, 8)] * 2
+    else:
+        spec = pm.serving_spec(pm.PanguMoEConfig(
+            num_hidden_layers=2, dtype="bfloat16"))
+        leaf_shapes = [(16, 4, 576)]
+    pc = PagedKVCache(spec.num_layers, spec.cache_shape, 16, 4,
+                      dtype=jnp.dtype(spec.cache_dtype))
+    assert pc.layout == spec.cache_layout
+    assert len(pc.pools) == spec.num_layers
+    for leaf in pc.pools:
+        arrays = jax.tree_util.tree_leaves(leaf)
+        assert len(arrays) == spec.pools_per_layer
+        assert [a.shape for a in arrays] == leaf_shapes
+        assert all(a.dtype == jnp.dtype(spec.cache_dtype) for a in arrays)
+
+
+def test_a_cache_shape_that_is_no_layout_is_refused():
+    with pytest.raises(ValueError, match="cache_shape"):
+        PagedKVCache(1, (2, 4, 8), 16, 4)
 
 
 @pytest.mark.parametrize("length", [1, 3, 4, 5, 23])
@@ -252,8 +281,8 @@ def test_write_prefill_on_the_latent_layout_equals_an_eager_loop(length):
     dense = tuple(jnp.asarray(np.where(
         np.arange(24)[None, :, None] < length,
         rng.normal(size=(2, 24, 12)), 0.0), jnp.float32) for _ in range(2))
-    got = PagedKVCache(2, 0, 0, 16, 4, latent_width=12)
-    want = PagedKVCache(2, 0, 0, 16, 4, latent_width=12)
+    got = PagedKVCache(2, (12,), 16, 4)
+    want = PagedKVCache(2, (12,), 16, 4)
     for pc in (got, want):
         pc.allocate("keep", 6)
         pc.pools = tuple(p + 1.0 for p in pc.pools)
@@ -282,8 +311,7 @@ def test_prefill_then_paged_decode_matches_the_reference_forward():
     rng = np.random.default_rng(6)
     prompts = [rng.integers(0, 256, (n,)).astype(np.int32)
                for n in (5, 11, 8)]
-    pc = PagedKVCache(cfg.num_hidden_layers, 0, 0, 32, 4,
-                      latent_width=cfg.latent_width)
+    pc = PagedKVCache(cfg.num_hidden_layers, (cfg.latent_width,), 32, 4)
     toks, seqs = [], []
     for b, p in enumerate(prompts):
         logits, dense, counts = spec.prefill(params,
@@ -369,7 +397,7 @@ def test_chunked_prefill_rides_the_shared_prompt_feed():
 def test_the_chunk_returns_its_counts_as_extra_rows():
     _, cfg, params = _family()                  # all 8 experts held
     spec = pm.serving_spec(cfg)
-    pc = PagedKVCache(3, 0, 0, 16, 8, latent_width=cfg.latent_width)
+    pc = PagedKVCache(3, (cfg.latent_width,), 16, 8)
     k, n, mb = 4, 2, cfg.max_seq_len // 8
     pc.allocate(0, 3)
     pc.reserve_slots(0, k)
@@ -403,7 +431,7 @@ def test_what_the_latent_layout_lacks_raises_by_name(config, feature):
 
 def test_the_host_tier_and_migration_raise_by_name_on_the_latent_layout():
     with pytest.raises(NotImplementedError, match="host tier"):
-        PagedKVCache(1, 0, 0, 8, 4, latent_width=12, host_tier_blocks=2)
+        PagedKVCache(1, (12,), 8, 4, host_tier_blocks=2)
     model, _, _ = _family()
     eng = _engine(model, 8)
     rid = eng.add_request(np.arange(5, dtype=np.int32),

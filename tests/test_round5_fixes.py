@@ -5,7 +5,6 @@ maximum.accumulate / argmax semantics, each run with NEGATIVE axis values
 — the configurations that previously crashed (cummax: lax reject) or
 silently scattered along the wrong dimension (put_along_axis reduce=).
 """
-import json
 
 import numpy as np
 import pytest
@@ -68,38 +67,3 @@ def test_cummax_negative_axis(axis):
     # indices index along the cummax axis: gathering with them rebuilds out
     take = np.take_along_axis(x, idx.numpy().astype("int64"), axis=axis)
     np.testing.assert_allclose(take, out.numpy(), rtol=1e-6)
-
-
-def test_scaling_anchor_reads_bench_line(tmp_path):
-    """ADVICE round 5: the projection anchor must read the headline's
-    `value` key (and verify the metric name), not a metric-named key."""
-    import sys
-    sys.path.insert(0, "tools")
-    try:
-        from scaling_analysis import FLAGSHIP_METRIC, read_flagship_anchor
-    finally:
-        sys.path.pop(0)
-
-    # a bench.py line measured on a chip → anchor derived from it
-    line = tmp_path / "bench_line.json"
-    line.write_text(json.dumps(
-        {"metric": FLAGSHIP_METRIC, "value": 163840.0}))
-    step_s, src = read_flagship_anchor(str(line))
-    assert step_s == pytest.approx(32 * 1024 / 163840.0, abs=1e-4)
-    assert "bench_line.json" in src
-
-    # wrong metric (re-pointed headline) → raises LOUDLY
-    line.write_text(json.dumps(
-        {"metric": "resnet_imgs_per_sec", "value": 9999.0}))
-    with pytest.raises(ValueError, match="headline metric"):
-        read_flagship_anchor(str(line))
-
-    # right metric but malformed value → also loud
-    line.write_text(json.dumps({"metric": FLAGSHIP_METRIC}))
-    with pytest.raises(KeyError):
-        read_flagship_anchor(str(line))
-
-    # missing file → no silent fallback constant: there is no projection
-    # without a step measured on a chip
-    with pytest.raises(OSError):
-        read_flagship_anchor(str(tmp_path / "nope.json"))

@@ -54,7 +54,7 @@ def _reference_tokens(model, prompt, max_new):
 
 # ------------------------------------------------------------ paged cache
 def test_paged_cache_alloc_free_and_exhaustion():
-    pc = PagedKVCache(num_layers=2, num_heads=4, head_dim=8,
+    pc = PagedKVCache(num_layers=2, cache_shape=(4, 8),
                       num_blocks=4, block_size=4)
     assert pc.num_free() == 4 and pc.utilization() == 0.0
     ids = pc.allocate("a", 7)                 # ceil(7/4) = 2 blocks
@@ -97,7 +97,7 @@ def test_write_prefill_roundtrips_dense_cache():
     ids = rng.randint(0, VOCAB, (2, T)).astype(np.int32)
     _, dense = gen.prefill(params, jnp.asarray(ids), geom)
 
-    pc = PagedKVCache(L, H, D, num_blocks=16, block_size=4)
+    pc = PagedKVCache(L, (H, D), num_blocks=16, block_size=4)
     for b, sid in enumerate(("s0", "s1")):
         pc.allocate(sid, T)
         pc.write_prefill(sid, dense, T, batch_index=b)
@@ -108,6 +108,43 @@ def test_write_prefill_roundtrips_dense_cache():
                 got = np.asarray(gather_block_kv(pc.pools[i][j], table))
                 want = np.asarray(dense[i][j][b])[:, :got.shape[2]]
                 np.testing.assert_array_equal(got[0], want)
+
+
+@pytest.mark.parametrize("cache_shape", [(4, 8), (12,)],
+                         ids=["heads", "latent"])
+def test_pool_rows_are_written_and_gathered_as_a_numpy_loop(cache_shape):
+    """`write_rows` / `gather_rows`, what a decode layer of any family
+    does to a pool: one function each for any per-position shape, held to
+    a loop over rows; the row whose block id is out of range (a padded or
+    frozen row: num_blocks) is dropped."""
+    from paddle_tpu.inference.serving.paged_cache import (gather_rows,
+                                                          pool_geometry,
+                                                          write_rows)
+    rng = np.random.default_rng(len(cache_shape))
+    nb, bs, n, mb = 6, 4, 3, 2
+    pool = rng.normal(size=(nb, bs) + cache_shape).astype(np.float32)
+    rows = rng.normal(size=(n,) + cache_shape).astype(np.float32)
+    slot_blocks = np.asarray([5, nb, 2], np.int32)      # row 1: dropped
+    slot_offsets = np.asarray([3, 1, 0], np.int32)
+    tables = np.asarray([[5, 0], [1, 1], [2, 4]], np.int32)
+    assert tuple(pool_geometry((jnp.asarray(pool),))) == (nb, bs)
+
+    want = pool.copy()
+    for r in (0, 2):
+        want[slot_blocks[r], slot_offsets[r]] = rows[r]
+    got = write_rows(jnp.asarray(pool), jnp.asarray(rows), slot_blocks,
+                     slot_offsets)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+    ctx = np.stack([np.concatenate([want[b] for b in tables[r]])
+                    for r in range(n)])                 # [N, MB * bs, ...]
+    got_ctx = np.asarray(gather_rows(got, jnp.asarray(tables)))
+    assert got_ctx.shape == (n, mb * bs) + cache_shape
+    np.testing.assert_array_equal(got_ctx, ctx)
+    if len(cache_shape) == 2:       # GPT-2's heads-major view of the same
+        np.testing.assert_array_equal(
+            np.asarray(gather_block_kv(got, jnp.asarray(tables))),
+            ctx.transpose(0, 2, 1, 3))
 
 
 def _eager_write_prefill(pc, seq_id, dense_cache, batch_index=0):
@@ -125,7 +162,7 @@ def _eager_write_prefill(pc, seq_id, dense_cache, batch_index=0):
         # [H, S, D] -> [S, H, D] -> [n_blocks, bs, H, D]
         blk = dense[batch_index].transpose(1, 0, 2)[:t_pad]
         blk = jnp.pad(blk, ((0, t_pad - blk.shape[0]), (0, 0), (0, 0)))
-        blk = blk.reshape(n_blocks, bs, pc.num_heads, pc.head_dim)
+        blk = blk.reshape((n_blocks, bs) + pc.cache_shape)
         return pool.at[idx].set(blk)
 
     pc.pools = tuple(
@@ -152,7 +189,7 @@ def _wp_cache(fill_seed=None, **kw):
     (three neighbours allocated, the outer two freed again) and, with
     `fill_seed`, whose pools start as noise so that an untouched block
     is told from a zeroed one."""
-    pc = PagedKVCache(_WP_L, _WP_H, _WP_D, num_blocks=_WP_NB,
+    pc = PagedKVCache(_WP_L, (_WP_H, _WP_D), num_blocks=_WP_NB,
                       block_size=_WP_BS, **kw)
     if fill_seed is not None:
         rng = np.random.default_rng(fill_seed)
@@ -291,7 +328,7 @@ def test_paged_decode_bitwise_matches_dense_decode_step():
     logits, cache = gen.prefill(params, jnp.asarray(prompts), geom)
     tok = np.argmax(np.asarray(logits), -1).astype(np.int32)
 
-    pc = PagedKVCache(L, H, D, num_blocks=16, block_size=bs)
+    pc = PagedKVCache(L, (H, D), num_blocks=16, block_size=bs)
     for b in range(B):
         pc.allocate(b, T)
         pc.write_prefill(b, cache, T, batch_index=b)
@@ -326,7 +363,7 @@ def test_paged_decode_ragged_positions_match_per_row_dense():
     lens = [3, 7, 5]
     prompts = [rng.randint(0, VOCAB, (t,)).astype(np.int32) for t in lens]
 
-    pc = PagedKVCache(L, H, D, num_blocks=16, block_size=bs)
+    pc = PagedKVCache(L, (H, D), num_blocks=16, block_size=bs)
     dense_rows, toks = [], []
     for b, p in enumerate(prompts):
         lg, dc = gen.prefill(params, jnp.asarray(p[None], jnp.int32), geom)

@@ -102,7 +102,7 @@ def test_fused_k_step_bitwise_matches_k_single_steps(model, sampling):
     k = 8
 
     def run(chunks):
-        cache = PagedKVCache(num_layers=L, num_heads=H, head_dim=D,
+        cache = PagedKVCache(num_layers=L, cache_shape=(H, D),
                              num_blocks=nb, block_size=bs)
         state = []
         for i, p in enumerate(prompts):

@@ -65,7 +65,7 @@ def test_committed_bound_is_available():
 # --------------------------------------------------------- pool mode
 def test_int8_pool_write_read_within_committed_bound():
     rng = np.random.RandomState(0)
-    c = PagedKVCache(2, 4, 8, 16, 4, kv_cache_dtype="int8")
+    c = PagedKVCache(2, (4, 8), 16, 4, kv_cache_dtype="int8")
     want = _rand_pools(rng, 2, (16, 4, 4, 8))
     c.pools = want
     got = c.pools
@@ -80,7 +80,7 @@ def test_int8_unchanged_blocks_are_bit_stable():
     chunk's pool rebind amounts to for untouched blocks) must leave
     codes AND scales bit-identical — the monotone-scale contract."""
     rng = np.random.RandomState(1)
-    c = PagedKVCache(2, 4, 8, 16, 4, kv_cache_dtype="int8")
+    c = PagedKVCache(2, (4, 8), 16, 4, kv_cache_dtype="int8")
     c.pools = _rand_pools(rng, 2, (16, 4, 4, 8))
     q0 = [(np.asarray(qk), np.asarray(qv)) for qk, qv in c._qpools]
     s0 = [(np.asarray(sk), np.asarray(sv)) for sk, sv in c._scales]
@@ -100,7 +100,7 @@ def test_int8_reused_blocks_reset_scale_and_content():
     next write's error is bounded by the NEW content's absmax, not the
     previous tenant's."""
     rng = np.random.RandomState(2)
-    c = PagedKVCache(1, 2, 4, 8, 2, kv_cache_dtype="int8")
+    c = PagedKVCache(1, (2, 4), 8, 2, kv_cache_dtype="int8")
     ids = c.allocate("big", 16)
     # large-magnitude tenant -> large scales
     c.pools = tuple((jnp.asarray(100.0 * rng.randn(8, 2, 2, 4)
@@ -125,13 +125,13 @@ def test_int8_reused_blocks_reset_scale_and_content():
 
 def test_kv_cache_dtype_validated():
     with pytest.raises(ValueError, match="kv_cache_dtype"):
-        PagedKVCache(1, 2, 4, 8, 2, kv_cache_dtype="int4")
+        PagedKVCache(1, (2, 4), 8, 2, kv_cache_dtype="int4")
 
 
 def test_float32_mode_keeps_plain_storage():
     """The default mode must stay the historical bitwise path: the
     pools property returns the storage itself, no codec in the loop."""
-    c = PagedKVCache(1, 2, 4, 8, 2)
+    c = PagedKVCache(1, (2, 4), 8, 2)
     assert c._qpools is None
     p = c.pools
     assert p is c._pools
@@ -190,7 +190,7 @@ def test_engine_int8_greedy_parity_and_bound():
 # ---------------------------------------------------- quantized spill
 def _spill_cache(**kw):
     kw.setdefault("kv_cache_dtype", "int8")
-    return PagedKVCache(2, 2, 4, 8, 2, enable_prefix_cache=True,
+    return PagedKVCache(2, (2, 4), 8, 2, enable_prefix_cache=True,
                         host_tier_blocks=8, **kw)
 
 
@@ -222,7 +222,7 @@ def test_int8_spill_payload_is_quantized_and_promotes_within_bound():
     assert len(payload) == c.num_layers + 1
     assert all(p[0].dtype == np.int8 for p in payload[:-1])
     assert payload[-1][0].dtype == np.float32
-    assert payload[-1][0].shape == (c.num_layers, c.num_heads)
+    assert payload[-1][0].shape == (c.num_layers, c.cache_shape[0])
 
     res = c.ensure_promoted(toks + [99])
     assert res["outcomes"] == ["hit"] * 8
@@ -270,6 +270,6 @@ def test_int8_export_prefix_ships_uniform_f32_to_peers():
         assert all(a.dtype == np.float32 for pair in payload
                    for a in pair)
         assert c._payload_digest(payload) == digest
-    peer = PagedKVCache(2, 2, 4, 8, 2, enable_prefix_cache=True)
+    peer = PagedKVCache(2, (2, 4), 8, 2, enable_prefix_cache=True)
     assert peer.admit_prefix(exp["tokens"], exp["blocks"]) == 8
     peer.check_integrity()

@@ -172,7 +172,7 @@ def test_refcount_zero_leak_under_churn():
     allocate-with-prefix, grow, free (randomly scrubbed, randomly
     registered) — then the audit must reconcile to the empty state."""
     rng = np.random.RandomState(3)
-    cache = PagedKVCache(num_layers=2, num_heads=2, head_dim=4,
+    cache = PagedKVCache(num_layers=2, cache_shape=(2, 4),
                          num_blocks=48, block_size=4,
                          enable_prefix_cache=True)
     tpls = [rng.randint(1, 50, (16,)).tolist() for _ in range(5)]
@@ -274,7 +274,7 @@ def test_scrub_is_refcount_aware():
     zero it under the other sharer — the block is tainted (dropped from
     the trie, never re-indexed) and scrubbed only at its LAST free."""
     import jax.numpy as jnp
-    cache = PagedKVCache(num_layers=1, num_heads=1, head_dim=2,
+    cache = PagedKVCache(num_layers=1, cache_shape=(1, 2),
                          num_blocks=8, block_size=4,
                          enable_prefix_cache=True)
     tpl = np.arange(1, 9, dtype=np.int32)           # 8 tokens, 2 blocks

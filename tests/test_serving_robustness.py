@@ -252,7 +252,7 @@ def test_requeue_preserves_arrival_order():
     its ORIGINAL FCFS position, ahead of later arrivals (appendleft
     would also pass this one, but inverts multi-request recovery order —
     covered below)."""
-    cache = PagedKVCache(num_layers=1, num_heads=2, head_dim=4,
+    cache = PagedKVCache(num_layers=1, cache_shape=(2, 4),
                          num_blocks=16, block_size=4)
     sched = Scheduler(SchedulerConfig(max_num_seqs=4), cache)
     reqs = [Request(request_id=f"r{i}", prompt_ids=np.ones(3, np.int32),

@@ -11,8 +11,7 @@ import pytest
 
 from paddle_tpu.inference.serving import EngineConfig, LLMEngine
 from paddle_tpu.inference.serving.attention import (PACK_COLS, as_spec,
-                                                    fused_decode_chunk,
-                                                    gpt2_spec)
+                                                    fused_decode_chunk)
 from paddle_tpu.inference.serving.paged_cache import (PagedKVCache,
                                                       write_prefill_scatter)
 from paddle_tpu.models import generation as gen
@@ -30,7 +29,7 @@ def gpt():
 
 
 def _lowered(params):
-    pc = PagedKVCache(2, 2, 64, 64, 8)
+    pc = PagedKVCache(2, (2, 64), 64, 8)
     k = 8
     packed = np.zeros((4, PACK_COLS + k + 16), np.int32)
     ids = jnp.zeros((1, 24), jnp.int32)
@@ -69,11 +68,11 @@ def test_gpt2_programs_keep_names_and_argument_shapes(gpt, name, args,
 
 def test_the_tuple_and_the_spec_lower_the_chunk_to_the_same_text(gpt):
     params = gpt[1]
-    pc = PagedKVCache(2, 2, 64, 64, 8)
+    pc = PagedKVCache(2, (2, 64), 64, 8)
     packed = np.zeros((4, PACK_COLS + 8 + 16), np.int32)
     texts = [fused_decode_chunk.lower(params, pc.pools, packed, g, 8,
                                       "ragged").as_text()
-             for g in (GEOM, gpt2_spec(GEOM))]
+             for g in (GEOM, gen.serving_spec(GEOM))]
     assert hashlib.sha1(texts[0].encode()).hexdigest() \
         == hashlib.sha1(texts[1].encode()).hexdigest()
     assert texts[0].count("stablehlo.sort") == texts[1].count(
@@ -82,7 +81,7 @@ def test_the_tuple_and_the_spec_lower_the_chunk_to_the_same_text(gpt):
 
 def test_a_geometry_tuple_names_the_gpt2_spec(gpt):
     spec = as_spec(GEOM)
-    assert isinstance(spec, ModelSpec) and spec is gpt2_spec(GEOM)
+    assert isinstance(spec, ModelSpec) and spec is gen.serving_spec(GEOM)
     assert as_spec(spec) is spec
     assert (spec.family, spec.cache_layout, spec.cache_shape,
             spec.pools_per_layer, spec.counters) \
@@ -90,11 +89,37 @@ def test_a_geometry_tuple_names_the_gpt2_spec(gpt):
     assert spec.cache_bytes_per_token == 2 * 2 * 2 * 64 * 4
     eng = LLMEngine.from_model(gpt[0], EngineConfig(block_size=8,
                                                     num_blocks=32))
-    assert eng.geom == GEOM and eng.spec is spec
+    assert eng.geom is spec and eng.spec is spec
     assert eng.stats.cache_bytes_per_token == spec.cache_bytes_per_token
     assert [p.shape for p in eng.cache.pools[0]] == [(32, 8, 2, 64)] * 2
     # the counters of an expert family stay at zero for GPT-2
     assert eng.stats.moe_pairs == 0 and eng.stats.moe_experts_hit == 0
+
+
+def test_the_scan_module_imports_no_family():
+    """serving/attention.py is the scan, the packed upload and the sampler
+    of EVERY family: at import time it names no module under
+    paddle_tpu/models/ but the seam (`spec`)."""
+    import ast
+    import paddle_tpu.inference.serving.attention as attention
+    tree = ast.parse(open(attention.__file__).read())
+    named = []
+    for node in tree.body:                       # top-level statements
+        if isinstance(node, ast.Import):
+            named += [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            base = "." * node.level + (node.module or "")
+            named += [base] + [f"{base}.{a.name}" for a in node.names]
+    models = [n for n in named if "models" in n.split(".")]
+    assert models and all(
+        n.lstrip(".").startswith("models.spec") for n in models), models
+
+
+def test_the_gpt_layer_names_its_own_spec(gpt):
+    """`LLMEngine.from_model` asks every model the same question."""
+    spec = gpt[0].serving_spec()
+    assert spec is as_spec(GEOM) and spec is gen.serving_spec(GEOM)
+    assert spec.config == GEOM
 
 
 def test_a_span_takes_stats_from_inside_its_scope(tmp_path):

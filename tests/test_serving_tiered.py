@@ -211,7 +211,7 @@ def _demoted_chain(host_blocks=8, promote_timeout_s=None):
     demoted to the host tier, with recognizable per-block payloads.
     Returns (cache, tokens, template_blocks)."""
     import jax.numpy as jnp
-    cache = PagedKVCache(num_layers=1, num_heads=1, head_dim=2,
+    cache = PagedKVCache(num_layers=1, cache_shape=(1, 2),
                          num_blocks=8, block_size=4,
                          enable_prefix_cache=True,
                          host_tier_blocks=host_blocks,
@@ -292,7 +292,7 @@ def test_taint_poisons_host_copy_and_never_spills():
     surviving sharer's device blocks are not zeroed under it; tainted
     blocks never reach the host store."""
     import jax.numpy as jnp
-    cache = PagedKVCache(num_layers=1, num_heads=1, head_dim=2,
+    cache = PagedKVCache(num_layers=1, cache_shape=(1, 2),
                          num_blocks=8, block_size=4,
                          enable_prefix_cache=True, host_tier_blocks=8)
     ta = np.arange(1, 18, dtype=np.int32)

@@ -1,7 +1,7 @@
 """The chip's compiler, asked without the chip.
 
-The kernels of the two main paths, compiled at the real widths of
-chip_smoke.py for a described (not attached) v5e:2x2: the flash forward
+The kernels of the two main paths, compiled at the real widths of the
+flagship GPT for a described (not attached) v5e:2x2: the flash forward
 and backward of the 6-head flagship, the packed-pair kernels of the
 12-head one at both backward branches, the ragged decode kernel at both
 head geometries, the flash kernel per shard under a 2x2 mesh, and the

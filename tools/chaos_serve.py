@@ -18,9 +18,8 @@ Exit status is nonzero on any violation, so CI can run this directly:
     JAX_PLATFORMS=cpu python tools/chaos_serve.py --seed 0 \
         --faults "nan_logits@4,stall@7:0.1,cache_corrupt@10" --requests 16
 
-`run_chaos` is importable — tests/test_bench_smoke.py smoke-invokes it
-and the chaos-marked acceptance test in tests/test_serving_robustness.py
-asserts the same invariants in-process.
+`run_chaos` is importable — the chaos-marked acceptance test in
+tests/test_serving_robustness.py asserts the same invariants in-process.
 
 `--replicas N` switches to the multi-replica harness (`run_chaos_replicas`):
 the same seeded workload flows through a ReplicaSet while replica-targeted

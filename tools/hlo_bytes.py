@@ -5,9 +5,8 @@ Usage: python tools/hlo_bytes.py /tmp/rn_hlo.txt [top_n]
 
 Thin CLI wrapper: the parsing and the dtype table live in
 paddle_tpu/analysis/hlo_bytes.py — the one source of truth for HLO byte
-accounting, shared with tools/scaling_analysis.py (all-reduce payload
-gate) and analysis/jaxcost.py (static cost model). Stdlib-only; never
-imports jax.
+accounting, shared with analysis/jaxcost.py (static cost model).
+Stdlib-only; never imports jax.
 """
 from __future__ import annotations
 

@@ -134,8 +134,8 @@ CLI:
         [--scenario steady ...] [--json out.json]
 
 `--slo` exits nonzero on any scenario SLO violation (CI gate).
-`run_suite` is importable: bench.py merges its report into BENCH_FULL
-and tests/test_observability.py runs the fast steady smoke in tier-1.
+`run_suite` is importable: tests/test_observability.py runs the fast
+steady smoke in tier-1.
 """
 from __future__ import annotations
 
