@@ -48,9 +48,10 @@ __all__ = ["gather_block_kv", "paged_decode_step", "fused_decode_chunk",
 
 
 def gather_block_kv(pool, block_tables):
-    """[num_blocks, bs, H, D] pool + [N, MB] tables -> [N, H, MB*bs, D]
-    contiguous per-sequence context, positions in block-table order: the
-    dense cache's heads-major layout."""
+    """[num_blocks, bs, H, D] pool (one stored in its logical shape) +
+    [N, MB] tables -> [N, H, MB*bs, D] contiguous per-sequence context,
+    positions in block-table order: the dense cache's heads-major
+    layout."""
     return gather_rows(pool, block_tables).transpose(0, 2, 1, 3)
 
 

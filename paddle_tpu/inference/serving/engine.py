@@ -312,6 +312,13 @@ class EngineStats:
             "bytes of cache one position costs over all layers (the "
             "model spec's layout and dtype)",
             labels=("engine",), unit="bytes").labels(**lbl)
+        self._g_cache_physical_bytes_per_token = obs.gauge(
+            "serving_cache_physical_bytes_per_token",
+            "bytes one position holds in the pools as stored, over all "
+            "layers: serving_cache_bytes_per_token plus the padding of "
+            "the shape the cache keeps block-major on the device "
+            "(paged_cache.physical_shape)",
+            labels=("engine",), unit="bytes").labels(**lbl)
         self._g_running = g_run.labels(**lbl)
         self._g_waiting = g_wait.labels(**lbl)
         self._g_blocks_used = g_blk.labels(state="used", **lbl)
@@ -408,8 +415,13 @@ class EngineStats:
     def cache_bytes_per_token(self) -> int:
         return int(self._g_cache_bytes_per_token.value)
 
-    def set_cache_bytes_per_token(self, n: int) -> None:
+    @property
+    def cache_physical_bytes_per_token(self) -> int:
+        return int(self._g_cache_physical_bytes_per_token.value)
+
+    def set_cache_bytes_per_token(self, n: int, physical: int) -> None:
         self._g_cache_bytes_per_token.set(n)
+        self._g_cache_physical_bytes_per_token.set(physical)
 
     def set_prefill_spend(self, tokens: int) -> None:
         self._g_prefill_spend.set(tokens)
@@ -655,7 +667,8 @@ class LLMEngine:
         # helpers it calls re-enter (e.g. _emit under _recover)
         self._lock = threading.RLock()
         self.stats = EngineStats(config.obs_label)
-        self.stats.set_cache_bytes_per_token(spec.cache_bytes_per_token)
+        self.stats.set_cache_bytes_per_token(
+            spec.cache_bytes_per_token, self.cache.physical_bytes_per_token)
         # (model, revision) event tag (serving/deploy.py): emission and
         # terminal events carry the serving revision so the causality
         # checker can prove no token was emitted by a revision other

@@ -3,13 +3,17 @@
 The dense decode cache in models/generation.py is [B, H, max_seq, D] per
 layer — every sequence pays for max_seq_len positions and a batch slot,
 so a serving mix of short and long requests wastes most of HBM. Here KV
-lives in a per-layer block pool [num_blocks, block_size, H, D]; a
-sequence owns an ordered list of block ids (its *block table*) and only
-ever holds ceil(len/block_size) blocks. This is the TPU-native shape of
-the Ragged Paged Attention kernel (PAPERS.md, arxiv 2604.15464) and of
-vLLM's PagedAttention, with the pool as one jnp array per layer so the
-ragged decode step (serving/attention.py) gathers it with one
-block-table index per layer.
+lives in a per-layer block pool, logically [num_blocks, block_size, H,
+D]; a sequence owns an ordered list of block ids (its *block table*) and
+only ever holds ceil(len/block_size) blocks. This is the TPU-native
+shape of the Ragged Paged Attention kernel (PAPERS.md, arxiv 2604.15464)
+and of vLLM's PagedAttention, with the pool as one jnp array per layer
+so the ragged decode step (serving/attention.py) gathers it with one
+block-table index per layer. [.., H, D] is the LOGICAL view, what a
+family writes and reads; the array is stored as [num_blocks, block_size,
+...physical_shape((H, D))], a shape the device keeps block-major
+(`physical_shape`), and `write_rows` / `gather_rows` /
+`write_prefill_scatter` and the ragged kernel are all that look inside.
 
 Prefix caching (docs/serving.md "Prefix caching"): with
 `enable_prefix_cache=True` blocks become REFCOUNTED and content-
@@ -80,7 +84,7 @@ from .host_tier import HostTierStore
 from .prefix_cache import PrefixCacheIndex, PrefixNode
 
 __all__ = ["PagedKVCache", "CacheExhausted", "write_rows", "gather_rows",
-           "pool_geometry"]
+           "pool_geometry", "physical_shape"]
 
 
 #: len(cache_shape) -> the layout (models/spec.py): (H, D) is a (k, v) pair
@@ -93,22 +97,75 @@ def pool_geometry(pools):
     return jax.tree_util.tree_leaves(pools)[0].shape[:2]
 
 
+_LANES, _SUBLANES = 128, 8
+
+
+def physical_shape(cache_shape):
+    """The per-position shape a pool is STORED in, from the logical one
+    (`ModelSpec.cache_shape`). A TPU tiles the two minor dimensions of an
+    array as 8 sublanes x 128 lanes, and keeps [num_blocks, block_size,
+    ...] block-major only when those two fill whole tiles: otherwise its
+    compiler moves the block id (or block_size) into the tile and every
+    program that indexes the pool by block copies the whole pool out of
+    that layout and back (PERF.md section 6, PR 32). So:
+
+    - heads (H, D), D a divisor of 128 below it and H * D a multiple of
+      8 x 128 (GPT-2 medium: 16 x 64): (H * D // 128, 128), that is
+      128 // D heads side by side on the lanes;
+    - latent (W,), W at least a lane row: W rounded up to 128 lanes
+      (576 -> 640), the padding zero and never read;
+    - anything else (12 heads x 64, the toy sizes of the CPU tests, a head
+      of 128 or more): the logical shape. Such a pool keeps whatever
+      copies the compiler makes for it."""
+    cache_shape = tuple(cache_shape)
+    if len(cache_shape) == 1:
+        (w,) = cache_shape
+        return (-(-w // _LANES) * _LANES,) if w > _LANES else cache_shape
+    h, d = cache_shape
+    if d < _LANES and _LANES % d == 0 \
+            and (h * d) % (_SUBLANES * _LANES) == 0:
+        return (h * d // _LANES, _LANES)
+    return cache_shape
+
+
+def _to_physical(x, stored):
+    """Values whose trailing dimensions are a logical per-position shape,
+    in the pool's stored one `stored` (`pool.shape[2:]`): heads regroup
+    onto the lanes (a reshape), a latent row is zero-padded. The same
+    values to the bit."""
+    if len(stored) == 1:
+        pad = stored[0] - x.shape[-1]
+        return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),)) \
+            if pad else x
+    return x.reshape(x.shape[:-2] + tuple(stored))
+
+
+def _to_logical(x, cache_shape):
+    """The inverse of `_to_physical`: stored values as `cache_shape`."""
+    if len(cache_shape) == 1:
+        return x[..., :cache_shape[0]]
+    return x.reshape(x.shape[:-2] + tuple(cache_shape))
+
+
 def write_rows(pool, rows, slot_blocks, slot_offsets):
-    """Write rows [N, ...] into pool [num_blocks, block_size, ...] at
-    (slot_blocks[n], slot_offsets[n]): the decode layer's cache write, for
-    any per-position shape. Out-of-range block ids (num_blocks: padded and
-    frozen rows) are dropped."""
-    return pool.at[slot_blocks, slot_offsets].set(rows.astype(pool.dtype),
-                                                  mode="drop")
+    """Write rows [N, H, D] or [N, W] (the LOGICAL per-position shape)
+    into pool [num_blocks, block_size, ...stored] at (slot_blocks[n],
+    slot_offsets[n]): the decode layer's cache write. Out-of-range block
+    ids (num_blocks: padded and frozen rows) are dropped."""
+    rows = _to_physical(rows.astype(pool.dtype), pool.shape[2:])
+    return pool.at[slot_blocks, slot_offsets].set(rows, mode="drop")
 
 
-def gather_rows(pool, tables):
-    """pool [num_blocks, block_size, ...] through block tables [N, MB] ->
-    [N, MB * block_size, ...]: each sequence's context, positions in
-    block-table order (position p = block p // block_size, slot
-    p % block_size), live or not."""
+def gather_rows(pool, tables, cache_shape=None):
+    """pool [num_blocks, block_size, ...stored] through block tables
+    [N, MB] -> [N, MB * block_size, ...cache_shape]: each sequence's
+    context in the LOGICAL per-position shape (`cache_shape`; None: the
+    pool is stored as it is read), positions in block-table order
+    (position p = block p // block_size, slot p % block_size), live or
+    not."""
     n, mb = tables.shape
-    return pool[tables].reshape((n, mb * pool.shape[1]) + pool.shape[2:])
+    ctx = pool[tables].reshape((n, mb * pool.shape[1]) + pool.shape[2:])
+    return ctx if cache_shape is None else _to_logical(ctx, cache_shape)
 
 
 # ptlint: disable=PT-T009  agrees with the committed plan entry
@@ -117,9 +174,10 @@ def gather_rows(pool, tables):
 def write_prefill_scatter(pools, dense_cache, block_ids, batch_index):
     """Scatter row `batch_index` of a dense prefill cache (L-tuple of
     (k, v) [B, H, S, D]) into `pools` (L-tuple of (k, v) [num_blocks,
-    block_size, H, D]) at `block_ids`, for every layer in one program.
-    The latent layout is the same program over one pool a layer: dense
-    rows [B, S, W] into [num_blocks, block_size, W], no transpose.
+    block_size, ...stored]) at `block_ids`, for every layer in one
+    program. The latent layout is the same program over one pool a layer:
+    dense rows [B, S, W] into [num_blocks, block_size, ...stored], no
+    transpose.
 
     Nothing the traffic varies is in the program's key: the WHOLE dense
     row is cut into ceil(S / block_size) blocks (zero-padded past S),
@@ -134,26 +192,14 @@ def write_prefill_scatter(pools, dense_cache, block_ids, batch_index):
     def scatter(pool, dense):
         row = jax.lax.dynamic_index_in_dim(dense, batch_index, 0,
                                            keepdims=False)
-        if pool.ndim == 3:                 # latent: [S, W] rows as they are
-            bs = pool.shape[1]
-            blk = jnp.pad(row, ((0, n_slots * bs - row.shape[0]), (0, 0)))
-            return pool.at[block_ids].set(
-                blk.reshape(n_slots, bs, -1).astype(pool.dtype),
-                mode="drop")
-        nb, bs, h, d = pool.shape
-        # [B, H, S, D] -> [H, S, D] -> [S, H, D] -> [n_slots, bs, H * D]
-        blk = row.transpose(1, 0, 2)
-        blk = jnp.pad(blk, ((0, n_slots * bs - blk.shape[0]),
-                            (0, 0), (0, 0)))
-        blk = blk.reshape(n_slots, bs, h * d)
-        # scattered as [nb, bs, H * D] rows: a v5e holds an f32 pool
-        # with the block id as its MINOR dimension (head_dim 64 is under
-        # the 128 lanes), so XLA relays the whole pool out around the
-        # scatter and back; with H * D minor that copy is not padded to
-        # 128 lanes (22 ms against 32 ms a prefill at 24 layers x 512
-        # blocks, PERF.md section 6, PR 28). The same values either way.
-        flat = pool.reshape(nb, bs, h * d)
-        return flat.at[block_ids].set(blk, mode="drop").reshape(pool.shape)
+        if row.ndim == 3:                  # heads: [H, S, D] -> [S, H, D]
+            row = row.transpose(1, 0, 2)
+        bs, stored = pool.shape[1], pool.shape[2:]
+        blk = _to_physical(row.astype(pool.dtype), stored)
+        blk = jnp.pad(blk, ((0, n_slots * bs - blk.shape[0]),)
+                      + ((0, 0),) * len(stored))
+        return pool.at[block_ids].set(
+            blk.reshape((n_slots, bs) + stored), mode="drop")
 
     return jax.tree_util.tree_map(scatter, pools, dense_cache)
 
@@ -179,13 +225,17 @@ class PagedKVCache:
     """Fixed-size per-layer KV block pools with alloc/free accounting.
 
     Pools: an L-tuple of the layer's cached leaf, built from
-    `cache_shape`, the per-position shape of ONE pool (what
-    `ModelSpec.cache_shape` says): (H, D) gives a (k_pool, v_pool) pair,
-    each [num_blocks, block_size, H, D] (`layout` "heads"); (W,) gives one
-    pool [num_blocks, block_size, W] (`layout` "latent"). This module is
-    the format's one owner: a decode layer writes and reads a pool through
-    `write_rows` / `gather_rows`, a prefill through
-    `write_prefill_scatter`. Token position p of a sequence lives in its
+    `cache_shape`, the LOGICAL per-position shape of ONE pool (what
+    `ModelSpec.cache_shape` says): (H, D) gives a (k_pool, v_pool) pair
+    (`layout` "heads"); (W,) gives one pool (`layout` "latent"). Each
+    pool is stored as [num_blocks, block_size, ...stored_shape], where
+    `stored_shape = physical_shape(cache_shape)`: (H, D) or (W,) itself,
+    or the packed / padded form the device keeps block-major. This module
+    is the format's one owner: a decode layer writes and reads a pool
+    through `write_rows` / `gather_rows`, a prefill through
+    `write_prefill_scatter`, all in the logical shape; the block
+    operations below index whole blocks on axis 0 and carry whatever
+    trails. Token position p of a sequence lives in its
     block table entry p // block_size at slot offset p % block_size — the
     identity layout that makes the gathered context bitwise-match the
     dense cache.
@@ -238,17 +288,21 @@ class PagedKVCache:
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.kv_cache_dtype = kv_cache_dtype
-        shape = (num_blocks, block_size) + cache_shape
+        #: the per-position shape the pools are stored in
+        self.stored_shape = physical_shape(cache_shape)
+        shape = (num_blocks, block_size) + self.stored_shape
         if self.layout == "latent":
             self._qpools = None
             self._pools = tuple(jnp.zeros(shape, dtype)
                                 for _ in range(num_layers))
         elif kv_cache_dtype == "int8":
             # quantized pool mode (module docstring): int8 codes +
-            # per-(block, head) scales; the `pools` property is the
+            # per-(block, head) scales, the codes in the LOGICAL shape
+            # the scales are per head of; the `pools` property is the
             # dequantized f32 view every consumer reads and writes
+            codes = (num_blocks, block_size) + cache_shape
             self._qpools = tuple(
-                (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8))
+                (jnp.zeros(codes, jnp.int8), jnp.zeros(codes, jnp.int8))
                 for _ in range(num_layers))
             scales = (num_blocks, cache_shape[0])      # per (block, head)
             self._scales = tuple(
@@ -310,22 +364,25 @@ class PagedKVCache:
     # ------------------------------------------------ pool storage view
     @property
     def pools(self) -> Tuple[Tuple[jnp.ndarray, jnp.ndarray], ...]:
-        """L-tuple of (k, v) [num_blocks, block_size, H, D] (latent
-        layout: of one [num_blocks, block_size, W] array) in the
-        LOGICAL f32 layout — what the attention gather, write_prefill
-        scatter, migration and scrub paths all read and assign. In f32
-        mode this is the storage itself (bit-for-bit the historical
-        attribute). In int8 mode the getter dequantizes the code/scale
-        storage and the setter re-encodes through
-        kv_quant.requantize_blocks, whose monotone scales make an
-        unchanged block's round-trip bit-stable (kv_quant docstring),
-        so repeated decode-chunk rebinds never walk stored values."""
+        """L-tuple of (k, v) [num_blocks, block_size, ...stored_shape]
+        (latent layout: of one such array) in the pool dtype — what the
+        attention gather, write_prefill scatter, migration and scrub
+        paths all read and assign; `stored_shape` is `physical_shape`
+        of the logical per-position shape, and only `write_rows` /
+        `gather_rows` / `write_prefill_scatter` and the ragged kernel
+        look inside it. In f32 mode this is the storage itself. In int8
+        mode the getter dequantizes the code/scale storage and the
+        setter re-encodes through kv_quant.requantize_blocks, whose
+        monotone scales make an unchanged block's round-trip bit-stable
+        (kv_quant docstring), so repeated decode-chunk rebinds never
+        walk stored values."""
         if self._qpools is None:
             return self._pools
         return tuple(
-            (kv_quant.dequantize_blocks(qk, sk),
-             kv_quant.dequantize_blocks(qv, sv))
-            for (qk, qv), (sk, sv) in zip(self._qpools, self._scales))
+            tuple(_to_physical(kv_quant.dequantize_blocks(q, sc),
+                               self.stored_shape)
+                  for q, sc in zip(qkv, scales))
+            for qkv, scales in zip(self._qpools, self._scales))
 
     @pools.setter
     def pools(self, new_pools) -> None:
@@ -334,12 +391,23 @@ class PagedKVCache:
             return
         qpools, scales = [], []
         for (k, v), (sk, sv) in zip(new_pools, self._scales):
-            qk, nsk = kv_quant.requantize_blocks(k, sk)
-            qv, nsv = kv_quant.requantize_blocks(v, sv)
+            qk, nsk = kv_quant.requantize_blocks(
+                _to_logical(k, self.cache_shape), sk)
+            qv, nsv = kv_quant.requantize_blocks(
+                _to_logical(v, self.cache_shape), sv)
             qpools.append((qk, qv))
             scales.append((nsk, nsv))
         self._qpools = tuple(qpools)
         self._scales = tuple(scales)
+
+    @property
+    def physical_bytes_per_token(self) -> int:
+        """Bytes one position holds in the pools as stored, over all
+        layers: `ModelSpec.cache_bytes_per_token` plus what
+        `physical_shape` pads (int8 mode: the codes, scales aside)."""
+        stored = self._pools if self._qpools is None else self._qpools
+        return sum(int(np.prod(p.shape[2:])) * p.dtype.itemsize
+                   for p in jax.tree_util.tree_leaves(stored))
 
     def _reset_block_scales(self, ids) -> None:
         """Zero freshly-claimed blocks' scale rows (int8 mode): stale
@@ -525,12 +593,15 @@ class PagedKVCache:
     def _dequant_payload(self, payload) -> tuple:
         """Decode a QUANTIZED spill payload (L int8 code pairs + the
         trailing (k_scales [L, H], v_scales [L, H]) pair) back to the
-        L-pair f32 shape the scatter/wire paths expect. Only meaningful
-        in int8 mode; called after the stored digest has verified."""
-        ks, vs = payload[self.num_layers]
+        L-pair f32 blocks, in the pools' stored shape, that the
+        scatter/wire paths expect. Only meaningful in int8 mode; called
+        after the stored digest has verified."""
+        scales = payload[self.num_layers]
+        block = (self.block_size,) + self.stored_shape
         return tuple(
-            (payload[li][0].astype(np.float32) * ks[li][None, :, None],
-             payload[li][1].astype(np.float32) * vs[li][None, :, None])
+            tuple((codes.astype(np.float32)
+                   * sc[li][None, :, None]).reshape(block)
+                  for codes, sc in zip(payload[li], scales))
             for li in range(self.num_layers))
 
     def _flush_demotions(self, nodes: List[PrefixNode]) -> None:

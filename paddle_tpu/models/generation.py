@@ -260,8 +260,8 @@ def serving_spec(geom) -> ModelSpec:
             x = _attn_merge(params, i, x, att[:, :, None, :], geom)
         else:
             # heads-major [N, H, S, D]: the dense cache _decode_attn takes
-            kc, vc = (gather_rows(p, tables).transpose(0, 2, 1, 3)
-                      for p in (kp, vp))
+            kc, vc = (gather_rows(p, tables, (num_heads, head_dim))
+                      .transpose(0, 2, 1, 3) for p in (kp, vp))
             x = _decode_attn(params, i, x, qkv[0], kc, vc, positions, geom)
         return x, (kp, vp), None
 

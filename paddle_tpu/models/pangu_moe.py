@@ -351,7 +351,7 @@ def _decode_layer(cfg, params, i, x, pool, slot_blocks, slot_offsets,
     q_nope, q_pe = mla_queries(params, pre, h, positions, cfg)
     row = mla_latent(params, pre, h, positions, cfg)
     pool = write_rows(pool, row, slot_blocks, slot_offsets)
-    ctx = gather_rows(pool, tables)
+    ctx = gather_rows(pool, tables, (cfg.latent_width,))
     attn = mla_absorbed(params, pre, q_nope, q_pe, ctx.astype(h.dtype),
                         att_lens, cfg)
     y, counts = layer_tail(params, i, x[:, 0], attn, cfg, live)

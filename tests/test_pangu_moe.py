@@ -251,14 +251,16 @@ def test_latent_cache_is_one_pool_a_layer_and_counts_its_bytes():
 def test_the_cache_is_built_from_what_the_spec_says_of_a_position(family):
     """`PagedKVCache` takes the spec's `cache_shape` and `cache_dtype` and
     nothing else about layout; `layout` is what the spec calls it. The
-    latent pools are those the `latent_width=` call of PR 29 made."""
+    latent pools are stored a whole number of 128-lane rows wide
+    (`paged_cache.physical_shape`: 576 -> 640), the toy heads as they
+    are."""
     if family == "gpt2":
         spec = gen.serving_spec((2, 4, 8, 32))
         leaf_shapes = [(16, 4, 4, 8)] * 2
     else:
         spec = pm.serving_spec(pm.PanguMoEConfig(
             num_hidden_layers=2, dtype="bfloat16"))
-        leaf_shapes = [(16, 4, 576)]
+        leaf_shapes = [(16, 4, 640)]
     pc = PagedKVCache(spec.num_layers, spec.cache_shape, 16, 4,
                       dtype=jnp.dtype(spec.cache_dtype))
     assert pc.layout == spec.cache_layout

@@ -3,9 +3,11 @@
 The kernels of the two main paths, compiled at the real widths of the
 flagship GPT for a described (not attached) v5e:2x2: the flash forward
 and backward of the 6-head flagship, the packed-pair kernels of the
-12-head one at both backward branches, the ragged decode kernel at both
-head geometries, the flash kernel per shard under a 2x2 mesh, and the
-serving cell's dense-admission scatter with its pools donated. Interpret
+12-head one at both backward branches, the ragged decode kernel at three
+head geometries (one of them packed two heads a lane row, as the cache
+stores it), the flash kernel per shard under a 2x2 mesh, and the serving
+cells' decode chunk and dense-admission scatter with their pools donated
+and no pool copied. Interpret
 mode accepts what Mosaic refuses (an unaligned slice, a batched dot with no
 free lhs dim, too much VMEM); these compiles do not. Nothing runs, so they
 say nothing about results or times.
@@ -158,7 +160,8 @@ def test_packed_flash_forward_alone_and_ragged_decode_are_named(one_chip):
     assert _kernel_names(
         lambda q, k, v: packed_flash_attention(q, k, v, True, 0.125),
         x, x, x) == ["packed_flash_fwd"]
-    pool = sds((512, 32, 16, 64), jnp.float32)
+    # the pool as the cache stores 16 heads x 64: two heads a lane row
+    pool = sds((512, 32, 8, 128), jnp.float32)
     assert _kernel_names(
         ragged_decode_attention, sds((16, 16, 64), jnp.float32), pool, pool,
         sds((16, 32), jnp.int32), sds((16,), jnp.int32)) == \
@@ -166,41 +169,110 @@ def test_packed_flash_forward_alone_and_ragged_decode_are_named(one_chip):
 
 
 # the serving shape: 8 rows, float32 pools of 512 blocks x 32 tokens,
-# 32 blocks per sequence (max_seq_len 1024)
-@pytest.mark.parametrize("heads,head_dim", [(6, 128), (12, 64)])
+# 32 blocks per sequence (max_seq_len 1024), each pool in the shape the
+# cache stores it in: (16, 64) packed two heads a lane row, the others as
+# they are
+@pytest.mark.parametrize("heads,head_dim", [(6, 128), (12, 64), (16, 64)])
 def test_ragged_decode_compiles(one_chip, heads, head_dim):
+    from paddle_tpu.inference.serving.paged_cache import physical_shape
     from paddle_tpu.ops.pallas.ragged_paged_attention import \
         ragged_decode_attention
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    pool = sds((512, 32, heads, head_dim), jnp.float32)
+    pool = sds((512, 32) + physical_shape((heads, head_dim)), jnp.float32)
     assert _kernel_calls(
         ragged_decode_attention, sds((8, heads, head_dim), jnp.float32),
         pool, pool, sds((8, 32), jnp.int32), sds((8,), jnp.int32)) == 1
 
 
-def test_write_prefill_scatter_updates_the_pools_in_place(one_chip):
-    """The dense-admission scatter at the serving cell's geometry (two
-    layers of it): one XLA module under its own name, every pool aliased
-    to its output (the donation took) and no more than one pool of
-    temporaries (the relayout a v5e makes around each scatter, one pool
-    at a time)."""
-    from paddle_tpu.inference.serving.paged_cache import \
-        write_prefill_scatter
+def _pool_copies(text: str, num_blocks: int) -> list:
+    """The compiled program's `copy` operations whose result is pool-shaped
+    (`[num_blocks, block_size, ...]`): the relayouts a v5e makes around a
+    program that indexes by block a pool it keeps block-id-minor."""
+    return [line.strip()[:120] for line in text.splitlines()
+            if re.search(rf"= \w+\[{num_blocks},\d+,[\d,]+\]\S* copy\(",
+                         line)]
+
+
+def _scatter_in_place(one_chip, layers, num_blocks, cache_shape, dtype,
+                      dense_shape):
+    """`write_prefill_scatter` on pools of `cache_shape` as the cache stores
+    them: one XLA module under its own name, every pool aliased to its
+    output (the donation took), NO pool-shaped copy and under 5 % of one
+    pool of temporaries: the scatter writes the sequence's blocks and
+    nothing else."""
+    from paddle_tpu.inference.serving.paged_cache import (
+        physical_shape, write_prefill_scatter)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    layers, pool_bytes = 2, 512 * 32 * 16 * 64 * 4
-    pool = sds((512, 32, 16, 64), jnp.float32)
-    dense = sds((1, 16, 1024, 64), jnp.float32)
+    pool = sds((num_blocks, 32) + physical_shape(cache_shape), dtype)
+    leaf = (pool, pool) if len(cache_shape) == 2 else pool
+    dense = sds(dense_shape, dtype)
+    dense_leaf = (dense, dense) if len(cache_shape) == 2 else dense
     compiled = write_prefill_scatter.lower(
-        ((pool, pool),) * layers, ((dense, dense),) * layers,
-        sds((32,), jnp.int32), sds((), jnp.int32)).compile()
-    assert "HloModule jit_write_prefill_scatter" in compiled.as_text()
+        (leaf,) * layers, (dense_leaf,) * layers,
+        sds((dense_shape[-2] // 32,), jnp.int32),
+        sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "HloModule jit_write_prefill_scatter" in text
+    assert _pool_copies(text, num_blocks) == []
+    pool_bytes = int(np.prod(pool.shape)) * jnp.dtype(dtype).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= len(
+        jax.tree_util.tree_leaves(leaf)) * layers * pool_bytes
+    assert mem.temp_size_in_bytes < 0.05 * pool_bytes
+
+
+def test_write_prefill_scatter_updates_the_pools_in_place(one_chip):
+    """The dense-admission scatter at the serving cell's geometry (two
+    layers of it, 16 heads x 64 stored as [8, 128])."""
+    _scatter_in_place(one_chip, 2, 512, (16, 64), jnp.float32,
+                      (1, 16, 1024, 64))
+
+
+def test_the_decode_chunk_copies_no_pool_at_the_serving_geometry(
+        one_chip, monkeypatch):
+    """`jit_fused_decode_chunk` at the GPT-2 serving cells' geometry (two
+    layers of GPT-2 medium's 16 heads x 64, 16 rows, 512 blocks x 32, a
+    small vocabulary), the ragged kernel routed as on the chip: the pools
+    stay block-major (`[512,32,8,128]`, row-major tiled), so no pool is
+    copied in or out, every pool is aliased to its output, and the kernel
+    is called once a layer under its name, on the pool as it is stored."""
+    from paddle_tpu.inference.serving.attention import (PACK_COLS,
+                                                        fused_decode_chunk)
+    from paddle_tpu.inference.serving.paged_cache import physical_shape
+    from paddle_tpu.models import generation as gen
+    from paddle_tpu.models.gpt import GPT, GPTConfig
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    layers, heads, head_dim, seq = geom = (2, 16, 64, 1024)
+    model = GPT(GPTConfig(vocab_size=512, hidden_size=heads * head_dim,
+                          num_layers=layers, num_heads=heads,
+                          max_seq_len=seq))
+    params = {k: sds(v.shape, v.dtype)
+              for k, v in gen.extract_params(model).items()}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pool = sds((512, 32) + physical_shape((heads, head_dim)), jnp.float32)
+    assert pool.shape == (512, 32, 8, 128)
+    compiled = fused_decode_chunk.lower(
+        params, ((pool, pool),) * layers,
+        sds((16, PACK_COLS + 8 + seq // 32), jnp.int32), geom, 8,
+        "ragged").compile()
+    text = compiled.as_text()
+    assert _pool_copies(text, 512) == []
+    assert "[512,32,8,128]{3,2,1,0:T(8,128)}" in text
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(kernels) == layers
+    assert all(re.match(r"\s*%ragged_decode_attention[.\d]* = f32\[16,8,128\]",
+                        k) and "f32[512,32,8,128]" in k for k in kernels)
+    pool_bytes = 512 * 32 * 8 * 128 * 4
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * layers * pool_bytes
-    assert mem.temp_size_in_bytes < 1.25 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes
 
 
 def test_flash_compiles_per_shard_under_a_mesh(topo):
@@ -283,23 +355,7 @@ def test_the_pair_buffer_has_a_compact_and_a_full_branch(one_chip, rows,
 
 def test_the_latent_pool_is_scattered_in_place_at_the_cell_size(one_chip):
     """`write_prefill_scatter` on the latent layout of the expert cell (5
-    layers of bf16 [8192, 32, 576], 2,048 dense positions): one module
-    under the shared name, every pool aliased to its output. What it also
-    shows (PERF.md section 5): a v5e keeps that shape with the block id
-    MINOR, so each pool is copied out of that layout and back (two
-    whole-pool copies a layer), within half a pool of temporaries a
-    layer."""
-    from paddle_tpu.inference.serving.paged_cache import \
-        write_prefill_scatter
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    layers, pool_bytes = 5, 8192 * 32 * 576 * 2
-    compiled = write_prefill_scatter.lower(
-        (sds((8192, 32, 576), jnp.bfloat16),) * layers,
-        (sds((1, 2048, 576), jnp.bfloat16),) * layers,
-        sds((64,), jnp.int32), sds((), jnp.int32)).compile()
-    assert "HloModule jit_write_prefill_scatter" in compiled.as_text()
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= layers * pool_bytes
-    assert mem.temp_size_in_bytes < 1.25 * pool_bytes
+    layers of bf16 rows 576 wide, stored 640 wide: a whole number of lane
+    rows, which a v5e keeps block-major; 2,048 dense positions)."""
+    _scatter_in_place(one_chip, 5, 8192, (576,), jnp.bfloat16,
+                      (1, 2048, 576))
