@@ -147,12 +147,13 @@ def _moe_mlp(x, wr, wu, bu, wd, bd, top_k, capacity_factor, min_capacity):
 
 
 def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
-                     scale, live=None):
+                     scale, live=None, scoring="sigmoid"):
     """The dropless expert layer of ONE chip of an expert-parallel
     deployment: x [T, h] -> (routed part [T, h] float32, counts int32 [4]).
 
     The router is whole: `router_w` [h, E] scores every token over all E
-    experts in float32 (sigmoid), the `top_k` largest are chosen and their
+    experts in float32 (`scoring`, static: "sigmoid" of each logit, or
+    "softmax" over all E), the `top_k` largest are chosen and their
     scores normalised to sum to `scale`. This chip holds the experts
     `first .. first + count - 1` (`held = (first, count)`, static;
     `w_gate`, `w_up` [count, h, f] and `w_down` [count, f, h] are theirs)
@@ -180,8 +181,12 @@ def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
     fetch."""
     first, count = held
     tokens, pairs = x.shape[0], x.shape[0] * top_k
-    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router_w,
-                                    precision="highest"))
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"scoring must be 'sigmoid' or 'softmax', got "
+                         f"{scoring!r}")
+    logits = jnp.dot(x.astype(jnp.float32), router_w, precision="highest")
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     top_s, top_i = jax.lax.top_k(scores, top_k)
     weight = (top_s / jnp.sum(top_s, axis=-1, keepdims=True)
               * jnp.float32(scale)).reshape(-1)

@@ -66,7 +66,7 @@ def as_spec(geom) -> ModelSpec:
 
 
 def paged_decode_step(params, pools, tokens, positions, block_tables,
-                      slot_blocks, slot_offsets, geom):
+                      slot_blocks, slot_offsets, geom, state_slots=None):
     """One ragged decode step over the block pool.
 
     params: the models.generation.extract_params dict.
@@ -81,6 +81,9 @@ def paged_decode_step(params, pools, tokens, positions, block_tables,
         (num_blocks) so the scatter drops them.
     geom: static ModelSpec, or the (num_layers, num_heads, head_dim,
         max_seq_len) tuple of models.generation (`as_spec`).
+    state_slots [N] int32 — where the spec has state layers
+        (`ModelSpec.layer_caches`), each sequence's slot in their
+        `SeqState` leaves (`PagedKVCache.state_slot`); else None.
 
     Returns (logits [N, V], updated pools). Composed of the spec's
     functions — for GPT-2 the shared jitted sub-programs of
@@ -96,7 +99,7 @@ def paged_decode_step(params, pools, tokens, positions, block_tables,
     for i, pool in enumerate(pools):
         x, pool, _ = spec.decode_layer(
             params, i, x, pool, slot_blocks, slot_offsets, block_tables,
-            positions, positions + 1, live, False)
+            positions, positions + 1, live, False, state_slots)
         new_pools.append(pool)
     return spec.head(params, x), tuple(new_pools)
 
@@ -121,6 +124,8 @@ def paged_decode_step(params, pools, tokens, positions, block_tables,
 #                between the last fed prompt token and the first sample)
 #   12..12+k-1   the pf_feed prompt tokens for this chunk (0-padded)
 #   12+k..       the block table row [MB]
+#   12+k+MB      the row's state slot: ONE more column, and only where the
+#                spec has state layers (MB = max_seq_len // block_size)
 PACK_COLS = 12
 
 
@@ -219,14 +224,21 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
 
     pools (arg 1) is DONATED: the KV carry is updated in place across
     the scan and the input buffers alias the output on TPU, so the k
-    cache writes cost no extra copies of the pool.
+    cache writes cost no extra copies of the pool. A state layer's
+    `SeqState` leaves are pools like the others: in the carry, donated,
+    aliased.
 
     Returns (out [k+2+len(counters), N] int32, updated pools).
     """
     spec = as_spec(geom)
-    tables = packed[:, PACK_COLS + k:]
     feed = packed[:, PACK_COLS:PACK_COLS + k].T      # [k, N] prompt feed
     num_blocks, block_size = pool_geometry(pools)
+    if spec.state_layers:
+        mb = spec.max_seq_len // block_size
+        tables = packed[:, PACK_COLS + k:PACK_COLS + k + mb]
+        state_slots = packed[:, PACK_COLS + k + mb]
+    else:
+        tables, state_slots = packed[:, PACK_COLS + k:], None
     n = packed.shape[0]
     active = packed[:, 2] > 0
     max_out = packed[:, 4]
@@ -257,7 +269,7 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
         for i, pool in enumerate(pools):
             x, pool, layer_counts = spec.decode_layer(
                 params, i, x, pool, slot_blocks, slot_offsets, tables,
-                pos, att_lens, run, ragged)
+                pos, att_lens, run, ragged, state_slots)
             new_pools.append(pool)
             if spec.counters:
                 counts = merge_counts(counts, layer_counts)

@@ -197,8 +197,9 @@ _STAT_EVENTS = ("steps", "prefill_tokens", "generated_tokens",
                 # expert) pairs routed, held experts hit per trip/layer,
                 # layers and trips that multiplied the full pair buffer
                 "moe_pairs", "moe_experts_hit", "moe_full_buffer_layers",
-                # the `context_tokens` stat of serving.decode, summed
-                "context_tokens")
+                # the `context_tokens` and `live_row_trips` stats of
+                # serving.decode, summed
+                "context_tokens", "live_row_trips")
 # float phase-time accumulators (serving_phase_seconds_total{engine,phase})
 _STAT_PHASES = {"time_schedule": "schedule", "time_prefill": "prefill",
                 "time_decode": "decode"}
@@ -319,6 +320,16 @@ class EngineStats:
             "the shape the cache keeps block-major on the device "
             "(paged_cache.physical_shape)",
             labels=("engine",), unit="bytes").labels(**lbl)
+        self._g_state_bytes_per_seq = obs.gauge(
+            "serving_state_bytes_per_seq",
+            "bytes of cache one SEQUENCE costs whatever its length: the "
+            "fixed-size entries of the spec's state layers (0 without any)",
+            labels=("engine",), unit="bytes").labels(**lbl)
+        self._g_state_slots_in_use = obs.gauge(
+            "serving_state_slots_in_use",
+            "state slots owned by a sequence after the last step (a spec "
+            "with state layers: one a sequence that holds cache)",
+            labels=("engine",)).labels(**lbl)
         self._g_running = g_run.labels(**lbl)
         self._g_waiting = g_wait.labels(**lbl)
         self._g_blocks_used = g_blk.labels(state="used", **lbl)
@@ -405,11 +416,13 @@ class EngineStats:
         self._step.observe(dt)
 
     def set_step_gauges(self, running: int, waiting: int,
-                        blocks_used: int, blocks_free: int) -> None:
+                        blocks_used: int, blocks_free: int,
+                        state_slots_used: int = 0) -> None:
         self._g_running.set(running)
         self._g_waiting.set(waiting)
         self._g_blocks_used.set(blocks_used)
         self._g_blocks_free.set(blocks_free)
+        self._g_state_slots_in_use.set(state_slots_used)
 
     @property
     def cache_bytes_per_token(self) -> int:
@@ -419,9 +432,19 @@ class EngineStats:
     def cache_physical_bytes_per_token(self) -> int:
         return int(self._g_cache_physical_bytes_per_token.value)
 
-    def set_cache_bytes_per_token(self, n: int, physical: int) -> None:
+    @property
+    def state_bytes_per_seq(self) -> int:
+        return int(self._g_state_bytes_per_seq.value)
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return int(self._g_state_slots_in_use.value)
+
+    def set_cache_bytes_per_token(self, n: int, physical: int,
+                                  state: int = 0) -> None:
         self._g_cache_bytes_per_token.set(n)
         self._g_cache_physical_bytes_per_token.set(physical)
+        self._g_state_bytes_per_seq.set(state)
 
     def set_prefill_spend(self, tokens: int) -> None:
         self._g_prefill_spend.set(tokens)
@@ -569,18 +592,23 @@ for _f in _STAT_REQ_SUMS:
 del _f
 
 
+def _live_trips(req, k: int) -> int:
+    """Trips of a k-trip chunk in which the row is live: as many as its
+    prompt feed and its max_tokens leave it. A row that stops early at EOS
+    is counted to its max_tokens: the host cannot know before the fetch."""
+    feed = max(0, req.pf_target - req.prefill_pos)
+    left = req.params.max_tokens - len(req.output_ids)
+    # the trip that eats the last prompt token also samples
+    return min(k, max(0, feed - 1) + left)
+
+
 def _context_tokens(reqs, k: int) -> int:
     """KV positions a k-trip chunk attends to, summed over rows and trips:
     a row whose first reserved position is p reads p + j + 1 at trip j
-    (the fused chunk's `att_lens`), for as many trips as its prompt feed
-    and its max_tokens leave it. A row that stops early at EOS is counted
-    to its max_tokens: the host cannot know before the fetch."""
+    (the fused chunk's `att_lens`), for each of its `_live_trips`."""
     total = 0
     for req in reqs:
-        feed = max(0, req.pf_target - req.prefill_pos)
-        left = req.params.max_tokens - len(req.output_ids)
-        # the trip that eats the last prompt token also samples
-        trips = min(k, max(0, feed - 1) + left)
+        trips = _live_trips(req, k)
         total += trips * req.slot[2] + trips * (trips + 1) // 2
     return total
 
@@ -644,7 +672,10 @@ class LLMEngine:
             enable_prefix_cache=config.enable_prefix_cache,
             host_tier_blocks=config.host_tier_blocks,
             promote_timeout_s=config.promote_timeout_s,
-            kv_cache_dtype=config.kv_cache_dtype)
+            kv_cache_dtype=config.kv_cache_dtype,
+            layer_caches=spec.layer_caches, state_shapes=spec.state_shapes,
+            # a sequence that holds cache is a running one
+            num_state_slots=config.max_num_seqs)
         cost_model = config.prefill_cost_model
         if cost_model == "auto":
             # committed-plan admission pricing; a repo without a plan
@@ -668,7 +699,8 @@ class LLMEngine:
         self._lock = threading.RLock()
         self.stats = EngineStats(config.obs_label)
         self.stats.set_cache_bytes_per_token(
-            spec.cache_bytes_per_token, self.cache.physical_bytes_per_token)
+            spec.cache_bytes_per_token, self.cache.physical_bytes_per_token,
+            self.cache.state_bytes_per_seq)
         # (model, revision) event tag (serving/deploy.py): emission and
         # terminal events carry the serving revision so the causality
         # checker can prove no token was emitted by a revision other
@@ -1414,10 +1446,13 @@ class LLMEngine:
                 t0 = time.perf_counter()
                 k = self.config.decode_chunk_size
                 context = _context_tokens(decode, k)
+                row_trips = sum(_live_trips(r, k) for r in decode)
                 self.stats.context_tokens += context
+                self.stats.live_row_trips += row_trips
                 with RecordEvent("serving.decode", cat="decode", args={
                         "num_seqs": len(decode), "chunk": k,
-                        "context_tokens": context}) as ev:
+                        "context_tokens": context,
+                        "live_row_trips": row_trips}) as ev:
                     # ptlint: disable=PT-C004  fault injector: stalls ON
                     # PURPOSE under the lock to exercise the watchdog
                     self.faults.stall(step_no)
@@ -1454,7 +1489,8 @@ class LLMEngine:
             running=self.scheduler.num_running(),
             waiting=self.scheduler.num_waiting(),
             blocks_used=self.cache.num_used(),
-            blocks_free=self.cache.num_free())
+            blocks_free=self.cache.num_free(),
+            state_slots_used=self.cache.num_state_slots_used())
         if self.cache.prefix_index is not None:
             self.stats.record_prefix(self.cache.prefix_stats())
             for dt in self.cache.drain_promote_seconds():
@@ -1576,8 +1612,10 @@ class LLMEngine:
         n = self.config.max_num_seqs if ragged \
             else _bucket(len(reqs), self.config.max_num_seqs)
         mb = self.max_blocks_per_seq
+        # a spec with state layers: one more column, the row's state slot
+        stateful = bool(self.spec.state_layers)
         with RecordEvent("serving.decode.pack", cat="decode"):
-            packed = np.zeros((n, PACK_COLS + k + mb), np.int32)
+            packed = np.zeros((n, PACK_COLS + k + mb + stateful), np.int32)
             fed = []                         # (req, tokens consumed)
             for i, req in enumerate(reqs):
                 p = req.params
@@ -1603,6 +1641,8 @@ class LLMEngine:
                     fed.append((req, f))
                 table = self.cache.block_table(req.request_id)
                 packed[i, PACK_COLS + k:PACK_COLS + k + len(table)] = table
+                if stateful:
+                    packed[i, -1] = self.cache.state_slot(req.request_id)
         with RecordEvent("serving.decode.dispatch", cat="decode"):
             out, pools = fused_decode_chunk(
                 self.params, self.cache.pools, jnp.asarray(packed),

@@ -83,8 +83,8 @@ from . import kv_quant
 from .host_tier import HostTierStore
 from .prefix_cache import PrefixCacheIndex, PrefixNode
 
-__all__ = ["PagedKVCache", "CacheExhausted", "write_rows", "gather_rows",
-           "pool_geometry", "physical_shape"]
+__all__ = ["PagedKVCache", "CacheExhausted", "SeqState", "write_rows",
+           "gather_rows", "pool_geometry", "physical_shape"]
 
 
 #: len(cache_shape) -> the layout (models/spec.py): (H, D) is a (k, v) pair
@@ -92,9 +92,48 @@ __all__ = ["PagedKVCache", "CacheExhausted", "write_rows", "gather_rows",
 _LAYOUTS = {2: "heads", 1: "latent"}
 
 
+@jax.tree_util.register_pytree_node_class
+class SeqState:
+    """The leaf of a layer that caches ONE fixed-size entry a SEQUENCE (a
+    recurrent state) where the others cache a row a position: its arrays,
+    each [num_state_slots, ...shape] (`ModelSpec.state_shapes`); a
+    sequence's entry is index `state_slot(seq)` of each. A pytree node, so
+    the pools of a hybrid cache go through `jax.jit`, donation and the scan
+    as the others do; a class of its own, so the block operations, which
+    index axis 0 by BLOCK, can tell it from a pool and leave it alone."""
+
+    def __init__(self, *arrays):
+        self.arrays = tuple(arrays)
+
+    def __iter__(self):
+        return iter(self.arrays)
+
+    def tree_flatten(self):
+        return self.arrays, None
+
+    @classmethod
+    def tree_unflatten(cls, aux, arrays):
+        return cls(*arrays)
+
+
+def _map_rows(fn, pools):
+    """`fn` over every block pool of `pools`; a state layer's leaf as it
+    is."""
+    return tuple(p if isinstance(p, SeqState)
+                 else jax.tree_util.tree_map(fn, p) for p in pools)
+
+
+def _split(layers):
+    """(the row layers' leaves, the state layers') of an L-tuple."""
+    return (tuple(p for p in layers if not isinstance(p, SeqState)),
+            tuple(p for p in layers if isinstance(p, SeqState)))
+
+
 def pool_geometry(pools):
-    """(num_blocks, block_size) of the pools of either layout."""
-    return jax.tree_util.tree_leaves(pools)[0].shape[:2]
+    """(num_blocks, block_size) of the pools of any layout (of the first
+    layer that caches rows)."""
+    rows = next(p for p in pools if not isinstance(p, SeqState))
+    return jax.tree_util.tree_leaves(rows)[0].shape[:2]
 
 
 _LANES, _SUBLANES = 128, 8
@@ -114,9 +153,18 @@ def physical_shape(cache_shape):
       128 // D heads side by side on the lanes;
     - latent (W,), W at least a lane row: W rounded up to 128 lanes
       (576 -> 640), the padding zero and never read;
-    - anything else (12 heads x 64, the toy sizes of the CPU tests, a head
-      of 128 or more): the logical shape. Such a pool keeps whatever
-      copies the compiler makes for it."""
+    - heads (H, D), D whole lane rows and H at most half a sublane tile
+      (grouped query attention: 2 KV heads x 256): ONE row of H * D lanes a
+      position, (H * D,). Stored as [.., 2, 256] the 2 heads would sit on
+      the sublanes and be padded to a tile of 8 (float32) or 16 (bfloat16):
+      4 or 8 times the bytes (from 5 heads on the padding stays under
+      double, and the shape is left alone). The row's minor dimensions are
+      then (block_size, H * D), as the latent layout's; the ragged kernel,
+      whose tile is a four-dimensional stored block, does not read such a
+      pool: `generation.decode_layer` gathers it;
+    - anything else (12 heads x 64, the toy sizes of the CPU tests, 8 heads
+      of 128): the logical shape. Such a pool keeps whatever copies the
+      compiler makes for it."""
     cache_shape = tuple(cache_shape)
     if len(cache_shape) == 1:
         (w,) = cache_shape
@@ -125,26 +173,30 @@ def physical_shape(cache_shape):
     if d < _LANES and _LANES % d == 0 \
             and (h * d) % (_SUBLANES * _LANES) == 0:
         return (h * d // _LANES, _LANES)
+    if d % _LANES == 0 and 2 * h <= _SUBLANES:
+        return (h * d,)
     return cache_shape
 
 
-def _to_physical(x, stored):
-    """Values whose trailing dimensions are a logical per-position shape,
-    in the pool's stored one `stored` (`pool.shape[2:]`): heads regroup
-    onto the lanes (a reshape), a latent row is zero-padded. The same
-    values to the bit."""
-    if len(stored) == 1:
+def _to_physical(x, stored, rank):
+    """Values whose `rank` trailing dimensions are a logical per-position
+    shape, in the pool's stored one `stored` (`pool.shape[2:]`): heads
+    regroup onto the lanes (a reshape), a latent row is zero-padded. The
+    same values to the bit."""
+    if rank == 1:
         pad = stored[0] - x.shape[-1]
         return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),)) \
             if pad else x
     return x.reshape(x.shape[:-2] + tuple(stored))
 
 
-def _to_logical(x, cache_shape):
-    """The inverse of `_to_physical`: stored values as `cache_shape`."""
-    if len(cache_shape) == 1:
+def _to_logical(x, cache_shape, stored_rank):
+    """The inverse of `_to_physical`: values whose `stored_rank` trailing
+    dimensions are a stored shape, as `cache_shape` (a row of heads may also
+    be asked for flat, (H * D,): no reshape where it is stored so)."""
+    if len(cache_shape) == 1 and stored_rank == 1:
         return x[..., :cache_shape[0]]
-    return x.reshape(x.shape[:-2] + tuple(cache_shape))
+    return x.reshape(x.shape[:x.ndim - stored_rank] + tuple(cache_shape))
 
 
 def write_rows(pool, rows, slot_blocks, slot_offsets):
@@ -152,7 +204,8 @@ def write_rows(pool, rows, slot_blocks, slot_offsets):
     into pool [num_blocks, block_size, ...stored] at (slot_blocks[n],
     slot_offsets[n]): the decode layer's cache write. Out-of-range block
     ids (num_blocks: padded and frozen rows) are dropped."""
-    rows = _to_physical(rows.astype(pool.dtype), pool.shape[2:])
+    rows = _to_physical(rows.astype(pool.dtype), pool.shape[2:],
+                        rows.ndim - 1)
     return pool.at[slot_blocks, slot_offsets].set(rows, mode="drop")
 
 
@@ -160,12 +213,14 @@ def gather_rows(pool, tables, cache_shape=None):
     """pool [num_blocks, block_size, ...stored] through block tables
     [N, MB] -> [N, MB * block_size, ...cache_shape]: each sequence's
     context in the LOGICAL per-position shape (`cache_shape`; None: the
-    pool is stored as it is read), positions in block-table order
+    pool is stored as it is read; (H * D,) for a pool of (H, D) heads: each
+    position's heads as one row), positions in block-table order
     (position p = block p // block_size, slot p % block_size), live or
     not."""
     n, mb = tables.shape
     ctx = pool[tables].reshape((n, mb * pool.shape[1]) + pool.shape[2:])
-    return ctx if cache_shape is None else _to_logical(ctx, cache_shape)
+    return ctx if cache_shape is None \
+        else _to_logical(ctx, cache_shape, pool.ndim - 2)
 
 
 # ptlint: disable=PT-T009  agrees with the committed plan entry
@@ -195,13 +250,30 @@ def write_prefill_scatter(pools, dense_cache, block_ids, batch_index):
         if row.ndim == 3:                  # heads: [H, S, D] -> [S, H, D]
             row = row.transpose(1, 0, 2)
         bs, stored = pool.shape[1], pool.shape[2:]
-        blk = _to_physical(row.astype(pool.dtype), stored)
+        blk = _to_physical(row.astype(pool.dtype), stored, row.ndim - 1)
         blk = jnp.pad(blk, ((0, n_slots * bs - blk.shape[0]),)
                       + ((0, 0),) * len(stored))
         return pool.at[block_ids].set(
             blk.reshape((n_slots, bs) + stored), mode="drop")
 
     return jax.tree_util.tree_map(scatter, pools, dense_cache)
+
+
+# ptlint: disable=PT-T009  the leaves are rebound from the return value by
+# the one caller (write_prefill), as write_prefill_scatter's pools are; the
+# committed plan's serving entries are traced at a geometry without state
+# layers, so there is no plan entry to consume
+@functools.partial(jax.jit, donate_argnums=(0,))
+def write_state_scatter(states, final, slot, batch_index):
+    """Row `batch_index` of a prefill's final entries (a tuple of
+    `SeqState`, arrays [B, ...shape]) into slot `slot` of the state layers'
+    leaves `states` (arrays [num_state_slots, ...shape]), every layer in one
+    program, the leaves donated. The WHOLE entry is written: whatever the
+    slot held of its last owner is gone."""
+    return jax.tree_util.tree_map(
+        lambda leaf, new: leaf.at[slot].set(jax.lax.dynamic_index_in_dim(
+            new, batch_index, 0, keepdims=False).astype(leaf.dtype)),
+        states, final)
 
 
 class CacheExhausted(RuntimeError):
@@ -248,6 +320,21 @@ class PagedKVCache:
     allocation and retaining a cached block is not (yet) a free; with
     the prefix cache disabled this reduces exactly to the historical
     allocated == freed zero-leak reconciliation.
+
+    State slots (`layout` "hybrid"): `layer_caches` says per layer "rows"
+    or "state" (`ModelSpec.layer_caches`). A rows layer is a (k, v) pair as
+    above. A state layer's leaf is a `SeqState` of arrays
+    [num_state_slots, ...shape] (`state_shapes`): one fixed-size entry a
+    SEQUENCE, whatever its length. The two kinds have ONE owner: `allocate`
+    takes a sequence's blocks and its slot, `free` returns both (a
+    preemption by recompute needs nothing more), `check_integrity` audits
+    both. A slot carries nothing from one owner to the next: a prefill
+    writes the whole entry (`write_prefill`), and a decode layer starts a
+    row at position 0 from zeros (`ModelSpec.decode_layer`). What the
+    hybrid layout cannot do yet raises by name, as on the latent layout:
+    here for int8 pools, the prefix cache and the host tier (a block of
+    rows is not the whole of a prefix: the state after it would have to be
+    kept too), at the call for block migration.
     """
 
     def __init__(self, num_layers: int, cache_shape: Tuple[int, ...],
@@ -255,7 +342,9 @@ class PagedKVCache:
                  enable_prefix_cache: bool = False,
                  host_tier_blocks: int = 0,
                  promote_timeout_s: Optional[float] = None,
-                 kv_cache_dtype: str = "float32"):
+                 kv_cache_dtype: str = "float32",
+                 layer_caches: Tuple[str, ...] = (),
+                 state_shapes: Tuple = (), num_state_slots: int = 0):
         if num_blocks <= 0 or block_size <= 0:
             raise ValueError("num_blocks and block_size must be positive")
         cache_shape = tuple(cache_shape)
@@ -265,8 +354,23 @@ class PagedKVCache:
                 f"(latent_width,), got {cache_shape!r}")
         #: the layout's name as models/spec.py has it (`cache_layout`)
         self.layout = _LAYOUTS[len(cache_shape)]
-        if self.layout == "latent":
-            # one pool a layer; what still assumes (k, v) pairs of heads
+        layer_caches = tuple(layer_caches) or ("rows",) * num_layers
+        if len(layer_caches) != num_layers \
+                or set(layer_caches) - {"rows", "state"} \
+                or "rows" not in layer_caches:
+            raise ValueError(
+                f"layer_caches must name 'rows' or 'state' for each of the "
+                f"{num_layers} layers, 'rows' at least once, got "
+                f"{layer_caches!r}")
+        if "state" in layer_caches:
+            if self.layout != "heads" or not state_shapes \
+                    or num_state_slots <= 0:
+                raise ValueError(
+                    "state layers need (num_heads, head_dim) rows layers, "
+                    "state_shapes and num_state_slots > 0")
+            self.layout = "hybrid"
+        if self.layout != "heads":
+            # what still assumes (k, v) pairs of heads in every layer
             # refuses here, by name, and never falls back
             for feature, asked in (
                     ("int8 KV pools (kv_cache_dtype='int8')",
@@ -277,7 +381,7 @@ class PagedKVCache:
                      host_tier_blocks > 0)):
                 if asked:
                     raise NotImplementedError(
-                        f"the latent cache layout does not support "
+                        f"the {self.layout} cache layout does not support "
                         f"{feature} yet")
         if kv_cache_dtype not in ("float32", "int8"):
             raise ValueError(
@@ -313,8 +417,18 @@ class PagedKVCache:
             self._qpools = None
             self._pools: Tuple[Tuple[jnp.ndarray, jnp.ndarray], ...] = \
                 tuple((jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-                      for _ in range(num_layers))
+                      if kind == "rows" else SeqState(*(
+                          jnp.zeros((num_state_slots,) + tuple(sh), dt)
+                          for sh, dt in state_shapes))
+                      for kind in layer_caches)
         # ----------------------------------------------- host accounting
+        self.layer_caches = layer_caches
+        self.num_state_slots = num_state_slots if self.layout == "hybrid" \
+            else 0
+        # state slots: a free list and seq -> slot, beside the blocks'
+        self._state_free: List[int] = list(
+            range(self.num_state_slots - 1, -1, -1))
+        self._state_slots: Dict[object, int] = {}
         self._free: List[int] = list(range(num_blocks - 1, -1, -1))
         self._tables: Dict[object, List[int]] = {}
         self._lens: Dict[object, int] = {}
@@ -380,7 +494,7 @@ class PagedKVCache:
             return self._pools
         return tuple(
             tuple(_to_physical(kv_quant.dequantize_blocks(q, sc),
-                               self.stored_shape)
+                               self.stored_shape, 2)
                   for q, sc in zip(qkv, scales))
             for qkv, scales in zip(self._qpools, self._scales))
 
@@ -392,9 +506,11 @@ class PagedKVCache:
         qpools, scales = [], []
         for (k, v), (sk, sv) in zip(new_pools, self._scales):
             qk, nsk = kv_quant.requantize_blocks(
-                _to_logical(k, self.cache_shape), sk)
+                _to_logical(k, self.cache_shape, len(self.stored_shape)),
+                sk)
             qv, nsv = kv_quant.requantize_blocks(
-                _to_logical(v, self.cache_shape), sv)
+                _to_logical(v, self.cache_shape, len(self.stored_shape)),
+                sv)
             qpools.append((qk, qv))
             scales.append((nsk, nsv))
         self._qpools = tuple(qpools)
@@ -407,7 +523,25 @@ class PagedKVCache:
         `physical_shape` pads (int8 mode: the codes, scales aside)."""
         stored = self._pools if self._qpools is None else self._qpools
         return sum(int(np.prod(p.shape[2:])) * p.dtype.itemsize
-                   for p in jax.tree_util.tree_leaves(stored))
+                   for layer in stored if not isinstance(layer, SeqState)
+                   for p in jax.tree_util.tree_leaves(layer))
+
+    @property
+    def state_bytes_per_seq(self) -> int:
+        """Bytes one sequence's entries hold over all state layers (0
+        without any): `ModelSpec.state_bytes_per_seq`, from the arrays."""
+        if self.layout != "hybrid":
+            return 0
+        return sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
+                   for layer in self._pools
+                   if isinstance(layer, SeqState) for a in layer)
+
+    def num_state_slots_used(self) -> int:
+        return len(self._state_slots)
+
+    def state_slot(self, seq_id) -> int:
+        """The slot of the sequence's entries in every state layer."""
+        return self._state_slots[seq_id]
 
     def _reset_block_scales(self, ids) -> None:
         """Zero freshly-claimed blocks' scale rows (int8 mode): stale
@@ -845,7 +979,13 @@ class PagedKVCache:
         (prefill). Raises CacheExhausted without side effects."""
         if seq_id in self._tables:
             raise ValueError(f"seq {seq_id!r} already allocated")
+        if self.num_state_slots and not self._state_free:
+            self.alloc_failures += 1
+            raise CacheExhausted(seq_id, 1, 0, self.num_state_slots,
+                                 what="state slot")
         ids = self._take_blocks(seq_id, self.blocks_needed(num_tokens))
+        if self.num_state_slots:
+            self._state_slots[seq_id] = self._state_free.pop()
         self._tables[seq_id] = ids
         self._lens[seq_id] = num_tokens
         return ids
@@ -917,8 +1057,7 @@ class PagedKVCache:
             raise ValueError(f"seq {seq_id!r} already allocated")
         idx = self.prefix_index
         if idx is None:
-            self._tables[seq_id] = []
-            self._lens[seq_id] = 0
+            self.allocate(seq_id, 0)
             return 0
         toks = [int(t) for t in tokens]
         path, partial = idx.match(toks[:len(toks) - 1], touch=True)
@@ -1064,7 +1203,8 @@ class PagedKVCache:
     def _heads_layout_only(self, feature: str) -> None:
         if self.layout != "heads":
             raise NotImplementedError(
-                f"the latent cache layout does not support {feature} yet")
+                f"the {self.layout} cache layout does not support "
+                f"{feature} yet")
 
     def payload_bytes(self, payload) -> int:
         """Wire size of an export_blocks payload (obs histogram food)."""
@@ -1218,6 +1358,10 @@ class PagedKVCache:
         ids = self._tables.pop(seq_id)
         self._lens.pop(seq_id)
         self._seq_tenant.pop(seq_id, None)
+        if seq_id in self._state_slots:
+            # the entry stays as it is: the slot's next owner overwrites
+            # it whole (write_prefill) or starts from zeros at position 0
+            self._state_free.append(self._state_slots.pop(seq_id))
         to_scrub: List[int] = []
         for b in reversed(ids):
             self._refcount[b] -= 1
@@ -1246,8 +1390,7 @@ class PagedKVCache:
         if not block_ids:
             return
         idx = jnp.asarray(list(block_ids), jnp.int32)
-        self.pools = jax.tree_util.tree_map(
-            lambda pool: pool.at[idx].set(0), self.pools)
+        self.pools = _map_rows(lambda pool: pool.at[idx].set(0), self.pools)
 
     def check_integrity(self) -> dict:
         """Invariant audit for the chaos harness: the free list and the
@@ -1280,6 +1423,18 @@ class PagedKVCache:
             "stale_tainted": len(self._tainted - owned),
             "trie_defects": idx.audit() if idx is not None else 0,
         }
+        # state slots: the free list and the owned slots partition them,
+        # no slot has two owners, and exactly the sequences that hold a
+        # table hold one
+        held = list(self._state_slots.values())
+        report["state_slots_leaked"] = self.num_state_slots \
+            - len(set(held) | set(self._state_free))
+        report["state_slots_double_owned"] = \
+            len(held) - len(set(held)) \
+            + len(set(held) & set(self._state_free))
+        report["state_slots_without_table"] = len(
+            set(self._state_slots) ^ set(self._tables)) \
+            if self.num_state_slots else 0
         # cross-tier keys: every trie host node must point at a live
         # store entry (orphan = promoted-from-under-us bug) and every
         # store entry must be reachable from the trie (leaked = host-
@@ -1333,8 +1488,17 @@ class PagedKVCache:
         past the prompt), matching a fresh pool block bit-for-bit. Must
         only run on PRIVATE tables (dense admission never attaches
         shared blocks — any prefix hit is admitted through the chunked
-        path, which writes only the uncached suffix positions)."""
+        path, which writes only the uncached suffix positions).
+
+        On the hybrid layout `dense_cache` holds, in the place of a state
+        layer's rows, the sequence's FINAL entry (a `SeqState` of arrays
+        [B, ...shape]): a second dispatch (`write_state_scatter`, span
+        `serving.prefill.write_state`) writes it whole into the
+        sequence's slot of every state layer."""
         ids = self._tables[seq_id]
+        dense_cache, final = _split(dense_cache)
+        pools = self.pools
+        rows, states = _split(pools)
         # [B, H, S, D] a (k, v) leaf, [B, S, W] a latent one
         leaf = jax.tree_util.tree_leaves(dense_cache)[0]
         batch, seq = leaf.shape[0], leaf.shape[-2]
@@ -1351,8 +1515,17 @@ class PagedKVCache:
                       args={"blocks": len(ids)}):
             padded = np.full((n_slots,), self.num_blocks, np.int32)
             padded[:len(ids)] = ids
-            self.pools = write_prefill_scatter(
-                self.pools, dense_cache, padded, np.int32(batch_index))
+            rows = write_prefill_scatter(rows, dense_cache, padded,
+                                         np.int32(batch_index))
+        if states:
+            slot = self._state_slots[seq_id]
+            with obs.span("serving.prefill.write_state", cat="prefill",
+                          args={"slot": slot}):
+                states = write_state_scatter(
+                    states, final, np.int32(slot), np.int32(batch_index))
+        rows, states = iter(rows), iter(states)
+        self.pools = tuple(next(states) if isinstance(p, SeqState)
+                           else next(rows) for p in pools)
 
     def prefix_stats(self) -> dict:
         """Prefix-cache telemetry snapshot (engine gauges + load suite

@@ -241,7 +241,9 @@ def serving_spec(geom) -> ModelSpec:
     and the engine ignores them. Under `ragged` on a backend that has the
     kernel (ops/pallas/ragged_paged_attention.route_gate) the pools are
     read through the block table inside the kernel and no context is
-    gathered; off-TPU both modes lower to the gather + composed attention.
+    gathered; off-TPU, and on a pool stored as one flat row a position
+    (4 heads x 128, 2 x 256: `paged_cache.physical_shape`), both modes
+    lower to the gather + composed attention.
     tests/test_serving_spec.py holds the three programs' names and
     argument counts."""
     # at call time: the serving package imports this module
@@ -249,12 +251,16 @@ def serving_spec(geom) -> ModelSpec:
     num_layers, num_heads, head_dim, max_seq = geom
 
     def decode_layer(params, i, x, pool, slot_blocks, slot_offsets, tables,
-                     positions, att_lens, live, ragged):
+                     positions, att_lens, live, ragged, state_slots=None):
         kp, vp = pool
         qkv = _decode_qkv(params, i, x, geom)     # [3, N, H, 1, D]
         kp = write_rows(kp, qkv[1][:, :, 0], slot_blocks, slot_offsets)
         vp = write_rows(vp, qkv[2][:, :, 0], slot_blocks, slot_offsets)
-        if ragged and _ragged.route_gate(head_dim, num_heads, kp.shape[1]):
+        # the kernel's tile is a stored block [bs, G, L]; a pool stored as
+        # ONE row of H * D lanes a position (`physical_shape`: at most 4
+        # heads of whole lane rows) has no such tile and takes the gather
+        if ragged and kp.ndim == 4 \
+                and _ragged.route_gate(head_dim, num_heads, kp.shape[1]):
             att = _ragged.ragged_decode_attention(
                 qkv[0][:, :, 0, :], kp, vp, tables, att_lens)
             x = _attn_merge(params, i, x, att[:, :, None, :], geom)

@@ -340,7 +340,8 @@ def _token_embed(params, tokens, positions):
 
 
 def _decode_layer(cfg, params, i, x, pool, slot_blocks, slot_offsets,
-                  tables, positions, att_lens, live, ragged):
+                  tables, positions, att_lens, live, ragged,
+                  state_slots=None):
     """One layer for N rows of one token each against the latent pool:
     write the token's row at its slot, gather each row's blocks through
     its table (`paged_cache.write_rows` / `gather_rows`), attend in the

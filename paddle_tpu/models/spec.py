@@ -8,9 +8,9 @@ what one position caches. The spec is the seam between the two: one
 frozen (hashable, so usable as a jit static argument) object per (family,
 configuration). Every family builds its own, `serving_spec(...)` beside
 the functions it wraps (`models.generation.serving_spec`, the first, and
-`models.pangu_moe.serving_spec`); both are cached, so the same
-configuration always gives the same object and the same compiled
-programs. The serving layer imports this module and no family.
+`models.pangu_moe.serving_spec`, `models.qwen3_next.serving_spec`); all
+are cached, so the same configuration always gives the same object and the
+same compiled programs. The serving layer imports this module and no family.
 
 Cache layouts (`PagedKVCache` builds the pools from `cache_shape` and
 `cache_dtype`; a decode layer writes and reads them through
@@ -21,7 +21,17 @@ to `write_prefill_scatter`):
 - "latent": ONE pool a layer, [num_blocks, block_size, W] (multi-head
             latent attention: the normed compressed key-value and the
             rotated shared key); `cache_shape` is (W,); dense prefill rows
-            are [B, S, W].
+            are [B, S, W];
+- "hybrid": `layer_caches` says per LAYER what it caches. A "rows" layer
+            is a "heads" layer, a (k, v) pair of pools of `cache_shape`
+            (H, D). A "state" layer caches no position: it keeps ONE
+            fixed-size entry a SEQUENCE (a recurrent state), the leaves
+            `state_shapes` names, stored [num_state_slots, ...shape] as a
+            `paged_cache.SeqState`; the cache manager gives a sequence a
+            slot with its blocks, `decode_layer` reads and writes the
+            entries at `state_slots`, and a prefill hands back each
+            sequence's final entry [B, ...shape] in the place of dense
+            rows.
 """
 from __future__ import annotations
 
@@ -44,19 +54,23 @@ class ModelSpec:
     family: str
     num_layers: int
     max_seq_len: int
-    cache_layout: str                 # "heads" | "latent"
+    cache_layout: str                 # "heads" | "latent" | "hybrid"
     cache_shape: Tuple[int, ...]      # per position and pool
     cache_dtype: str                  # numpy/jax dtype name of the pools
     #: (params, tokens [N], positions [N]) -> x [N, 1, h]
     embed: Callable
     #: (params, i, x, layer_pool, slot_blocks [N], slot_offsets [N],
     #:  tables [N, MB], positions [N], att_lens [N], live [N] bool,
-    #:  ragged: bool) -> (x, layer_pool, counts): writes the new token's
-    #: cache row at (slot_block, slot_offset) (out-of-range blocks are
-    #: dropped), attends row n to its first att_lens[n] positions through
-    #: its block table, runs the rest of layer i. `layer_pool` is the
-    #: layout's per-layer leaf: a (k, v) pair or one array. `counts` is an
-    #: int32 vector of len(counters), or None.
+    #:  ragged: bool, state_slots [N] or None) -> (x, layer_pool, counts):
+    #: writes the new token's cache row at (slot_block, slot_offset)
+    #: (out-of-range blocks are dropped), attends row n to its first
+    #: att_lens[n] positions through its block table, runs the rest of
+    #: layer i. `layer_pool` is the layout's per-layer leaf: a (k, v) pair,
+    #: one array, or a state layer's `SeqState`, whose entry of row n is at
+    #: `state_slots[n]` (None where the spec has no state layer; a row at
+    #: position 0 starts from a zero entry whatever its slot held, a row
+    #: not `live` leaves its entry as it is). `counts` is an int32 vector
+    #: of len(counters), or None.
     decode_layer: Callable
     #: (params, x [N, 1, h]) -> logits [N, V]
     head: Callable
@@ -68,15 +82,35 @@ class ModelSpec:
     counters: Tuple[str, ...] = ()
     #: what the functions above were built from (a config, a geometry)
     config: Hashable = None
+    #: per layer "rows" (a cache row a position) or "state" (one entry a
+    #: sequence); () = rows in every layer
+    layer_caches: Tuple[str, ...] = ()
+    #: the leaves of a state layer's entry, per sequence: ((shape, dtype
+    #: name), ...)
+    state_shapes: Tuple[Tuple[Tuple[int, ...], str], ...] = ()
 
     @property
     def pools_per_layer(self) -> int:
-        return 2 if self.cache_layout == "heads" else 1
+        return 1 if self.cache_layout == "latent" else 2
+
+    @property
+    def state_layers(self) -> int:
+        return sum(c == "state" for c in self.layer_caches)
 
     @property
     def cache_bytes_per_token(self) -> int:
+        """Bytes one more position costs: the layers that cache rows."""
         import math
         import jax.numpy as jnp      # bfloat16 is jax's, not numpy's
-        return (self.num_layers * self.pools_per_layer
+        return ((self.num_layers - self.state_layers) * self.pools_per_layer
                 * math.prod(self.cache_shape)
                 * jnp.dtype(self.cache_dtype).itemsize)
+
+    @property
+    def state_bytes_per_seq(self) -> int:
+        """Bytes a sequence costs whatever its length: the state layers."""
+        import math
+        import jax.numpy as jnp
+        return self.state_layers * sum(
+            math.prod(shape) * jnp.dtype(dtype).itemsize
+            for shape, dtype in self.state_shapes)

@@ -25,6 +25,9 @@ from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
     ((12, 64), jnp.float32, (12, 64)),     # 6 lane rows: no whole tile
     ((6, 128), jnp.float32, (6, 128)),
     ((2, 16), jnp.float32, (2, 16)),       # the toy sizes of the CPU tests
+    ((2, 256), jnp.bfloat16, (512,)),      # 2 KV heads of 256: one row of
+    ((2, 256), jnp.float32, (512,)),       # 512 lanes, no sublane padding
+    ((4, 128), jnp.bfloat16, (512,)),
     ((576,), jnp.bfloat16, (640,)),        # the latent row, to lane rows
     ((512,), jnp.bfloat16, (512,)),
     ((24,), jnp.float32, (24,)),           # under one lane row: as it is
@@ -41,9 +44,9 @@ def test_the_stored_shape_is_decided_from_the_logical_one(cache_shape, dtype,
                for a in leaves)
 
 
-_SHAPES = [(16, 64), (576,), (130,), (2, 16), (12,)]
+_SHAPES = [(16, 64), (576,), (130,), (2, 16), (12,), (2, 256)]
 _IDS = ["packed", "padded-576", "padded-130", "heads-logical",
-        "latent-logical"]
+        "latent-logical", "heads-flat"]
 
 
 @pytest.mark.parametrize("cache_shape", _SHAPES, ids=_IDS)
@@ -186,37 +189,92 @@ def test_the_bytes_a_position_holds_logical_and_as_stored(
         * jnp.dtype(dtype).itemsize == logical
 
 
-@pytest.fixture(scope="module")
-def packed_model():
-    """The smallest GPT whose pools pack: 16 heads x 64."""
-    from paddle_tpu.models.gpt import GPT, GPTConfig
-    return GPT(GPTConfig(vocab_size=96, hidden_size=1024, num_layers=1,
-                         num_heads=16, max_seq_len=64))
-
-
-def test_an_engine_on_packed_pools_serves_generate_s_tokens(packed_model):
-    """Through `LLMEngine`: the pools are stored packed, both gauges say
-    what a position costs (the logical figure the spec's, the stored one
-    the cache's, equal here: packing pads nothing), and the tokens are
-    `generate()`'s, from a dense prefill and from a chunked one."""
+@pytest.mark.parametrize("heads, head_dim, stored", [
+    (16, 64, (8, 128)),            # the smallest GPT whose pools pack
+    (4, 128, (512,)),              # one flat row a position: on the chip
+    (2, 256, (512,)),              # it must not reach the ragged kernel
+], ids=["packed", "flat-4x128", "flat-2x256"])
+def test_an_engine_serves_generate_s_tokens_on_pools_as_stored(
+        heads, head_dim, stored):
+    """Through `LLMEngine` with the default kernel ("ragged": on the chip,
+    PADDLE_TPU_TEST_REAL_TPU=1, the kernel's gate is open): the pools are
+    stored packed or flat, both gauges say what a position costs (the
+    logical figure the spec's, the stored one the cache's, equal here:
+    neither pads), and the tokens are `generate()`'s, from a dense prefill
+    and from a chunked one."""
     from paddle_tpu.models.generation import generate
-    eng = LLMEngine.from_model(packed_model, EngineConfig(
+    from paddle_tpu.models.gpt import GPT, GPTConfig
+    model = GPT(GPTConfig(vocab_size=96, hidden_size=heads * head_dim,
+                          num_layers=1, num_heads=heads, max_seq_len=64))
+    eng = LLMEngine.from_model(model, EngineConfig(
         block_size=8, num_blocks=24, max_num_seqs=4,
         prefill_chunk_threshold=12))
-    assert [p.shape for p in eng.cache.pools[0]] == [(24, 8, 8, 128)] * 2
-    assert eng.spec.cache_shape == (16, 64)
-    assert eng.stats.cache_bytes_per_token == 2 * 16 * 64 * 4 \
-        == eng.spec.cache_bytes_per_token
-    assert eng.stats.cache_physical_bytes_per_token == 2 * 8 * 128 * 4
+    assert eng.config.kernel == "ragged"
+    assert [p.shape for p in eng.cache.pools[0]] == [(24, 8) + stored] * 2
+    assert eng.spec.cache_shape == (heads, head_dim)
+    assert eng.stats.cache_bytes_per_token == 2 * heads * head_dim * 4 \
+        == eng.spec.cache_bytes_per_token \
+        == eng.stats.cache_physical_bytes_per_token
     prompts = [np.arange(3, 10, dtype=np.int32),
                np.arange(20, 37, dtype=np.int32)]      # dense, chunked
     rids = [eng.add_request(p, SamplingParams(max_tokens=6))
             for p in prompts]
     out = eng.run(max_steps=200)
     for rid, p in zip(rids, prompts):
-        want = np.asarray(generate(packed_model, p[None], 6))[0, len(p):]
+        want = np.asarray(generate(model, p[None], 6))[0, len(p):]
         np.testing.assert_array_equal(np.asarray(out[rid]), want)
     assert not any(eng.cache.check_integrity().values())
+
+
+@pytest.mark.parametrize("heads, head_dim, stored, through_kernel", [
+    (4, 128, (512,), False),       # one flat row a position: no kernel tile
+    (2, 256, (512,), False),
+    (8, 128, (8, 128), True),      # stored as it is: the kernel's tile
+    (16, 64, (8, 128), True),      # packed: the kernel's tile
+])
+def test_the_decode_layer_under_the_ragged_route_reads_every_stored_shape(
+        monkeypatch, heads, head_dim, stored, through_kernel):
+    """GPT-2's decode layer as the chip runs it (`ragged` on, the kernel's
+    gate open; off the chip the kernel in interpret mode, on it
+    (PADDLE_TPU_TEST_REAL_TPU=1) the kernel itself) on the pools as
+    `PagedKVCache` stores them: a four-dimensional pool goes through the
+    kernel, a flat row of H * D lanes takes the gather, and both give what
+    the gather path gives."""
+    from paddle_tpu.models import generation as gen
+    from paddle_tpu.models.gpt import GPT, GPTConfig
+    model = GPT(GPTConfig(vocab_size=32, hidden_size=heads * head_dim,
+                          num_layers=1, num_heads=heads, max_seq_len=32))
+    params = gen.extract_params(model)
+    spec = gen.serving_spec((1, heads, head_dim, 32))
+    rng = np.random.default_rng(heads)
+    pc = PagedKVCache(1, (heads, head_dim), num_blocks=8, block_size=8)
+    assert [p.shape[2:] for p in pc.pools[0]] == [stored] * 2
+    pool = tuple(jnp.asarray(0.1 * rng.normal(size=p.shape), p.dtype)
+                 for p in pc.pools[0])
+    positions = np.asarray([0, 11, 23], np.int32)       # 1, 2 and 3 blocks
+    tables = np.asarray([[5, 8, 8, 8], [1, 6, 8, 8], [0, 3, 7, 8]], np.int32)
+    calls = []
+    kernel, on_chip = rpa.ragged_decode_attention, \
+        jax.default_backend() == "tpu"
+
+    def counted(*args):
+        calls.append(args[1].shape)
+        return kernel(*args, interpret=not on_chip)
+
+    monkeypatch.setattr(rpa, "supported", lambda *geometry: True)
+    monkeypatch.setattr(rpa, "ragged_decode_attention", counted)
+    x = spec.embed(params, jnp.asarray([3, 7, 9], jnp.int32),
+                   jnp.asarray(positions))
+    got, want = (spec.decode_layer(
+        params, 0, x, pool, tables[np.arange(3), positions // 8],
+        positions % 8, jnp.asarray(tables), jnp.asarray(positions),
+        jnp.asarray(positions + 1), jnp.ones((3,), bool), ragged)
+        for ragged in (True, False))
+    assert calls == ([(8, 8) + stored] if through_kernel else [])
+    tol = 2e-3 if on_chip else 1e-5
+    np.testing.assert_allclose(got[0], want[0], rtol=tol, atol=tol)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def _filled_packed_cache(seed):

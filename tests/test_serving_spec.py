@@ -115,6 +115,51 @@ def test_the_scan_module_imports_no_family():
         n.lstrip(".").startswith("models.spec") for n in models), models
 
 
+@pytest.mark.parametrize("family", ["latent", "hybrid"])
+def test_the_other_families_chunk_keeps_its_name_and_argument_shapes(family):
+    """The one decode program under every family: the latent family's
+    upload is [rows, PACK_COLS + k + max_blocks] as the frozen runners
+    shape it, a family with state layers carries ONE more column (the
+    row's state slot) and its state leaves among the donated pools."""
+    from paddle_tpu.inference.serving.attention import PACK_COLS
+    from paddle_tpu.models import pangu_moe, qwen3_next
+    if family == "latent":
+        cfg = pangu_moe.PanguMoEConfig(
+            vocab_size=64, hidden_size=32, num_hidden_layers=2,
+            first_k_dense_replace=1, num_attention_heads=2, q_lora_rank=16,
+            kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, intermediate_size=32, moe_intermediate_size=16,
+            n_routed_experts=4, num_experts_per_tok=2, max_seq_len=32)
+        spec, shapes = pangu_moe.serving_spec(cfg), \
+            pangu_moe.param_shapes(cfg)
+        leaves, extra = 2, 0                    # one pool a layer
+    else:
+        cfg = qwen3_next.Qwen3NextConfig(
+            vocab_size=64, hidden_size=32, num_hidden_layers=4,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+            linear_num_key_heads=2, linear_num_value_heads=2,
+            linear_key_head_dim=8, linear_value_head_dim=8,
+            moe_intermediate_size=16, shared_expert_intermediate_size=16,
+            num_experts=4, num_experts_per_tok=2, max_seq_len=32)
+        spec, shapes = qwen3_next.serving_spec(cfg), \
+            qwen3_next.param_shapes(cfg)
+        leaves, extra = 3 * 2 + 2, 1            # (S, conv) x 3 and (k, v)
+    params = {n: jnp.zeros(s, d) for n, (s, d) in shapes.items()}
+    pc = PagedKVCache(spec.num_layers, spec.cache_shape, 8, 8,
+                      layer_caches=spec.layer_caches,
+                      state_shapes=spec.state_shapes, num_state_slots=2)
+    packed = np.zeros((2, PACK_COLS + 8 + 32 // 8 + extra), np.int32)
+    lowered = fused_decode_chunk.lower(params, pc.pools, packed, spec, 8)
+    assert "module @jit_fused_decode_chunk " in lowered.as_text()
+    flat_in = jax.tree_util.tree_leaves(lowered.in_avals)
+    assert len(flat_in) == len(params) + leaves + 1
+    # the result and the pools back, and the spec's four counts as rows
+    out = jax.tree_util.tree_leaves(lowered.out_info)
+    assert len(out) == 1 + leaves and out[0].shape == (8 + 2 + 4, 2)
+    # every pool leaf is donated: it aliases its output
+    assert lowered.as_text().count("tf.aliasing_output") == leaves
+
+
 def test_the_gpt_layer_names_its_own_spec(gpt):
     """`LLMEngine.from_model` asks every model the same question."""
     spec = gpt[0].serving_spec()
