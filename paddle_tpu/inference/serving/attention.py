@@ -10,7 +10,9 @@ is the same for all of them:
 - `fused_decode_chunk`: k such steps as ONE `lax.scan` on the device
   with sampling and termination in the carry. The host uploads one packed
   int32 array (layout at `PACK_COLS`) and fetches one int32 result.
-- `_sample_rows`: the branchless per-row sampler inside the scan.
+- `_sample_rows`: the per-row sampler inside the scan. It does only what
+  a chunk's rows ask for: the argmax when no row samples, the scaled
+  categorical draw when none truncates, one sort when one does.
 
 Batch shape: everything here is shape-polymorphic only in
 (N, max_blocks_per_seq, num_blocks). Under the default ragged kernel
@@ -136,35 +138,63 @@ def pack_f32(x) -> int:
     return int(np.float32(x).view(np.int32))
 
 
-def _sample_rows(logits, keys, temps, top_ks, top_ps):
-    """Branchless per-row sampling over [N, V] logits — the device twin
-    of LLMEngine._sample / generation._sampling_rollout: greedy when
-    temp<=0, else temperature softmax restricted by top-k (kth-largest
-    threshold, ties kept) and nucleus top-p (smallest prefix of the
+def _truncate(lg, top_ks, top_ps):
+    """Mask [N, V] temperature-scaled logits by each row's top-k
+    (kth-largest threshold, ties kept, like the host sampler's
+    kth = sort(lg)[-top_k]) and nucleus top-p (smallest prefix of the
     descending distribution with cumulative mass >= top_p; the kept set
     is computed with an EXCLUSIVE cumsum so the crossing token stays).
-    All rows run every path; jnp.where selects, so the program is a
-    fixed dataflow suitable as a lax.scan body."""
-    vocab = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    lg = logits.astype(jnp.float32) / jnp.where(temps > 0, temps, 1.0)[:, None]
-    # top-k: threshold at the k-th largest value (ties kept, like the
-    # host sampler's kth = sort(lg)[-top_k]).
+    ONE sort: top-k's mask is monotone, so the descending sort of the
+    masked logits is the mask of the descending sort, value for value."""
+    vocab = lg.shape[-1]
+    use_k = (top_ks > 0)[:, None]
     srt = jnp.sort(lg, axis=-1)[:, ::-1]
     kth = jnp.take_along_axis(
         srt, jnp.clip(top_ks - 1, 0, vocab - 1)[:, None], axis=1)
-    lg = jnp.where((top_ks[:, None] > 0) & (lg < kth), -1e30, lg)
+    lg = jnp.where(use_k & (lg < kth), -1e30, lg)
+    srt = jnp.where(use_k & (srt < kth), -1e30, srt)
     # top-p: exclusive cumulative mass < top_p keeps the crossing token.
-    srt = jnp.sort(lg, axis=-1)[:, ::-1]
     probs = jax.nn.softmax(srt, axis=-1)
     excl = jnp.cumsum(probs, axis=-1) - probs
     n_keep = jnp.sum(excl < top_ps[:, None], axis=-1)
     pth = jnp.take_along_axis(
         srt, jnp.clip(n_keep - 1, 0, vocab - 1)[:, None], axis=1)
     use_p = (top_ps > 0.0) & (top_ps < 1.0)
-    lg = jnp.where(use_p[:, None] & (lg < pth), -1e30, lg)
-    sampled = jax.vmap(jax.random.categorical)(keys, lg).astype(jnp.int32)
-    return jnp.where(temps <= 0.0, greedy, sampled)
+    return jnp.where(use_p[:, None] & (lg < pth), -1e30, lg)
+
+
+def _sample_rows(logits, base_keys, out_cnt, temps, top_ks, top_ps,
+                 any_sampled, any_truncated):
+    """Per-row sampling over [N, V] logits — the device twin of
+    LLMEngine._sample / generation._sampling_rollout: greedy when
+    temp<=0, else a categorical draw from the temperature softmax
+    restricted by top-k and top-p (`_truncate`), keyed by
+    fold_in(seed_key, out_cnt).
+
+    It does only what the rows ask for, under two scalar predicates the
+    caller takes from the chunk's control columns: no row samples
+    (`any_sampled` false) -> the argmax and nothing else; rows sample
+    but none truncates (`any_truncated` false) -> the scaled draw with
+    no sort, softmax or cumsum. Inside a branch every row runs the same
+    dataflow and jnp.where selects, so a row's token does not depend on
+    which branch its neighbours put it in."""
+    def greedy(logits):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def sampled(logits):
+        keys = jax.vmap(jax.random.fold_in)(base_keys, out_cnt)
+        lg = logits.astype(jnp.float32) \
+            / jnp.where(temps > 0, temps, 1.0)[:, None]
+
+        def draw(lg):
+            return jax.vmap(jax.random.categorical)(keys, lg)
+
+        drawn = lax.cond(any_truncated,
+                         lambda lg: draw(_truncate(lg, top_ks, top_ps)),
+                         draw, lg).astype(jnp.int32)
+        return jnp.where(temps <= 0.0, greedy(logits), drawn)
+
+    return lax.cond(any_sampled, sampled, greedy, logits)
 
 
 # ptlint: disable=PT-T009  agrees with the committed plan entry
@@ -249,6 +279,11 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
     base_keys = jax.vmap(jax.random.PRNGKey)(packed[:, 9])
     pf_more = packed[:, 11] > 0
     ragged = kernel == "ragged"
+    # what the chunk's rows ask of the sampler: chunk-invariant scalars
+    samples = active & (temps > 0)
+    any_sampled = jnp.any(samples)
+    any_truncated = jnp.any(
+        samples & ((top_ks > 0) | ((top_ps > 0) & (top_ps < 1))))
 
     def body(carry, feed_j):
         pools, tok, pos, out_cnt, finished, bad, pf_left, counts = carry
@@ -276,8 +311,8 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
         logits = spec.head(params, x)
         row_bad = rows_not_finite(logits) & run
         bad = bad | row_bad
-        keys = jax.vmap(jax.random.fold_in)(base_keys, out_cnt)
-        tok_new = _sample_rows(logits, keys, temps, top_ks, top_ps)
+        tok_new = _sample_rows(logits, base_keys, out_cnt, temps, top_ks,
+                               top_ps, any_sampled, any_truncated)
         ok = run & ~row_bad
         step_ok = ok & sampling
         emit = jnp.where(step_ok, tok_new, -1)

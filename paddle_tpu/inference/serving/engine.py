@@ -199,7 +199,11 @@ _STAT_EVENTS = ("steps", "prefill_tokens", "generated_tokens",
                 "moe_pairs", "moe_experts_hit", "moe_full_buffer_layers",
                 # the `context_tokens` and `live_row_trips` stats of
                 # serving.decode, summed
-                "context_tokens", "live_row_trips")
+                "context_tokens", "live_row_trips",
+                # decode chunks dispatched with at least one row that
+                # samples (temperature > 0): the chunks whose scan took
+                # the sampler's sampled branch
+                "sampled_chunks")
 # float phase-time accumulators (serving_phase_seconds_total{engine,phase})
 _STAT_PHASES = {"time_schedule": "schedule", "time_prefill": "prefill",
                 "time_decode": "decode"}
@@ -1449,10 +1453,15 @@ class LLMEngine:
                 row_trips = sum(_live_trips(r, k) for r in decode)
                 self.stats.context_tokens += context
                 self.stats.live_row_trips += row_trips
+                sampled_rows = sum(1 for r in decode
+                                   if r.params.temperature > 0)
+                if sampled_rows:
+                    self.stats.sampled_chunks += 1
                 with RecordEvent("serving.decode", cat="decode", args={
                         "num_seqs": len(decode), "chunk": k,
                         "context_tokens": context,
-                        "live_row_trips": row_trips}) as ev:
+                        "live_row_trips": row_trips,
+                        "sampled_rows": sampled_rows}) as ev:
                     # ptlint: disable=PT-C004  fault injector: stalls ON
                     # PURPOSE under the lock to exercise the watchdog
                     self.faults.stall(step_no)

@@ -66,6 +66,14 @@ def _prompts(n, seed=7, lo=3, hi=8):
             for _ in range(n)]
 
 
+def _await_rejoin(rs):
+    """`rs.run()` returns when the requests are done, and the survivor can
+    manage that before a killed replica's 10 ms backoff has passed: the
+    chaos harness's wait for the replica to be back in rotation."""
+    import tools.chaos_serve as cs
+    cs._await_rejoin(rs, 0, 3000)
+
+
 def _assert_no_leaks(rs):
     for idx, audit in rs.check_integrity().items():
         assert audit is not None, f"replica {idx} has no live engine"
@@ -222,6 +230,7 @@ def test_killed_replica_rejoins_after_warmup_probe(model):
     rids = [rs.add_request(p, SamplingParams(max_tokens=8))
             for p in _prompts(6)]
     rs.run(max_steps=3000)
+    _await_rejoin(rs)
     rep = rs.replicas[1]
     assert rep.state == ReplicaState.UP
     assert rep.restarts == 1
@@ -382,6 +391,7 @@ def test_router_metrics_families(model):
     for p in _prompts(4):
         rs.add_request(p, SamplingParams(max_tokens=6))
     rs.run(max_steps=3000)
+    _await_rejoin(rs)
     fams = {f["name"]: f for f in obs.snapshot()["metrics"]}
     for name in ("serving_replica_up", "serving_failovers_total",
                  "serving_requeued_total", "serving_router_ttft_seconds",

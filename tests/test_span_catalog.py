@@ -123,8 +123,10 @@ def test_decode_span_counts_the_positions_its_rows_attend_to(engine_trace):
     # 6..10 positions; "three": 3 and 1 of 12: all 8 trips, 4..11
     by_hand = sum(range(6, 11)) + sum(range(4, 12))
     # and the live rows summed over the trips: 5 + 8
+    # both requests are greedy: no row of the chunk samples
     assert decode[3] == {"num_seqs": 2, "chunk": 8,
-                         "context_tokens": by_hand, "live_row_trips": 13}
+                         "context_tokens": by_hand, "live_row_trips": 13,
+                         "sampled_rows": 0}
     steps = [s[3]["step"] for s in engine_trace
              if s[0] == "serving.engine_step"]
     assert steps == [1, 2]

@@ -232,14 +232,11 @@ def test_write_prefill_scatter_updates_the_pools_in_place(one_chip):
                       (1, 16, 1024, 64))
 
 
-def test_the_decode_chunk_copies_no_pool_at_the_serving_geometry(
-        one_chip, monkeypatch):
+@functools.lru_cache(maxsize=None)
+def _decode_chunk_compiled(one_chip):
     """`jit_fused_decode_chunk` at the GPT-2 serving cells' geometry (two
     layers of GPT-2 medium's 16 heads x 64, 16 rows, 512 blocks x 32, a
-    small vocabulary), the ragged kernel routed as on the chip: the pools
-    stay block-major (`[512,32,8,128]`, row-major tiled), so no pool is
-    copied in or out, every pool is aliased to its output, and the kernel
-    is called once a layer under its name, on the pool as it is stored."""
+    small vocabulary), the ragged kernel routed as on the chip."""
     from paddle_tpu.inference.serving.attention import (PACK_COLS,
                                                         fused_decode_chunk)
     from paddle_tpu.inference.serving.paged_cache import physical_shape
@@ -254,13 +251,26 @@ def test_the_decode_chunk_copies_no_pool_at_the_serving_geometry(
                           max_seq_len=seq))
     params = {k: sds(v.shape, v.dtype)
               for k, v in gen.extract_params(model).items()}
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     pool = sds((512, 32) + physical_shape((heads, head_dim)), jnp.float32)
     assert pool.shape == (512, 32, 8, 128)
-    compiled = fused_decode_chunk.lower(
-        params, ((pool, pool),) * layers,
-        sds((16, PACK_COLS + 8 + seq // 32), jnp.int32), geom, 8,
-        "ragged").compile()
+    backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        return fused_decode_chunk.lower(
+            params, ((pool, pool),) * layers,
+            sds((16, PACK_COLS + 8 + seq // 32), jnp.int32), geom, 8,
+            "ragged").compile()
+    finally:
+        jax.default_backend = backend
+
+
+def test_the_decode_chunk_copies_no_pool_at_the_serving_geometry(one_chip):
+    """The pools stay block-major (`[512,32,8,128]`, row-major tiled), so
+    no pool is copied in or out, every pool is aliased to its output, and
+    the kernel is called once a layer under its name, on the pool as it is
+    stored."""
+    layers = 2
+    compiled = _decode_chunk_compiled(one_chip)
     text = compiled.as_text()
     assert _pool_copies(text, 512) == []
     assert "[512,32,8,128]{3,2,1,0:T(8,128)}" in text
@@ -273,6 +283,44 @@ def test_the_decode_chunk_copies_no_pool_at_the_serving_geometry(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * layers * pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes
+
+
+def _computation(text: str, name: str) -> str:
+    """The HLO computation `name` of a compiled program's text with every
+    computation it calls (fusions, reducers, branches), as one string."""
+    bodies = dict(re.findall(r"^(%[\w.-]+) \(.*?\{\n(.*?)^\}", text,
+                             flags=re.M | re.S))
+    seen, todo = [], [name]
+    while todo:
+        n = todo.pop()
+        if n in seen:
+            continue
+        seen.append(n)
+        todo += [c for c in re.findall(r"%[\w.-]+", bodies[n])
+                 if c in bodies]
+    return "\n".join(bodies[n] for n in seen)
+
+
+def test_the_decode_chunk_sorts_only_where_a_row_samples(one_chip):
+    """The same program: the sampler is a `conditional` on whether any
+    row of the chunk samples. Its greedy computation is the argmax alone
+    (no sort, softmax, cumulative sum or random bits), and the whole program
+    sorts `[16, V]` once, in the branch of the rows that truncate (the
+    branchless sampler sorted twice, every trip)."""
+    text = _decode_chunk_compiled(one_chip).as_text()
+    conds = re.findall(
+        r"= \(s32\[16\]\S*\) conditional\(.*?"
+        r"branch_computations=\{(%[\w.-]+), (%[\w.-]+)\}", text)
+    assert len(conds) == 1
+    greedy, sampled = (_computation(text, c) for c in conds[0])
+    # the sort, the softmax, the cumsum, the key schedule, the inner cond
+    costly = (" sort(", " exponential(", " reduce-window(", " xor(",
+              " conditional(")
+    for op in costly:
+        assert op not in greedy and op in sampled, op
+    assert " reduce(" in greedy and " iota(" in greedy      # the argmax
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert len(sorts) == 1 and "f32[16,512]" in sorts[0]
 
 
 def test_flash_compiles_per_shard_under_a_mesh(topo):

@@ -194,6 +194,21 @@ def _build_model(vocab=97, hidden=32, layers=2, heads=4, seq=48):
     return m, cfg
 
 
+def _await_rejoin(rs, steps: int, max_steps: int) -> None:
+    """The survivors can drain a run's requests before a killed replica's
+    restart backoff has passed (the faster the decode step, the sooner):
+    keep the router's housekeeping ticking until no replica is DOWN or
+    STARTING any more. A FAILED one never rejoins; the gates name it."""
+    import time
+    while any(str(s) in ("down", "starting") for s in rs.states().values()):
+        rs.step()
+        steps += 1
+        assert steps <= max_steps, \
+            f"a killed replica failed to rejoin in {max_steps} steps " \
+            f"(states {rs.states()})"
+        time.sleep(0.002)
+
+
 def run_chaos(seed: int = 0, n_requests: int = 16,
               faults: str = DEFAULT_FAULTS, max_steps: int = 400,
               cancel_every: int = 0, prefix_cache: bool = False,
@@ -665,6 +680,7 @@ def run_chaos_replicas(seed: int = 0, n_requests: int = 24,
             if not any(r.has_unfinished() for r in rs.replicas) \
                     and rs.has_unfinished():
                 time.sleep(0.002)               # restart backoff pending
+        _await_rejoin(rs, steps, max_steps)
         return rs, rids, homes
 
     # reference pass: same workload through an unfaulted router (defines
@@ -855,6 +871,7 @@ def run_chaos_disagg(seed: int = 0, n_requests: int = 18,
             if not any(r.has_unfinished() for r in rs.replicas) \
                     and rs.has_unfinished():
                 time.sleep(0.002)               # restart backoff pending
+        _await_rejoin(rs, steps, max_steps)
         return rs, rids
 
     # reference pass: same workload, same tiers, no faults — handoffs
