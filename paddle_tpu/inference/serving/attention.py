@@ -67,8 +67,47 @@ def as_spec(geom) -> ModelSpec:
     return serving_spec(tuple(geom))
 
 
+def _pool_of(spec, pools, kind):
+    """One block pool of the first layer of `kind` ("rows", "window"): its
+    shape[:2] is the group's (num_blocks, block_size)."""
+    layer = pools[spec.layer_caches.index(kind)]
+    return jax.tree_util.tree_leaves(layer)[0]
+
+
+def _window_slots(spec, pools, window_tables, window_firsts, pos, run):
+    """What a window layer's call takes in the place of the block table
+    and its write slot, for rows whose token is at `pos` [N] (`run` [N]: the
+    others' writes are dropped): (slot_blocks in the window group, the
+    window tables, att_starts, table_starts)."""
+    num_wblocks, block_size = _pool_of(spec, pools, "window").shape[:2]
+    entry = jnp.clip(pos // block_size - window_firsts, 0,
+                     window_tables.shape[1] - 1)
+    slot_blocks = jnp.where(
+        run, jnp.take_along_axis(window_tables, entry[:, None], axis=1)[:, 0],
+        num_wblocks)
+    att_starts = jnp.maximum(pos + 1 - spec.window, 0).astype(jnp.int32)
+    return slot_blocks, window_tables, att_starts, window_firsts * block_size
+
+
+def _layer(spec, params, i, x, pool, slot_blocks, slot_offsets, tables,
+           in_window, positions, att_lens, live, ragged, state_slots):
+    """Layer i through the spec: a window layer with its own group's write
+    slot, table and the two arguments that say where its attention starts
+    (`in_window`, from `_window_slots`), every other layer as ever."""
+    if spec.window and spec.layer_caches[i] == "window":
+        slot_blocks, tables, att_starts, table_starts = in_window
+        return spec.decode_layer(
+            params, i, x, pool, slot_blocks, slot_offsets, tables,
+            positions, att_lens, live, ragged, state_slots, att_starts,
+            table_starts)
+    return spec.decode_layer(
+        params, i, x, pool, slot_blocks, slot_offsets, tables, positions,
+        att_lens, live, ragged, state_slots)
+
+
 def paged_decode_step(params, pools, tokens, positions, block_tables,
-                      slot_blocks, slot_offsets, geom, state_slots=None):
+                      slot_blocks, slot_offsets, geom, state_slots=None,
+                      window_tables=None, window_firsts=None):
     """One ragged decode step over the block pool.
 
     params: the models.generation.extract_params dict.
@@ -86,6 +125,10 @@ def paged_decode_step(params, pools, tokens, positions, block_tables,
     state_slots [N] int32 — where the spec has state layers
         (`ModelSpec.layer_caches`), each sequence's slot in their
         `SeqState` leaves (`PagedKVCache.state_slot`); else None.
+    window_tables [N, WB] int32, window_firsts [N] int32 — where the spec
+        has window layers, each sequence's window blocks padded with 0 and
+        the logical index of the first (`PagedKVCache.window_table`); the
+        write slot in the window group is derived from `positions`.
 
     Returns (logits [N, V], updated pools). Composed of the spec's
     functions — for GPT-2 the shared jitted sub-programs of
@@ -97,11 +140,16 @@ def paged_decode_step(params, pools, tokens, positions, block_tables,
     positions = jnp.asarray(positions, jnp.int32)
     x = spec.embed(params, tokens, positions)     # [N, 1, C]
     live = jnp.ones(tokens.shape, bool)
+    in_window = _window_slots(
+        spec, pools, jnp.asarray(window_tables, jnp.int32),
+        jnp.asarray(window_firsts, jnp.int32), positions, live) \
+        if spec.window else None
     new_pools = []
     for i, pool in enumerate(pools):
-        x, pool, _ = spec.decode_layer(
-            params, i, x, pool, slot_blocks, slot_offsets, block_tables,
-            positions, positions + 1, live, False, state_slots)
+        x, pool, _ = _layer(
+            spec, params, i, x, pool, slot_blocks, slot_offsets,
+            block_tables, in_window, positions, positions + 1, live, False,
+            state_slots)
         new_pools.append(pool)
     return spec.head(params, x), tuple(new_pools)
 
@@ -126,7 +174,11 @@ def paged_decode_step(params, pools, tokens, positions, block_tables,
 #                between the last fed prompt token and the first sample)
 #   12..12+k-1   the pf_feed prompt tokens for this chunk (0-padded)
 #   12+k..       the block table row [MB]
-#   12+k+MB      the row's state slot: ONE more column, and only where the
+#   12+k+MB..    only where the spec has window layers: the row's window
+#                table [WB] (the blocks that hold its last `window`
+#                positions) and ONE column, the logical index of the
+#                table's first block
+#   last         the row's state slot: ONE more column, and only where the
 #                spec has state layers (MB = max_seq_len // block_size)
 PACK_COLS = 12
 
@@ -262,13 +314,25 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
     """
     spec = as_spec(geom)
     feed = packed[:, PACK_COLS:PACK_COLS + k].T      # [k, N] prompt feed
-    num_blocks, block_size = pool_geometry(pools)
-    if spec.state_layers:
+    if spec.window:
+        # two groups of pools: the table, the write slot and the id that
+        # drops a frozen row's write are each group's own
+        num_blocks, block_size = _pool_of(spec, pools, "rows").shape[:2]
         mb = spec.max_seq_len // block_size
-        tables = packed[:, PACK_COLS + k:PACK_COLS + k + mb]
-        state_slots = packed[:, PACK_COLS + k + mb]
+        at = PACK_COLS + k + mb
+        tables = packed[:, PACK_COLS + k:at]
+        end = packed.shape[1] - bool(spec.state_layers)
+        window_tables, window_firsts = packed[:, at:end - 1], \
+            packed[:, end - 1]
+        state_slots = packed[:, end] if spec.state_layers else None
     else:
-        tables, state_slots = packed[:, PACK_COLS + k:], None
+        num_blocks, block_size = pool_geometry(pools)
+        if spec.state_layers:
+            mb = spec.max_seq_len // block_size
+            tables = packed[:, PACK_COLS + k:PACK_COLS + k + mb]
+            state_slots = packed[:, PACK_COLS + k + mb]
+        else:
+            tables, state_slots = packed[:, PACK_COLS + k:], None
     n = packed.shape[0]
     active = packed[:, 2] > 0
     max_out = packed[:, 4]
@@ -300,11 +364,14 @@ def fused_decode_chunk(params, pools, packed, geom, k, kernel="ragged"):
         slot_offsets = pos % block_size
         x = spec.embed(params, tok_in, pos)
         att_lens = jnp.where(run, pos + 1, 0).astype(jnp.int32)
+        # slot j of a chunk is at position + j in BOTH tables
+        in_window = _window_slots(spec, pools, window_tables, window_firsts,
+                                  pos, run) if spec.window else None
         new_pools = []
         for i, pool in enumerate(pools):
-            x, pool, layer_counts = spec.decode_layer(
-                params, i, x, pool, slot_blocks, slot_offsets, tables,
-                pos, att_lens, run, ragged, state_slots)
+            x, pool, layer_counts = _layer(
+                spec, params, i, x, pool, slot_blocks, slot_offsets, tables,
+                in_window, pos, att_lens, run, ragged, state_slots)
             new_pools.append(pool)
             if spec.counters:
                 counts = merge_counts(counts, layer_counts)

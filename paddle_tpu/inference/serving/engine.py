@@ -78,7 +78,8 @@ from ...core import anomaly
 from ...models import generation as gen
 from ...profiler import RecordEvent
 from .attention import PACK_COLS, as_spec, fused_decode_chunk, pack_f32
-from .paged_cache import CacheExhausted, PagedKVCache
+from .paged_cache import (CacheExhausted, PagedKVCache,
+                          window_blocks_per_seq)
 from .scheduler import (EngineOverloaded, Request, RequestState,
                         SamplingParams, ScheduledBatch, Scheduler,
                         SchedulerConfig, record_promotion_events)
@@ -200,6 +201,10 @@ _STAT_EVENTS = ("steps", "prefill_tokens", "generated_tokens",
                 # the `context_tokens` and `live_row_trips` stats of
                 # serving.decode, summed
                 "context_tokens", "live_row_trips",
+                # a spec with window layers: the `window_context_tokens`
+                # stat of serving.decode summed, and the window blocks the
+                # drains gave back (`PagedKVCache.release_behind`)
+                "window_context_tokens", "window_blocks_freed",
                 # decode chunks dispatched with at least one row that
                 # samples (temperature > 0): the chunks whose scan took
                 # the sampler's sampled branch
@@ -334,6 +339,17 @@ class EngineStats:
             "state slots owned by a sequence after the last step (a spec "
             "with state layers: one a sequence that holds cache)",
             labels=("engine",)).labels(**lbl)
+        self._g_window_bytes_per_seq = obs.gauge(
+            "serving_window_bytes_per_seq",
+            "bytes of cache the spec's window layers cost a sequence at "
+            "most: `window` positions each, however long the sequence "
+            "grows (0 without any)",
+            labels=("engine",), unit="bytes").labels(**lbl)
+        self._g_window_blocks_in_use = obs.gauge(
+            "serving_window_blocks_in_use",
+            "blocks of the window layers' group owned by a sequence after "
+            "the last step (a spec with window layers)",
+            labels=("engine",), unit="blocks").labels(**lbl)
         self._g_running = g_run.labels(**lbl)
         self._g_waiting = g_wait.labels(**lbl)
         self._g_blocks_used = g_blk.labels(state="used", **lbl)
@@ -421,12 +437,14 @@ class EngineStats:
 
     def set_step_gauges(self, running: int, waiting: int,
                         blocks_used: int, blocks_free: int,
-                        state_slots_used: int = 0) -> None:
+                        state_slots_used: int = 0,
+                        window_blocks_used: int = 0) -> None:
         self._g_running.set(running)
         self._g_waiting.set(waiting)
         self._g_blocks_used.set(blocks_used)
         self._g_blocks_free.set(blocks_free)
         self._g_state_slots_in_use.set(state_slots_used)
+        self._g_window_blocks_in_use.set(window_blocks_used)
 
     @property
     def cache_bytes_per_token(self) -> int:
@@ -444,11 +462,20 @@ class EngineStats:
     def state_slots_in_use(self) -> int:
         return int(self._g_state_slots_in_use.value)
 
+    @property
+    def window_bytes_per_seq(self) -> int:
+        return int(self._g_window_bytes_per_seq.value)
+
+    @property
+    def window_blocks_in_use(self) -> int:
+        return int(self._g_window_blocks_in_use.value)
+
     def set_cache_bytes_per_token(self, n: int, physical: int,
-                                  state: int = 0) -> None:
+                                  state: int = 0, window: int = 0) -> None:
         self._g_cache_bytes_per_token.set(n)
         self._g_cache_physical_bytes_per_token.set(physical)
         self._g_state_bytes_per_seq.set(state)
+        self._g_window_bytes_per_seq.set(window)
 
     def set_prefill_spend(self, tokens: int) -> None:
         self._g_prefill_spend.set(tokens)
@@ -617,6 +644,20 @@ def _context_tokens(reqs, k: int) -> int:
     return total
 
 
+def _window_context_tokens(reqs, k: int, window: int) -> int:
+    """KV positions a k-trip chunk attends to in ONE window layer, summed
+    over rows and trips: min(p + j + 1, window) at trip j, where
+    `_context_tokens` counts p + j + 1."""
+    total = 0
+    for req in reqs:
+        p, trips = req.slot[2], _live_trips(req, k)
+        # trips whose context p + j + 1 is still short of the window
+        short = min(trips, max(0, window - p - 1))
+        total += short * p + short * (short + 1) // 2 \
+            + (trips - short) * window
+    return total
+
+
 def _bucket(n: int, cap: int) -> int:
     b = 1
     while b < n:
@@ -679,7 +720,10 @@ class LLMEngine:
             kv_cache_dtype=config.kv_cache_dtype,
             layer_caches=spec.layer_caches, state_shapes=spec.state_shapes,
             # a sequence that holds cache is a running one
-            num_state_slots=config.max_num_seqs)
+            num_state_slots=config.max_num_seqs, window=spec.window,
+            # and holds at most a window and a chunk's look-ahead of them
+            num_window_blocks=config.max_num_seqs * window_blocks_per_seq(
+                spec.window, config.block_size, config.decode_chunk_size))
         cost_model = config.prefill_cost_model
         if cost_model == "auto":
             # committed-plan admission pricing; a repo without a plan
@@ -704,7 +748,7 @@ class LLMEngine:
         self.stats = EngineStats(config.obs_label)
         self.stats.set_cache_bytes_per_token(
             spec.cache_bytes_per_token, self.cache.physical_bytes_per_token,
-            self.cache.state_bytes_per_seq)
+            self.cache.state_bytes_per_seq, self.cache.window_bytes_per_seq)
         # (model, revision) event tag (serving/deploy.py): emission and
         # terminal events carry the serving revision so the causality
         # checker can prove no token was emitted by a revision other
@@ -1453,6 +1497,12 @@ class LLMEngine:
                 row_trips = sum(_live_trips(r, k) for r in decode)
                 self.stats.context_tokens += context
                 self.stats.live_row_trips += row_trips
+                in_window = {}
+                if self.spec.window:
+                    in_window["window_context_tokens"] = \
+                        _window_context_tokens(decode, k, self.spec.window)
+                    self.stats.window_context_tokens += \
+                        in_window["window_context_tokens"]
                 sampled_rows = sum(1 for r in decode
                                    if r.params.temperature > 0)
                 if sampled_rows:
@@ -1461,7 +1511,7 @@ class LLMEngine:
                         "num_seqs": len(decode), "chunk": k,
                         "context_tokens": context,
                         "live_row_trips": row_trips,
-                        "sampled_rows": sampled_rows}) as ev:
+                        "sampled_rows": sampled_rows, **in_window}) as ev:
                     # ptlint: disable=PT-C004  fault injector: stalls ON
                     # PURPOSE under the lock to exercise the watchdog
                     self.faults.stall(step_no)
@@ -1477,6 +1527,10 @@ class LLMEngine:
                     self.stats.observe_decode_chunk(dt)
                     if toks is not None:
                         self._drain_chunk(step_no, decode, toks, bad, outs)
+                        if self.spec.window:
+                            ev.set_stats(
+                                window_blocks_freed=self._release_windows(
+                                    decode))
             step_ev.args = {"step": step_no, "outputs": len(outs),
                             "errors": self.stats.errors,
                             "expired": self.stats.expired,
@@ -1499,7 +1553,8 @@ class LLMEngine:
             waiting=self.scheduler.num_waiting(),
             blocks_used=self.cache.num_used(),
             blocks_free=self.cache.num_free(),
-            state_slots_used=self.cache.num_state_slots_used())
+            state_slots_used=self.cache.num_state_slots_used(),
+            window_blocks_used=self.cache.num_window_used())
         if self.cache.prefix_index is not None:
             self.stats.record_prefix(self.cache.prefix_stats())
             for dt in self.cache.drain_promote_seconds():
@@ -1559,6 +1614,18 @@ class LLMEngine:
                             total=len(req.output_ids),
                             finished=req.finished,
                             **(self._rev_tag or {}))
+
+    @holds_lock("_lock")
+    def _release_windows(self, decode: List[Request]) -> int:
+        """After a chunk's drain: every row that still holds cache gives
+        back the window blocks its window has moved past (host arithmetic
+        only; a row that finished, or was requeued by a recovery, has
+        already returned all it held). Returns the blocks freed."""
+        with RecordEvent("serving.decode.release_window", cat="decode"):
+            freed = sum(self.cache.release_behind(req.request_id)
+                        for req in decode)
+        self.stats.window_blocks_freed += freed
+        return freed
 
     @holds_lock("_lock")
     def _prefill(self, req: Request, tokens: np.ndarray):
@@ -1621,10 +1688,15 @@ class LLMEngine:
         n = self.config.max_num_seqs if ragged \
             else _bucket(len(reqs), self.config.max_num_seqs)
         mb = self.max_blocks_per_seq
-        # a spec with state layers: one more column, the row's state slot
+        # a spec with state layers: one more column, the row's state slot;
+        # one with window layers: the row's window table and the logical
+        # index of its first block, between the block table and the slot
         stateful = bool(self.spec.state_layers)
+        wb = window_blocks_per_seq(self.spec.window, self.config.block_size, k)
+        at_window = PACK_COLS + k + mb
         with RecordEvent("serving.decode.pack", cat="decode"):
-            packed = np.zeros((n, PACK_COLS + k + mb + stateful), np.int32)
+            packed = np.zeros(
+                (n, at_window + (wb + 1 if wb else 0) + stateful), np.int32)
             fed = []                         # (req, tokens consumed)
             for i, req in enumerate(reqs):
                 p = req.params
@@ -1650,6 +1722,10 @@ class LLMEngine:
                     fed.append((req, f))
                 table = self.cache.block_table(req.request_id)
                 packed[i, PACK_COLS + k:PACK_COLS + k + len(table)] = table
+                if wb:
+                    wtable, first = self.cache.window_table(req.request_id)
+                    packed[i, at_window:at_window + len(wtable)] = wtable
+                    packed[i, at_window + wb] = first
                 if stateful:
                     packed[i, -1] = self.cache.state_slot(req.request_id)
         with RecordEvent("serving.decode.dispatch", cat="decode"):

@@ -84,7 +84,8 @@ from .host_tier import HostTierStore
 from .prefix_cache import PrefixCacheIndex, PrefixNode
 
 __all__ = ["PagedKVCache", "CacheExhausted", "SeqState", "write_rows",
-           "gather_rows", "pool_geometry", "physical_shape"]
+           "gather_rows", "pool_geometry", "physical_shape",
+           "window_blocks_per_seq"]
 
 
 #: len(cache_shape) -> the layout (models/spec.py): (H, D) is a (k, v) pair
@@ -116,17 +117,18 @@ class SeqState:
         return cls(*arrays)
 
 
-def _map_rows(fn, pools):
-    """`fn` over every block pool of `pools`; a state layer's leaf as it
-    is."""
-    return tuple(p if isinstance(p, SeqState)
-                 else jax.tree_util.tree_map(fn, p) for p in pools)
+def _map_kind(fn, pools, kinds, kind):
+    """`fn` over every block pool of the layers of `kind` ("rows" or
+    "window": two groups, each with block ids of its own); the others as
+    they are."""
+    return tuple(jax.tree_util.tree_map(fn, p) if k == kind else p
+                 for p, k in zip(pools, kinds))
 
 
-def _split(layers):
-    """(the row layers' leaves, the state layers') of an L-tuple."""
-    return (tuple(p for p in layers if not isinstance(p, SeqState)),
-            tuple(p for p in layers if isinstance(p, SeqState)))
+def _of_kind(layers, kinds, kind):
+    """The leaves of the layers of `kind` ("rows", "window", "state") of an
+    L-tuple."""
+    return tuple(p for p, k in zip(layers, kinds) if k == kind)
 
 
 def pool_geometry(pools):
@@ -134,6 +136,18 @@ def pool_geometry(pools):
     layer that caches rows)."""
     rows = next(p for p in pools if not isinstance(p, SeqState))
     return jax.tree_util.tree_leaves(rows)[0].shape[:2]
+
+
+def window_blocks_per_seq(window: int, block_size: int,
+                          lookahead: int) -> int:
+    """The most window blocks a sequence holds at once, when
+    `PagedKVCache.release_behind` follows every reservation of at most
+    `lookahead` positions: the blocks of window - 1 + lookahead positions
+    that start on a block's last slot (window // block_size + 2 where the
+    block divides the window and lookahead <= block_size + 1). The width of
+    the window table in the decode chunk's upload; 0 without a window."""
+    return 1 + (window + lookahead + block_size - 3) // block_size \
+        if window else 0
 
 
 _LANES, _SUBLANES = 128, 8
@@ -276,6 +290,27 @@ def write_state_scatter(states, final, slot, batch_index):
         states, final)
 
 
+# ptlint: disable=PT-T009  the pools are rebound from the return value by
+# the one caller (write_prefill); the committed plan's serving entries are
+# traced at a geometry without window layers
+@functools.partial(jax.jit, donate_argnums=(0,))
+def write_window_scatter(pools, dense, slot_blocks, slot_offsets,
+                         batch_index):
+    """Row `batch_index` of a prefill's window rows (a tuple of (k, v)
+    [B, H, window, D], the prompt's last `window` positions) into the
+    window layers' pools, ROW BY ROW: row r goes to (slot_blocks[r],
+    slot_offsets[r]), because the window does not start on a block's
+    border; rows the prompt does not have carry the out-of-range block id
+    and are dropped. One program whatever the prompt's length."""
+    def scatter(pool, rows):
+        row = jax.lax.dynamic_index_in_dim(rows, batch_index, 0,
+                                           keepdims=False)
+        return write_rows(pool, row.transpose(1, 0, 2), slot_blocks,
+                          slot_offsets)
+
+    return jax.tree_util.tree_map(scatter, pools, dense)
+
+
 class CacheExhausted(RuntimeError):
     """Block pool exhaustion report: who needed how much vs. what's free.
 
@@ -335,6 +370,28 @@ class PagedKVCache:
     here for int8 pools, the prefix cache and the host tier (a block of
     rows is not the whole of a prefix: the state after it would have to be
     kept too), at the call for block migration.
+
+    Window blocks (`layout` "hybrid" too): a "window" layer
+    (`ModelSpec.layer_caches`, sliding-window attention over the last
+    `window` positions) keeps its (k, v) in a SECOND group of pools,
+    [num_window_blocks, block_size, ...stored_shape], with a free list of
+    its own and a second table a sequence: the blocks that hold the
+    window, and the logical index of the first (`window_table`). Position
+    p lives in entry p // block_size - first at offset p % block_size.
+    `allocate(seq, n)` takes the blocks of the last `window` positions of
+    n, `append_slot` / `reserve_slots` grow both tables, and
+    `release_behind(seq)` returns every block the window has moved past:
+    one whose last position is more than `window - 1` behind the
+    sequence's next position, which no later query can attend to. So a
+    sequence holds at most `window_blocks_per_seq(..., lookahead)` of them
+    however long it grows, when the caller releases after every
+    reservation of `lookahead` positions (the engine: where it drains a
+    chunk). `free` returns both groups; either group's exhaustion raises
+    `CacheExhausted` with no side effect; `check_integrity` audits both. A
+    cache may hold window layers and state layers at once. The refusals of
+    the hybrid layout hold here for the same reason turned round: a block
+    of the full layers is not the whole of a prefix when the window layers
+    have given theirs back.
     """
 
     def __init__(self, num_layers: int, cache_shape: Tuple[int, ...],
@@ -344,7 +401,8 @@ class PagedKVCache:
                  promote_timeout_s: Optional[float] = None,
                  kv_cache_dtype: str = "float32",
                  layer_caches: Tuple[str, ...] = (),
-                 state_shapes: Tuple = (), num_state_slots: int = 0):
+                 state_shapes: Tuple = (), num_state_slots: int = 0,
+                 window: int = 0, num_window_blocks: int = 0):
         if num_blocks <= 0 or block_size <= 0:
             raise ValueError("num_blocks and block_size must be positive")
         cache_shape = tuple(cache_shape)
@@ -356,15 +414,21 @@ class PagedKVCache:
         self.layout = _LAYOUTS[len(cache_shape)]
         layer_caches = tuple(layer_caches) or ("rows",) * num_layers
         if len(layer_caches) != num_layers \
-                or set(layer_caches) - {"rows", "state"} \
+                or set(layer_caches) - {"rows", "state", "window"} \
                 or "rows" not in layer_caches:
             raise ValueError(
-                f"layer_caches must name 'rows' or 'state' for each of the "
-                f"{num_layers} layers, 'rows' at least once, got "
-                f"{layer_caches!r}")
+                f"layer_caches must name 'rows', 'state' or 'window' for "
+                f"each of the {num_layers} layers, 'rows' at least once, "
+                f"got {layer_caches!r}")
+        heads = self.layout == "heads"
+        if "window" in layer_caches:
+            if not heads or window <= 0 or num_window_blocks <= 0:
+                raise ValueError(
+                    "window layers need (num_heads, head_dim) rows layers, "
+                    "window > 0 and num_window_blocks > 0")
+            self.layout = "hybrid"
         if "state" in layer_caches:
-            if self.layout != "heads" or not state_shapes \
-                    or num_state_slots <= 0:
+            if not heads or not state_shapes or num_state_slots <= 0:
                 raise ValueError(
                     "state layers need (num_heads, head_dim) rows layers, "
                     "state_shapes and num_state_slots > 0")
@@ -420,11 +484,25 @@ class PagedKVCache:
                       if kind == "rows" else SeqState(*(
                           jnp.zeros((num_state_slots,) + tuple(sh), dt)
                           for sh, dt in state_shapes))
+                      if kind == "state" else tuple(
+                          jnp.zeros((num_window_blocks,) + shape[1:], dtype)
+                          for _ in range(2))
                       for kind in layer_caches)
         # ----------------------------------------------- host accounting
         self.layer_caches = layer_caches
-        self.num_state_slots = num_state_slots if self.layout == "hybrid" \
-            else 0
+        self.num_state_slots = num_state_slots \
+            if "state" in layer_caches else 0
+        # window blocks: a free list, seq -> the blocks of its window and
+        # seq -> the logical index of the first of them, beside the blocks'
+        self.window = window if "window" in layer_caches else 0
+        self.num_window_blocks = num_window_blocks if self.window else 0
+        self._wfree: List[int] = list(
+            range(self.num_window_blocks - 1, -1, -1))
+        self._wtables: Dict[object, List[int]] = {}
+        self._wfirst: Dict[object, int] = {}
+        self.window_blocks_allocated = 0
+        self.window_blocks_freed = 0
+        self.window_high_water = 0
         # state slots: a free list and seq -> slot, beside the blocks'
         self._state_free: List[int] = list(
             range(self.num_state_slots - 1, -1, -1))
@@ -523,14 +601,24 @@ class PagedKVCache:
         `physical_shape` pads (int8 mode: the codes, scales aside)."""
         stored = self._pools if self._qpools is None else self._qpools
         return sum(int(np.prod(p.shape[2:])) * p.dtype.itemsize
-                   for layer in stored if not isinstance(layer, SeqState)
+                   for layer in _of_kind(stored, self.layer_caches, "rows")
                    for p in jax.tree_util.tree_leaves(layer))
+
+    @property
+    def window_bytes_per_seq(self) -> int:
+        """Bytes `window` positions hold in the window layers' pools as
+        stored (0 without any): `ModelSpec.window_bytes_per_seq`, from the
+        arrays."""
+        return self.window * sum(
+            int(np.prod(p.shape[2:])) * p.dtype.itemsize
+            for layer in _of_kind(self._pools, self.layer_caches, "window")
+            for p in layer) if self.window else 0
 
     @property
     def state_bytes_per_seq(self) -> int:
         """Bytes one sequence's entries hold over all state layers (0
         without any): `ModelSpec.state_bytes_per_seq`, from the arrays."""
-        if self.layout != "hybrid":
+        if not self.num_state_slots:
             return 0
         return sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
                    for layer in self._pools
@@ -617,6 +705,77 @@ class PagedKVCache:
 
     def blocks_needed(self, num_tokens: int) -> int:
         return -(-num_tokens // self.block_size)
+
+    # ------------------------------------------------------ window blocks
+    def num_window_free(self) -> int:
+        return len(self._wfree)
+
+    def num_window_used(self) -> int:
+        return self.num_window_blocks - len(self._wfree)
+
+    def _window_first(self, next_pos: int) -> int:
+        """The logical index of the first block a sequence has to keep
+        whose next position is `next_pos`: that query attends to
+        next_pos - window + 1 .. next_pos, and every later one starts
+        later."""
+        return max(0, next_pos - self.window + 1) // self.block_size
+
+    def window_blocks_needed(self, num_tokens: int, ahead: int = 0) -> int:
+        """Window blocks a sequence of num_tokens cached tokens holds once
+        `ahead` more positions are reserved for it (0 without window
+        layers)."""
+        if not self.window:
+            return 0
+        return self.blocks_needed(num_tokens + ahead) \
+            - self._window_first(num_tokens)
+
+    def window_table(self, seq_id) -> Tuple[List[int], int]:
+        """(the sequence's window blocks, the logical index of the first):
+        position p is in entry p // block_size - first."""
+        return list(self._wtables[seq_id]), self._wfirst[seq_id]
+
+    def _window_short(self, seq_id, upto: int, held_upto: int = None) -> int:
+        """Window blocks still to take so that the sequence's window table
+        (which reaches logical block `held_upto`, exclusive; default: as it
+        stands) reaches position upto - 1; raises CacheExhausted, nothing
+        taken, where the group has not that many free."""
+        if not self.window:
+            return 0
+        if held_upto is None:
+            held_upto = self._wfirst[seq_id] + len(self._wtables[seq_id])
+        need = self.blocks_needed(upto) - held_upto
+        if need > len(self._wfree):
+            self.alloc_failures += 1
+            raise CacheExhausted(seq_id, need, len(self._wfree),
+                                 self.num_window_blocks, what="window block")
+        return max(0, need)
+
+    def _take_window(self, n: int) -> List[int]:
+        got = [self._wfree.pop() for _ in range(n)]
+        self.window_blocks_allocated += n
+        self.window_high_water = max(self.window_high_water,
+                                     self.num_window_used())
+        return got
+
+    def release_behind(self, seq_id) -> int:
+        """Return to the window group every block of the sequence whose
+        last position is more than window - 1 behind its next position (no
+        later query attends to it). Host arithmetic only; the rows stay in
+        the pool until the block's next owner overwrites them, unread: a
+        window layer's mask starts at the query's own window. Returns the
+        number of blocks freed."""
+        if seq_id not in self._wtables:
+            return 0
+        table = self._wtables[seq_id]
+        drop = min(len(table), self._window_first(self._lens[seq_id])
+                   - self._wfirst[seq_id])
+        if drop <= 0:
+            return 0
+        self._wfree.extend(reversed(table[:drop]))
+        del table[:drop]
+        self._wfirst[seq_id] += drop
+        self.window_blocks_freed += drop
+        return drop
 
     def has_seq(self, seq_id) -> bool:
         return seq_id in self._tables
@@ -983,9 +1142,15 @@ class PagedKVCache:
             self.alloc_failures += 1
             raise CacheExhausted(seq_id, 1, 0, self.num_state_slots,
                                  what="state slot")
+        wneed = self._window_short(seq_id, num_tokens,
+                                   self._window_first(num_tokens))
         ids = self._take_blocks(seq_id, self.blocks_needed(num_tokens))
         if self.num_state_slots:
             self._state_slots[seq_id] = self._state_free.pop()
+        if self.window:
+            # the blocks of the last `window` positions of num_tokens
+            self._wtables[seq_id] = self._take_window(wneed)
+            self._wfirst[seq_id] = self._window_first(num_tokens)
         self._tables[seq_id] = ids
         self._lens[seq_id] = num_tokens
         return ids
@@ -998,9 +1163,12 @@ class PagedKVCache:
         """
         pos = self._lens[seq_id]
         table = self._tables[seq_id]
+        wneed = self._window_short(seq_id, pos + 1)
         if pos % self.block_size == 0 and len(table) * self.block_size \
                 <= pos:
             table.extend(self._take_blocks(seq_id, 1))
+        if wneed:
+            self._wtables[seq_id].extend(self._take_window(wneed))
         self._lens[seq_id] = pos + 1
         block = table[pos // self.block_size]
         return block, pos % self.block_size, pos
@@ -1022,8 +1190,11 @@ class PagedKVCache:
         pos = self._lens[seq_id]
         table = self._tables[seq_id]
         need = self.blocks_needed(pos + n) - len(table)
+        wneed = self._window_short(seq_id, pos + n)
         if need > 0:
             table.extend(self._take_blocks(seq_id, need))
+        if wneed:
+            self._wtables[seq_id].extend(self._take_window(wneed))
         self._lens[seq_id] = pos + n
         return table[pos // self.block_size], pos % self.block_size, pos
 
@@ -1362,6 +1533,16 @@ class PagedKVCache:
             # the entry stays as it is: the slot's next owner overwrites
             # it whole (write_prefill) or starts from zeros at position 0
             self._state_free.append(self._state_slots.pop(seq_id))
+        if seq_id in self._wtables:
+            wids = self._wtables.pop(seq_id)
+            del self._wfirst[seq_id]
+            self._wfree.extend(reversed(wids))
+            self.window_blocks_freed += len(wids)
+            if scrub and wids:
+                at = jnp.asarray(wids, jnp.int32)
+                self.pools = _map_kind(lambda pool: pool.at[at].set(0),
+                                       self.pools, self.layer_caches,
+                                       "window")
         to_scrub: List[int] = []
         for b in reversed(ids):
             self._refcount[b] -= 1
@@ -1390,7 +1571,8 @@ class PagedKVCache:
         if not block_ids:
             return
         idx = jnp.asarray(list(block_ids), jnp.int32)
-        self.pools = _map_rows(lambda pool: pool.at[idx].set(0), self.pools)
+        self.pools = _map_kind(lambda pool: pool.at[idx].set(0), self.pools,
+                               self.layer_caches, "rows")
 
     def check_integrity(self) -> dict:
         """Invariant audit for the chaos harness: the free list and the
@@ -1435,6 +1617,20 @@ class PagedKVCache:
         report["state_slots_without_table"] = len(
             set(self._state_slots) ^ set(self._tables)) \
             if self.num_state_slots else 0
+        # window blocks: the free list and the tables partition the group,
+        # no block has two owners, exactly the sequences that hold a table
+        # hold a window table, and the counters account for the blocks out
+        wheld = [b for ids in self._wtables.values() for b in ids]
+        report["window_blocks_leaked"] = self.num_window_blocks \
+            - len(set(wheld) | set(self._wfree))
+        report["window_blocks_double_owned"] = \
+            len(wheld) - len(set(wheld)) \
+            + len(set(wheld) & set(self._wfree))
+        report["window_blocks_without_table"] = len(
+            set(self._wtables) ^ set(self._tables)) if self.window else 0
+        report["window_counter_drift"] = \
+            (self.window_blocks_allocated - self.window_blocks_freed) \
+            - (self.num_window_blocks - len(self._wfree))
         # cross-tier keys: every trie host node must point at a live
         # store entry (orphan = promoted-from-under-us bug) and every
         # store entry must be reachable from the trie (leaked = host-
@@ -1494,11 +1690,18 @@ class PagedKVCache:
         layer's rows, the sequence's FINAL entry (a `SeqState` of arrays
         [B, ...shape]): a second dispatch (`write_state_scatter`, span
         `serving.prefill.write_state`) writes it whole into the
-        sequence's slot of every state layer."""
+        sequence's slot of every state layer. In the place of a window
+        layer's rows it holds the rows of the prompt's last `window`
+        positions alone ((k, v) [B, H, window, D], row r the position
+        max(0, num_tokens - window) + r): a third dispatch
+        (`write_window_scatter`, span `serving.prefill.write_window`) puts
+        them row by row into the sequence's window blocks."""
         ids = self._tables[seq_id]
-        dense_cache, final = _split(dense_cache)
-        pools = self.pools
-        rows, states = _split(pools)
+        kinds, pools = self.layer_caches, self.pools
+        dense_cache, final, last = (_of_kind(dense_cache, kinds, kind)
+                                    for kind in ("rows", "state", "window"))
+        rows, states, windows = (_of_kind(pools, kinds, kind)
+                                 for kind in ("rows", "state", "window"))
         # [B, H, S, D] a (k, v) leaf, [B, S, W] a latent one
         leaf = jax.tree_util.tree_leaves(dense_cache)[0]
         batch, seq = leaf.shape[0], leaf.shape[-2]
@@ -1523,9 +1726,26 @@ class PagedKVCache:
                           args={"slot": slot}):
                 states = write_state_scatter(
                     states, final, np.int32(slot), np.int32(batch_index))
-        rows, states = iter(rows), iter(states)
-        self.pools = tuple(next(states) if isinstance(p, SeqState)
-                           else next(rows) for p in pools)
+        if windows:
+            # the prompt's last `window` positions, row by row: dense row r
+            # is position lo + r, which lives in the window table's entry
+            # (lo + r) // block_size - first
+            table, first = self._wtables[seq_id], self._wfirst[seq_id]
+            at = max(0, num_tokens - self.window) + np.arange(self.window)
+            entry = at // self.block_size - first
+            held = (at < num_tokens) & (entry >= 0)
+            blocks = np.full((self.window,), self.num_window_blocks,
+                             np.int32)
+            blocks[held] = np.asarray(table, np.int32)[entry[held]]
+            with obs.span("serving.prefill.write_window", cat="prefill",
+                          args={"blocks": len(table)}):
+                windows = write_window_scatter(
+                    windows, last, blocks,
+                    (at % self.block_size).astype(np.int32),
+                    np.int32(batch_index))
+        leaves = {"rows": iter(rows), "state": iter(states),
+                  "window": iter(windows)}
+        self.pools = tuple(next(leaves[kind]) for kind in kinds)
 
     def prefix_stats(self) -> dict:
         """Prefix-cache telemetry snapshot (engine gauges + load suite
@@ -1555,6 +1775,16 @@ class PagedKVCache:
         return out
 
     def stats(self) -> dict:
+        """Both groups' accounting; the window group's keys only where the
+        cache has window layers."""
+        window = {
+            "window_blocks": self.num_window_blocks,
+            "window_free": self.num_window_free(),
+            "window_used": self.num_window_used(),
+            "window_blocks_allocated": self.window_blocks_allocated,
+            "window_blocks_freed": self.window_blocks_freed,
+            "window_high_water": self.window_high_water,
+        } if self.window else {}
         return {
             "num_blocks": self.num_blocks,
             "block_size": self.block_size,
@@ -1567,4 +1797,5 @@ class PagedKVCache:
             "blocks_attached": self.blocks_attached,
             "alloc_failures": self.alloc_failures,
             "high_water": self.high_water,
+            **window,
         }

@@ -276,6 +276,7 @@ class Scheduler:
         "running": "_lock",
         "num_preemptions": "_lock",
         "watermark_holds": "_lock",
+        "window_holds": "_lock",
         "_vtime": "_lock",
         "_vfinish": "_lock",
         "_wfq_weights": "_lock",
@@ -300,6 +301,7 @@ class Scheduler:
         self.running: List[Request] = []
         self.num_preemptions = 0
         self.watermark_holds = 0             # admissions paused by watermark
+        self.window_holds = 0                # ... by the window group's free
         # multi-tenant WFQ state (inert when config.tenants is None):
         # start-time fair queuing over per-tenant virtual finish times.
         # _vtime is the system virtual clock (last admission's virtual
@@ -877,6 +879,14 @@ class Scheduler:
                 # strand — admit (the head alone may legitimately
                 # exceed the watermark).
                 self.watermark_holds += 1
+                break
+            # a cache with window layers: the request's need in the second
+            # group (the blocks of its last window, or of its first chunk)
+            # against that group's free count; 0 > 0 without such layers
+            if (self.cache.window_blocks_needed(0, eff) if chunked
+                    else self.cache.window_blocks_needed(len(tokens))) \
+                    > self.cache.num_window_free():
+                self.window_holds += 1
                 break
             if chunked:
                 remaining = max(0, req.params.max_tokens

@@ -251,7 +251,8 @@ def serving_spec(geom) -> ModelSpec:
     num_layers, num_heads, head_dim, max_seq = geom
 
     def decode_layer(params, i, x, pool, slot_blocks, slot_offsets, tables,
-                     positions, att_lens, live, ragged, state_slots=None):
+                     positions, att_lens, live, ragged, state_slots=None,
+                     att_starts=None, table_starts=None):
         kp, vp = pool
         qkv = _decode_qkv(params, i, x, geom)     # [3, N, H, 1, D]
         kp = write_rows(kp, qkv[1][:, :, 0], slot_blocks, slot_offsets)

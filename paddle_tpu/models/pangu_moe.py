@@ -341,7 +341,7 @@ def _token_embed(params, tokens, positions):
 
 def _decode_layer(cfg, params, i, x, pool, slot_blocks, slot_offsets,
                   tables, positions, att_lens, live, ragged,
-                  state_slots=None):
+                  state_slots=None, att_starts=None, table_starts=None):
     """One layer for N rows of one token each against the latent pool:
     write the token's row at its slot, gather each row's blocks through
     its table (`paged_cache.write_rows` / `gather_rows`), attend in the

@@ -455,27 +455,34 @@ def _moe_tokens(p, pre, flat, cfg, live):
     return (routed + shared).astype(flat.dtype), counts
 
 
-def moe_block(p, i, h, cfg, live=None):
-    """The expert block of layer i on h [..., hidden], and the counts. A
-    long prompt goes through `MOE_TOKEN_BLOCK` tokens at a time: the grouped
-    matmul compiles for minutes at tens of thousands of rows, and so every
-    prompt length shares one block's program (the tail's padding is switched
-    off in the routing and counts nothing)."""
-    pre = f"layers.{i}.moe."
+def map_token_blocks(expert_tokens, h, live=None):
+    """`expert_tokens(flat [T, hidden], live [T] or None) -> (out [T,
+    hidden], counts)`, an expert block, over h [..., hidden]. A long prompt
+    goes through `MOE_TOKEN_BLOCK` tokens at a time: the grouped matmul
+    compiles for minutes at tens of thousands of rows, and so every prompt
+    length shares one block's program (the tail's padding is switched off
+    in the routing and counts nothing)."""
     flat = h.reshape(-1, h.shape[-1])
     T = flat.shape[0]
     if T <= MOE_TOKEN_BLOCK:
-        out, counts = _moe_tokens(p, pre, flat, cfg, live)
+        out, counts = expert_tokens(flat, live)
         return out.reshape(h.shape), counts
     n = -(-T // MOE_TOKEN_BLOCK)
     pad = n * MOE_TOKEN_BLOCK - T
     on = jnp.ones((T,), bool) if live is None else live
     out, counts = jax.lax.map(
-        lambda block: _moe_tokens(p, pre, block[0], cfg, block[1]),
+        lambda block: expert_tokens(block[0], block[1]),
         (jnp.pad(flat, ((0, pad), (0, 0))).reshape(n, MOE_TOKEN_BLOCK, -1),
          jnp.pad(on, (0, pad)).reshape(n, MOE_TOKEN_BLOCK)))
     counts = jnp.concatenate([counts[:, :-1].sum(0), counts[:, -1:].max(0)])
     return out.reshape(n * MOE_TOKEN_BLOCK, -1)[:T].reshape(h.shape), counts
+
+
+def moe_block(p, i, h, cfg, live=None):
+    """The expert block of layer i on h [..., hidden], and the counts."""
+    pre = f"layers.{i}.moe."
+    return map_token_blocks(
+        lambda flat, on: _moe_tokens(p, pre, flat, cfg, on), h, live)
 
 
 def _dense_layers(p, ids, cfg):
@@ -565,11 +572,11 @@ def _own_group(x, G):
                    axis=3)
 
 
-def _rows_attention(q, ctx_k, ctx_v, att_lens, G):
+def _rows_attention(q, ctx_k, ctx_v, att_lens, G, att_starts=None):
     """One query a row against cached positions whose G key-value heads lie
     side by side in ONE row, as the pools keep them: q [N, H, D], ctx_k,
     ctx_v [N, S, G * D], row n attends to its first att_lens[n] positions
-    -> [N, H, D]. Each query head is laid into its own group's lanes of a
+    (from att_starts[n] on, where given: a window) -> [N, H, D]. Each query head is laid into its own group's lanes of a
     G * D-wide row (zeros in the others) and multiplied against the whole
     cached row, and takes its group's lanes of the whole value row back:
     G times the multiply-adds, which a decode step does not notice, and no
@@ -581,7 +588,10 @@ def _rows_attention(q, ctx_k, ctx_v, att_lens, G):
     scores = jnp.einsum("nhw,nsw->nhs", wide, ctx_k,
                         preferred_element_type=F32) \
         * F32(1.0 / math.sqrt(D))
-    attend = jnp.arange(ctx_k.shape[1])[None, :] < att_lens[:, None]
+    at = jnp.arange(ctx_k.shape[1])[None, :]
+    attend = at < att_lens[:, None]
+    if att_starts is not None:
+        attend = attend & (at >= att_starts[:, None])
     scores = jnp.where(attend[:, None], scores, F32(-1e30))
     probs = jax.nn.softmax(scores, axis=-1).astype(ctx_v.dtype)
     out = jnp.einsum("nhs,nsw->nhw", probs, ctx_v,
@@ -608,9 +618,9 @@ def _attn_decode(cfg, p, pre, h, pool, slot_blocks, slot_offsets, tables,
 
 def _decode_layer(cfg, params, i, x, pool, slot_blocks, slot_offsets,
                   tables, positions, att_lens, live, ragged,
-                  state_slots=None):
+                  state_slots=None, att_starts=None, table_starts=None):
     """Layer i for N rows of one token each (`ModelSpec.decode_layer`;
-    `ragged` has no kernel to choose here yet)."""
+    `ragged` has no kernel to choose here yet; no layer has a window)."""
     pre, eps = f"layers.{i}.", cfg.rms_norm_eps
     h = rms_norm0(x[:, 0], params[pre + "norm1.weight"], eps)
     if cfg.is_full_attention(i):

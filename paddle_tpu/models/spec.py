@@ -8,9 +8,9 @@ what one position caches. The spec is the seam between the two: one
 frozen (hashable, so usable as a jit static argument) object per (family,
 configuration). Every family builds its own, `serving_spec(...)` beside
 the functions it wraps (`models.generation.serving_spec`, the first, and
-`models.pangu_moe.serving_spec`, `models.qwen3_next.serving_spec`); all
-are cached, so the same configuration always gives the same object and the
-same compiled programs. The serving layer imports this module and no family.
+`models.pangu_moe.serving_spec`, `models.qwen3_next.serving_spec`,
+`models.mellum.serving_spec`); all are cached, so the same configuration
+always gives the same object and the same compiled programs. The serving layer imports this module and no family.
 
 Cache layouts (`PagedKVCache` builds the pools from `cache_shape` and
 `cache_dtype`; a decode layer writes and reads them through
@@ -31,7 +31,17 @@ to `write_prefill_scatter`):
             slot with its blocks, `decode_layer` reads and writes the
             entries at `state_slots`, and a prefill hands back each
             sequence's final entry [B, ...shape] in the place of dense
-            rows.
+            rows. A "window" layer is a rows layer that attends only to
+            the last `window` positions (sliding-window attention): its
+            (k, v) pools are a GROUP of their own, [num_window_blocks,
+            block_size, ...], with a free list and a table a sequence of
+            their own; the table holds only the blocks of the window
+            (the cache manager returns a block when the window has moved
+            past it), so a sequence costs such a layer at most
+            `window_bytes_per_seq` however long it grows. A prefill hands
+            over, for such a layer, the rows of the prompt's last
+            `window` positions alone: [B, H, window, D], row r the
+            position max(0, T - window) + r.
 """
 from __future__ import annotations
 
@@ -61,12 +71,17 @@ class ModelSpec:
     embed: Callable
     #: (params, i, x, layer_pool, slot_blocks [N], slot_offsets [N],
     #:  tables [N, MB], positions [N], att_lens [N], live [N] bool,
-    #:  ragged: bool, state_slots [N] or None) -> (x, layer_pool, counts):
+    #:  ragged: bool, state_slots [N] or None[, att_starts [N],
+    #:  table_starts [N]]) -> (x, layer_pool, counts):
     #: writes the new token's cache row at (slot_block, slot_offset)
     #: (out-of-range blocks are dropped), attends row n to its first
     #: att_lens[n] positions through its block table, runs the rest of
-    #: layer i. `layer_pool` is the layout's per-layer leaf: a (k, v) pair,
-    #: one array, or a state layer's `SeqState`, whose entry of row n is at
+    #: layer i. A "window" layer, and no other, is called with the two
+    #: trailing arguments: `tables` [N, WB] is then ITS table (the blocks
+    #: that hold the window, slot_blocks ids of ITS pools), row w of a
+    #: sequence's gathered context is position table_starts[n] + w, and
+    #: row n attends to positions att_starts[n] .. att_lens[n] - 1.
+    #: `layer_pool` is the layout's per-layer leaf: a (k, v) pair, one array, or a state layer's `SeqState`, whose entry of row n is at
     #: `state_slots[n]` (None where the spec has no state layer; a row at
     #: position 0 starts from a zero entry whatever its slot held, a row
     #: not `live` leaves its entry as it is). `counts` is an int32 vector
@@ -82,12 +97,16 @@ class ModelSpec:
     counters: Tuple[str, ...] = ()
     #: what the functions above were built from (a config, a geometry)
     config: Hashable = None
-    #: per layer "rows" (a cache row a position) or "state" (one entry a
-    #: sequence); () = rows in every layer
+    #: per layer "rows" (a cache row a position), "state" (one entry a
+    #: sequence) or "window" (a cache row a position of the last `window`);
+    #: () = rows in every layer
     layer_caches: Tuple[str, ...] = ()
     #: the leaves of a state layer's entry, per sequence: ((shape, dtype
     #: name), ...)
     state_shapes: Tuple[Tuple[Tuple[int, ...], str], ...] = ()
+    #: positions a "window" layer attends to, the query's own included
+    #: (0 where no layer has a window)
+    window: int = 0
 
     @property
     def pools_per_layer(self) -> int:
@@ -98,13 +117,28 @@ class ModelSpec:
         return sum(c == "state" for c in self.layer_caches)
 
     @property
-    def cache_bytes_per_token(self) -> int:
-        """Bytes one more position costs: the layers that cache rows."""
+    def window_layers(self) -> int:
+        return sum(c == "window" for c in self.layer_caches)
+
+    def _row_bytes(self) -> int:
+        """Bytes of one position in one layer that caches rows."""
         import math
         import jax.numpy as jnp      # bfloat16 is jax's, not numpy's
-        return ((self.num_layers - self.state_layers) * self.pools_per_layer
-                * math.prod(self.cache_shape)
+        return (self.pools_per_layer * math.prod(self.cache_shape)
                 * jnp.dtype(self.cache_dtype).itemsize)
+
+    @property
+    def cache_bytes_per_token(self) -> int:
+        """Bytes one more position costs: the layers that cache a row for
+        EVERY position (a window layer's cost stops growing at `window`)."""
+        return (self.num_layers - self.state_layers - self.window_layers) \
+            * self._row_bytes()
+
+    @property
+    def window_bytes_per_seq(self) -> int:
+        """The most a sequence's window layers have to hold: `window`
+        positions each (whole blocks, as stored, hold a little more)."""
+        return self.window_layers * self.window * self._row_bytes()
 
     @property
     def state_bytes_per_seq(self) -> int:

@@ -182,3 +182,97 @@ def test_a_span_takes_stats_from_inside_its_scope(tmp_path):
              for ln in pl.lines for e in ln.events
              if e.name == "serving.decode"]
     assert found == [{"chunk": 8, "moe_pairs": 7, "moe_experts_hit": 3}]
+
+
+# sha1[:16] of the lowered text of the accepted cells' programs at the toy
+# geometries below, taken on the parent of PR 35 (f1f9e0e) with jax 0.9.0
+# under this harness's settings (conftest: matmul precision "highest"):
+# a spec with no window layer gets the upload, the scan and the programs it
+# got before window layers existed, byte for byte. A PR that changes one of
+# these programs ON PURPOSE takes the new value from its own tree and says
+# so; another jax prints other text, and the test then skips.
+PARENT_OF_PR_35 = {
+    "gpt2.chunk": "03d3b78e69ba813e", "gpt2.prefill": "094638537d5db785",
+    "gpt2.scatter": "337b6f8e98105a15", "latent.chunk": "6e7835780fe145cb",
+    "latent.prefill": "4d3236615dc44a3b", "hybrid.chunk": "ad0fb8cca40b02c6",
+    "hybrid.prefill": "52eba390fb3ddb0f",
+    "hybrid.prefill.blocked": "b43661103ae6a379",
+}
+
+
+def _sha(lowered):
+    return hashlib.sha1(lowered.as_text().encode()).hexdigest()[:16]
+
+
+def _lowered_by_family(family, gpt_params, monkeypatch):
+    from paddle_tpu.models import pangu_moe, qwen3_next
+    if family == "gpt2":
+        low = _lowered(gpt_params)
+        return {"gpt2.chunk": low["jit_fused_decode_chunk"],
+                "gpt2.prefill": low["jit_prefill"],
+                "gpt2.scatter": low["jit_write_prefill_scatter"]}
+    if family == "latent":
+        cfg = pangu_moe.PanguMoEConfig(
+            vocab_size=64, hidden_size=32, num_hidden_layers=2,
+            first_k_dense_replace=1, num_attention_heads=2, q_lora_rank=16,
+            kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, intermediate_size=32, moe_intermediate_size=16,
+            n_routed_experts=4, num_experts_per_tok=2, max_seq_len=32)
+        mod, extra = pangu_moe, 0
+    else:
+        cfg = qwen3_next.Qwen3NextConfig(
+            vocab_size=64, hidden_size=32, num_hidden_layers=4,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+            linear_num_key_heads=2, linear_num_value_heads=2,
+            linear_key_head_dim=8, linear_value_head_dim=8,
+            moe_intermediate_size=16, shared_expert_intermediate_size=16,
+            num_experts=4, num_experts_per_tok=2, max_seq_len=32)
+        mod, extra = qwen3_next, 1
+    spec = mod.serving_spec(cfg)
+    params = {n: jnp.zeros(s, d) for n, (s, d) in
+              mod.param_shapes(cfg).items()}
+    pc = PagedKVCache(spec.num_layers, spec.cache_shape, 8, 8,
+                      layer_caches=spec.layer_caches,
+                      state_shapes=spec.state_shapes, num_state_slots=2)
+    packed = np.zeros((2, PACK_COLS + 8 + 4 + extra), np.int32)
+    out = {f"{family}.chunk": fused_decode_chunk.lower(
+               params, pc.pools, packed, spec, 8),
+           f"{family}.prefill": mod.prefill.lower(
+               params, jnp.zeros((1, 16), jnp.int32), cfg)}
+    if family == "hybrid":      # a prompt through the token-block map
+        monkeypatch.setattr(qwen3_next, "MOE_TOKEN_BLOCK", 8)
+        out["hybrid.prefill.blocked"] = mod.prefill.lower(
+            params, jnp.zeros((1, 20), jnp.int32), cfg)
+    return out
+
+
+@pytest.mark.skipif(jax.__version__ != "0.9.0",
+                    reason="the recorded text is jax 0.9.0's")
+@pytest.mark.parametrize("family", ["gpt2", "latent", "hybrid"])
+def test_a_spec_without_window_layers_lowers_to_the_text_it_had(
+        gpt, family, monkeypatch):
+    """GPT-2's, the latent family's and the hybrid's programs (cells 2 and
+    4, 3, 6) are the parent's to the byte: window layers are static on
+    `spec.window > 0`, and what the sliding-window family shares with the
+    hybrid one (`_rows_attention`, `map_token_blocks`) lowers as it did."""
+    found = {name: _sha(low) for name, low in
+             _lowered_by_family(family, gpt[1], monkeypatch).items()}
+    assert found == {k: v for k, v in PARENT_OF_PR_35.items()
+                     if k.startswith(family + ".")}
+
+
+def test_no_serving_module_names_a_family():
+    """The scan, the cache manager, the engine and the scheduler import
+    `models.spec` and no family, the sliding-window one included."""
+    import ast
+    import paddle_tpu.inference.serving as serving
+    from pathlib import Path
+    families = ("mellum", "qwen3_next", "pangu_moe")
+    for name in ("attention", "paged_cache", "engine", "scheduler"):
+        path = Path(serving.__file__).parent / f"{name}.py"
+        tree = ast.parse(path.read_text())
+        named = [a.name for n in ast.walk(tree) if isinstance(n, ast.Import)
+                 for a in n.names]
+        named += [f"{n.module}.{a.name}" for n in ast.walk(tree)
+                  if isinstance(n, ast.ImportFrom) for a in n.names]
+        assert not [n for n in named if any(f in n for f in families)], name
