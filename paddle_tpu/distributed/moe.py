@@ -43,7 +43,8 @@ from ..parallel.api import mark_sharding
 from ..parallel import mesh as _mesh
 from ..ops import manipulation as M
 
-__all__ = ["MoEMLP", "moe_dispatch_combine", "held_experts_mlp"]
+__all__ = ["MoEMLP", "moe_dispatch_combine", "held_experts_mlp",
+           "batched_form"]
 
 
 def _ep_constraint(x):
@@ -146,10 +147,41 @@ def _moe_mlp(x, wr, wu, bu, wd, bd, top_k, capacity_factor, min_capacity):
     return out.reshape(B, T, H), aux.astype(jnp.float32)
 
 
+#: the tile `jax.lax.ragged_dot`'s grouped kernel multiplies by on a v5e
+#: where it divides both sides of an expert's weights (K and N): the kernel
+#: then reads the weights of the experts a token reached at about 3/4 of the
+#: HBM peak. Where it divides neither the kernel falls to tiles of 256 and
+#: 128 and reads them at under a fifth (PERF.md section 6, PR 36)
+GROUPED_TILE = 512
+#: rows an expert above which a batched product stops being weight-bound on
+#: a v5e (197 TFLOP/s / 819 GB/s = 240 rows at bfloat16): padding every
+#: expert to more rows than this is no longer free
+RIDGE_ROWS = 240
+
+
+def batched_form(pairs, num_experts, count, hidden, width):
+    """Whether `held_experts_mlp` has a batched form at these shapes, and
+    when it is taken: (C, least) or None. Static, from shapes alone.
+
+    C, the rows an expert, is four times the load uniform routing gives
+    one (`pairs / num_experts`) rounded up to the 16 rows of a bfloat16
+    sublane tile; None where C passes the chip's ridge. `least` is how many
+    of the `count` held experts a token must reach: a batched product
+    reads every held expert's weights (at about 7/8 of the HBM peak), the
+    grouped kernel only those reached, so batched wins from 7 in 8 reached
+    where the kernel's tile divides `hidden` and `width`, and from 1 in 4
+    where it does not (probed on the chip, PERF.md section 6, PR 36)."""
+    capacity = 16 * math.ceil(4 * pairs / (16 * num_experts))
+    if capacity > RIDGE_ROWS:
+        return None
+    tiled = hidden % GROUPED_TILE == 0 and width % GROUPED_TILE == 0
+    return capacity, math.ceil(count * (7 / 8 if tiled else 1 / 4))
+
+
 def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
                      scale, live=None, scoring="sigmoid"):
     """The dropless expert layer of ONE chip of an expert-parallel
-    deployment: x [T, h] -> (routed part [T, h] float32, counts int32 [4]).
+    deployment: x [T, h] -> (routed part [T, h] float32, counts int32 [5]).
 
     The router is whole: `router_w` [h, E] scores every token over all E
     experts in float32 (`scoring`, static: "sigmoid" of each logit, or
@@ -159,23 +191,38 @@ def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
     `w_gate`, `w_up` [count, h, f] and `w_down` [count, f, h] are theirs)
     and computes exactly the (token, held expert) pairs: the T * top_k
     pairs are sorted by held expert (pairs of experts held elsewhere and of
-    rows that `live` [T] switches off sort behind the last group),
-    `jax.lax.ragged_dot` multiplies group by group, and each token sums
-    its weighted rows back. No capacity, so no token is dropped at any
-    imbalance; a token none of whose experts is held gets zeros. What the
-    absent experts would add is another chip's, and nothing here stands in
-    for them or for the exchange.
+    rows that `live` [T] switches off sort behind the last group), the
+    three products run group by group, and each token sums its weighted
+    rows back. No token is dropped at any imbalance; a token none of whose
+    experts is held gets zeros. What the absent experts would add is
+    another chip's, and nothing here stands in for them or for the
+    exchange.
 
-    The buffer `ragged_dot` multiplies has the size the batch needs, chosen
-    on the device (the kernel's row tile is min(rows, 512) whatever the
-    groups hold, so rows behind the last group are paid for as padding): R
-    rows, twice what uniform routing sends here rounded up to the 128 rows
-    of an MXU pass (static, from shapes), when the pairs routed here fit in
-    them; all T * top_k otherwise (a token MAY send all its picks here).
-    Both are in the program, under one `jax.lax.cond`.
+    The products have three forms, all in the program under one
+    `jax.lax.switch`, chosen on the device from the loads of the batch:
+
+    - batched: the sorted pairs gathered into [count, C, h] (expert e's
+      rows are the sorted pairs start[e] .. start[e] + sizes[e] - 1; a slot
+      past sizes[e] holds some other row, whose product is never read) and
+      multiplied as batched matmuls over the held experts. Taken when the
+      most loaded held expert has at most C rows and enough of them are
+      reached for reading every one to pay. C and that number are static
+      (`batched_form`): C is four times the uniform load in whole sublane
+      tiles, 32 rows at 8 tokens an expert; where it would pass the chip's
+      ridge padding stops being free and the form is not in the program (a
+      prompt's block of 1,024 tokens over 64 experts).
+    - compact: `jax.lax.ragged_dot` over the first R sorted pairs, R twice
+      what uniform routing sends here rounded up to the 128 rows of an MXU
+      pass (static, from shapes; the kernel's row tile is min(rows, 512)
+      whatever the groups hold, so rows behind the last group are paid for
+      as padding). Taken when the pairs routed here fit in R rows and the
+      batched form was not.
+    - full: `ragged_dot` over all T * top_k pairs (a token MAY send all its
+      picks here): the dropless fallback at any imbalance. Where R >= T *
+      top_k the compact form is this one.
 
     counts = (pairs routed here, held experts with at least one token, 1
-    if they did not fit in R rows and the full buffer was multiplied, most
+    if the full buffer was multiplied, 1 if the batched form was, most
     tokens one held expert got): sums first, the maximum last
     (`models.spec.merge_counts`); what the tracing reads, at no extra
     fetch."""
@@ -197,33 +244,63 @@ def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
     group = jnp.where(mine, local, count).reshape(-1)   # count = not here
     order = jnp.argsort(group, stable=True)
     sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
-    routed_here = jnp.sum(sizes)
+    routed_here, max_load = jnp.sum(sizes), jnp.max(sizes)
+    reached = jnp.sum(sizes > 0)
     place = jnp.argsort(order)            # of each (token, pick), sorted
 
-    def multiply(n):
-        """The first n sorted pairs through the experts, into [T, h]."""
-        rows = x[order[:n] // top_k]
-        act = jax.nn.silu(jax.lax.ragged_dot(
-            rows, w_gate, sizes, preferred_element_type=jnp.float32)) \
-            * jax.lax.ragged_dot(rows, w_up, sizes,
-                                 preferred_element_type=jnp.float32)
-        out = jax.lax.ragged_dot(act.astype(x.dtype), w_down, sizes,
-                                 preferred_element_type=jnp.float32)
-        out = jnp.where(group[:, None] < count,
-                        out[jnp.minimum(place, n - 1)] * weight[:, None],
+    def combine(out, at):
+        """Each (token, pick) takes row `at` of the products' output,
+        weighted; a token sums its picks: [T, h]."""
+        out = jnp.where(group[:, None] < count, out[at] * weight[:, None],
                         0.0)
         return out.reshape(tokens, top_k, -1).sum(axis=1)
+
+    def gated(rows, product):
+        """(silu(rows W_gate) * (rows W_up)) W_down, float32, with the
+        experts' weights multiplied as `product` does it."""
+        act = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
+        return product(act.astype(x.dtype), w_down)
+
+    def grouped(n):
+        """The first n sorted pairs through `ragged_dot`."""
+        out = gated(x[order[:n] // top_k], lambda a, w: jax.lax.ragged_dot(
+            a, w, sizes, preferred_element_type=jnp.float32))
+        return combine(out, jnp.minimum(place, n - 1))
+
+    def batched(capacity):
+        """Every held expert's first `capacity` sorted pairs through
+        batched matmuls over the experts."""
+        start = jnp.cumsum(sizes) - sizes
+        slots = jnp.minimum(start[:, None] + jnp.arange(capacity),
+                            pairs - 1)
+        out = gated(x[order[slots] // top_k],           # [count, C, h]
+                    lambda a, w: jnp.einsum(
+                        "eck,ekn->ecn", a, w,
+                        preferred_element_type=jnp.float32))
+        here = jnp.minimum(group, count - 1)
+        at = here * capacity + jnp.clip(place - start[here], 0,
+                                        capacity - 1)
+        return combine(out.reshape(count * capacity, -1), at)
 
     compact = 128 * math.ceil(2 * pairs * count
                               / (128 * router_w.shape[1]))
     full = routed_here > compact          # never where compact >= pairs
-    if compact >= pairs:
-        routed = multiply(pairs)
+    if compact < pairs:
+        forms, index = [lambda: grouped(compact), lambda: grouped(pairs)], \
+            full.astype(jnp.int32)
     else:
-        routed = jax.lax.cond(full, lambda: multiply(pairs),
-                              lambda: multiply(compact))
-    counts = jnp.stack([routed_here, jnp.sum(sizes > 0), full,
-                        jnp.max(sizes)]).astype(jnp.int32)
+        forms, index = [lambda: grouped(pairs)], 0
+    form = batched_form(pairs, router_w.shape[1], count, x.shape[1],
+                        w_gate.shape[2])
+    fits = jnp.bool_(False)
+    if form is not None:
+        capacity, least = form
+        fits = (max_load <= capacity) & (reached >= least)
+        forms.insert(0, lambda: batched(capacity))
+        index, full = jnp.where(fits, 0, index + 1), full & ~fits
+    routed = forms[0]() if len(forms) == 1 else jax.lax.switch(index, forms)
+    counts = jnp.stack([routed_here, reached, full, fits,
+                        max_load]).astype(jnp.int32)
     return routed, counts
 
 

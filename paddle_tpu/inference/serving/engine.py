@@ -196,8 +196,10 @@ _STAT_EVENTS = ("steps", "prefill_tokens", "generated_tokens",
                 "watchdog_trips", "rejected",
                 # expert families (ModelSpec.counters): (token, held
                 # expert) pairs routed, held experts hit per trip/layer,
-                # layers and trips that multiplied the full pair buffer
+                # layers and trips that multiplied the full pair buffer,
+                # and those that multiplied batched over the experts
                 "moe_pairs", "moe_experts_hit", "moe_full_buffer_layers",
+                "moe_batched_layers",
                 # the `context_tokens` and `live_row_trips` stats of
                 # serving.decode, summed
                 "context_tokens", "live_row_trips",
@@ -1462,7 +1464,9 @@ class LLMEngine:
                         ev.set_stats(
                             moe_pairs=counts["moe_pairs"],
                             moe_full_buffer_layers=counts[
-                                "moe_full_buffer_layers"])
+                                "moe_full_buffer_layers"],
+                            moe_batched_layers=counts[
+                                "moe_batched_layers"])
                     self.stats.prefill_tokens += int(tokens.size)
                     prefill_spend += int(tokens.size)
                     self.stats.time_prefill += time.perf_counter() - t0

@@ -77,7 +77,7 @@ __all__ = ["PanguMoEConfig", "PanguMoE", "param_shapes", "forward",
 
 #: the counts an expert layer returns (ModelSpec.counters)
 COUNTERS = ("moe_pairs", "moe_experts_hit", "moe_full_buffer_layers",
-            "moe_max_load")
+            "moe_batched_layers", "moe_max_load")
 
 
 @dataclass(frozen=True)

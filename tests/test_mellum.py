@@ -19,6 +19,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.distributed.moe import held_experts_mlp
+from paddle_tpu.models.pangu_moe import COUNTERS
 from paddle_tpu.inference.serving import (EngineConfig, LLMEngine,
                                           SamplingParams)
 from paddle_tpu.inference.serving.attention import (PACK_COLS,
@@ -140,7 +141,10 @@ def test_yarn_inverse_frequencies_are_the_formulas_for_this_config():
 
 def test_the_whole_expert_layer_is_the_references():
     """`held_experts_mlp` with held = (0, E), every expert: the reference's
-    whole layer, and no `cond` in the program (R >= T x top_k)."""
+    whole layer. R >= T x top_k, so there is no compact branch: ONE `cond`
+    of two branches, the batched form and the `ragged_dot` fallback (PR 36
+    changed this on purpose: the program had no `cond` before the batched
+    form)."""
     _, cfg, params = _family()
     x = jnp.asarray(np.random.default_rng(3).normal(0, 1, (24, 64)),
                     jnp.float32)
@@ -158,7 +162,10 @@ def test_the_whole_expert_layer_is_the_references():
     want, pairs = ref._experts(params, pre, x, ref.sizes(cfg), None)
     assert np.abs(np.asarray(routed) - np.asarray(want)).max() < LIMIT
     assert int(counts[0]) == int(pairs) == 24 * 2
-    assert "cond" not in str(jax.make_jaxpr(layer)(x))
+    jaxpr = str(jax.make_jaxpr(layer)(x))
+    assert jaxpr.count("cond[") == 1
+    assert jaxpr.count("ragged_dot_general[") == 3
+    assert dict(zip(COUNTERS, np.asarray(counts)))["moe_batched_layers"] == 1
 
 
 # ------------------------------------------- through the paged cache
@@ -557,7 +564,9 @@ def test_the_chunks_upload_carries_the_window_table_and_its_first_block():
     flat_in = jax.tree_util.tree_leaves(lowered.in_avals)
     assert len(flat_in) == len(params) + 4 * 2 + 1
     out = jax.tree_util.tree_leaves(lowered.out_info)
-    assert len(out) == 1 + 8 and out[0].shape == (8 + 2 + 4, 2)
+    # the spec's five counts as rows (four before PR 36's
+    # `moe_batched_layers`)
+    assert len(out) == 1 + 8 and out[0].shape == (8 + 2 + 5, 2)
     # every pool of both groups is donated: it aliases its output
     assert lowered.as_text().count("tf.aliasing_output") == 8
 

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.distributed.moe import held_experts_mlp
+from paddle_tpu.distributed.moe import batched_form, held_experts_mlp
 from paddle_tpu.inference.serving import (EngineConfig, LLMEngine,
                                           SamplingParams)
 from paddle_tpu.inference.serving.attention import (PACK_COLS,
@@ -136,7 +136,7 @@ def test_no_token_is_dropped_when_all_route_to_one_held_expert():
     x = jnp.abs(jnp.asarray(rng.normal(size=(50, 32)), jnp.float32))
     router = router.at[:, 3].set(5.0)           # x >= 0: column 3 wins
     routed, counts = held_experts_mlp(x, router, wg, wu, wd, (3, 2), 2, 1.0)
-    assert int(counts[3]) == 50 and int(counts[0]) >= 50
+    assert int(counts[-1]) == 50 and int(counts[0]) >= 50
     scores = jax.nn.sigmoid(jnp.dot(x, router, precision="highest"))
     top_s, top_i = jax.lax.top_k(scores, 2)
     assert bool((top_i[:, 0] == 3).all())
@@ -184,20 +184,34 @@ def _routed_by_class(rng, both, one, tokens=256):
     return jnp.asarray(x, jnp.float32), jnp.asarray(router, jnp.float32)
 
 
-@pytest.mark.parametrize("both, one, live, full", [
-    pytest.param(None, None, None, 0, id="uniform-compact"),
-    pytest.param(256, 0, None, 1, id="every-pick-here-full"),
-    pytest.param(60, 8, None, 0, id="pairs-equal-R-compact"),
-    pytest.param(60, 9, None, 1, id="pairs-R-plus-1-full"),
-    pytest.param(256, 0, 50, 0, id="rows-switched-off-compact"),
-    pytest.param(0, 0, None, 0, id="no-held-expert-zeros"),
+@pytest.mark.parametrize("both, one, live, form", [
+    pytest.param(None, None, None, "batched", id="uniform-batched"),
+    pytest.param(None, None, 100, "batched",
+                 id="uniform-rows-switched-off-batched"),
+    pytest.param(32, 0, None, "batched", id="load-equal-C-batched"),
+    pytest.param(33, 0, None, "compact", id="load-C-plus-1-compact"),
+    pytest.param(16, 30, None, "batched", id="uneven-under-C-batched"),
+    pytest.param(256, 0, None, "full", id="every-pick-here-full"),
+    pytest.param(0, 256, None, "full", id="every-token-on-one-expert-full"),
+    pytest.param(0, 128, None, "compact",
+                 id="half-the-tokens-on-one-expert-compact"),
+    pytest.param(60, 8, None, "compact", id="pairs-equal-R-compact"),
+    pytest.param(60, 9, None, "full", id="pairs-R-plus-1-full"),
+    pytest.param(256, 0, 50, "compact", id="rows-switched-off-compact"),
+    pytest.param(0, 0, None, "compact", id="no-held-expert-zeros"),
 ])
-def test_the_compact_and_the_full_buffer_equal_a_per_token_loop(
-        both, one, live, full):
-    """256 tokens, top-2 of 64 experts, 4 held: 512 pairs against a compact
-    buffer of R = 128 rows. Whichever branch the routed pairs choose, the
-    routed part and the counts are those of a loop over every token's
-    picks."""
+def test_every_form_of_the_expert_layer_equals_a_per_token_loop(
+        both, one, live, form):
+    """256 tokens, top-2 of 64 experts, 4 held (the rest are another
+    chip's): 512 pairs against a capacity of C = 32 rows an expert and a
+    compact buffer of R = 128 rows. Whichever form the loads choose
+    (batched over the experts while the most loaded has at most C rows,
+    else `ragged_dot` over R rows while the pairs fit, else over all 512:
+    no token is dropped at any routing), the routed part and the counts
+    are those of a loop over every token's picks, and the counts say which
+    form ran. (PR 36 changed this test on purpose: the batched form and its
+    count `moe_batched_layers` are new, six cases more.)"""
+    assert batched_form(512, 64, 4, 32, 16) == (32, 1)
     rng = np.random.default_rng(6)
     if both is None:
         x = jnp.asarray(rng.normal(size=(256, 32)), jnp.float32)
@@ -224,13 +238,66 @@ def test_the_compact_and_the_full_buffer_equal_a_per_token_loop(
                                atol=1e-5)
     assert dict(zip(pm.COUNTERS, np.asarray(counts))) == {
         "moe_pairs": load.sum(), "moe_experts_hit": (load > 0).sum(),
-        "moe_full_buffer_layers": full, "moe_max_load": load.max()}
-    assert (load.sum() > 128) == bool(full)
+        "moe_full_buffer_layers": form == "full",
+        "moe_batched_layers": form == "batched", "moe_max_load": load.max()}
+    assert form == ("batched" if 0 < load.max() <= 32 else
+                    "full" if load.sum() > 128 else "compact")
     if both is not None and live is None:
         assert load.sum() == 2 * both + one
         none_held = np.asarray(x[:, 2] > 0)     # the third class
         assert not np.asarray(routed)[none_held].any()
         assert none_held.sum() == 256 - both - one
+
+
+@pytest.mark.parametrize("pairs, experts, count, hidden, width, form", [
+    pytest.param(512, 64, 64, 2304, 896, (32, 16), id="cell-7-decode"),
+    pytest.param(8192, 64, 64, 2304, 896, None, id="cell-7-prefill-block"),
+    pytest.param(1024, 256, 16, 7680, 2048, (16, 14), id="cell-3-decode"),
+    pytest.param(8192, 256, 16, 7680, 2048, (128, 14),
+                 id="cell-3-longest-prompt"),
+    pytest.param(1280, 512, 128, 2048, 512, (16, 112), id="cell-6-decode"),
+    pytest.param(10240, 512, 128, 2048, 512, (80, 112),
+                 id="cell-6-prefill-block"),
+    pytest.param(8, 64, 64, 2304, 896, (16, 16), id="one-row"),
+])
+def test_the_batched_form_is_eligible_by_shapes_alone(
+        pairs, experts, count, hidden, width, form):
+    """C is four times the uniform load in whole sublane tiles, and the
+    form is out of the program where C passes the chip's ridge; it is taken
+    from 7 in 8 held experts reached where the grouped kernel's 512 tile
+    divides both sides of an expert's weights, from 1 in 4 where not."""
+    assert batched_form(pairs, experts, count, hidden, width) == form
+
+
+def test_few_experts_reached_keep_the_grouped_kernel():
+    """A batched product reads every held expert, the grouped kernel only
+    those a token reached: with 16 held experts of tile-sized weights and
+    tokens on two of them, the layer stays on `ragged_dot` whatever the
+    loads; with 14 reached it multiplies batched. Same result."""
+    rng = np.random.default_rng(9)
+    wg, wu, wd = (jnp.asarray(rng.normal(size=s) * 0.05, jnp.float32)
+                  for s in ((16, 512, 512), (16, 512, 512), (16, 512, 512)))
+    x = rng.normal(size=(32, 512))
+    assert batched_form(32, 16, 16, 512, 512) == (16, 14)
+    took = {}
+    for reached in (2, 14):
+        # token t's one pick is expert t % reached
+        router = np.zeros((512, 16))
+        x[:, :16] = 4.0 * np.eye(16)[np.arange(32) % reached]
+        router[:16] = 8.0 * np.eye(16) - 4.0
+        routed, counts = held_experts_mlp(
+            jnp.asarray(x, jnp.float32), jnp.asarray(router, jnp.float32),
+            wg, wu, wd, (0, 16), 1, 1.0)
+        counts = dict(zip(pm.COUNTERS, np.asarray(counts)))
+        assert counts["moe_experts_hit"] == reached
+        took[reached] = counts["moe_batched_layers"]
+        with jax.default_matmul_precision("highest"):
+            want = np.stack([np.asarray(ref._mlp(
+                jnp.asarray(x[t], jnp.float32), wg[t % reached],
+                wu[t % reached], wd[t % reached], None)) for t in range(32)])
+        np.testing.assert_allclose(np.asarray(routed), want, atol=1e-4,
+                                   rtol=1e-5)
+    assert took == {2: 0, 14: 1}
 
 
 # ------------------------------------------------- the latent paged cache
@@ -384,6 +451,44 @@ def test_engine_streams_are_bit_equal_for_chunks_of_8_and_of_1():
     assert eng8.stats.as_dict()["moe_pairs"] == eng8.stats.moe_pairs
     # 8 of 8 experts held: the compact buffer is the whole one, no fallback
     assert eng8.stats.as_dict()["moe_full_buffer_layers"] == 0
+    # at most 4 rows x top-2: no load passes the capacity, so every expert
+    # layer of every prefill and trip took the batched form (PR 36)
+    assert eng8.stats.as_dict()["moe_batched_layers"] \
+        == eng8.stats.moe_batched_layers > 0
+
+
+def test_the_batched_layers_are_on_the_spans_and_in_the_engines_stats():
+    """`moe_batched_layers` (PR 36) beside `moe_full_buffer_layers`: a stat
+    of `serving.prefill` and `serving.decode`, set from the program's one
+    fetch, and an `EngineStats` counter that sums them. All 8 experts held
+    and at most 3 rows of top-2: every load fits the capacity and two
+    experts at least are reached, so each prefill counts its 2 expert
+    layers, a chunk 2 a trip with a live row, and nothing falls back."""
+    from paddle_tpu import obs
+    model, _, _ = _family()
+    eng = _engine(model, 8)
+    rng = np.random.default_rng(11)
+    for i, n in enumerate((5, 12, 9)):
+        eng.add_request(rng.integers(0, 256, (n,)).astype(np.int32),
+                        SamplingParams(max_tokens=17), request_id=f"r{i}")
+    assert not obs.trace.is_enabled()
+    obs.trace.enable()
+    try:
+        eng.run()
+        spans = {name: [e.args for e in obs.trace.events() if e.name == name]
+                 for name in ("serving.prefill", "serving.decode")}
+    finally:
+        obs.trace.disable()
+        obs.trace.clear()
+    assert [p["moe_batched_layers"] for p in spans["serving.prefill"]] \
+        == [2, 2, 2]
+    assert len(spans["serving.decode"]) == 2        # 16 tokens: 8 and 8
+    for d in spans["serving.decode"]:
+        assert d["moe_batched_layers"] == 2 * d["chunk"] == 16
+        assert d["moe_full_buffer_layers"] == 0 and 0 < d["moe_max_load"] <= 3
+    assert eng.stats.moe_batched_layers == 3 * 2 + 2 * 16
+    assert eng.stats.as_dict()["moe_batched_layers"] == 38
+    assert eng.stats.moe_full_buffer_layers == 0
 
 
 def test_chunked_prefill_rides_the_shared_prompt_feed():
@@ -411,12 +516,14 @@ def test_the_chunk_returns_its_counts_as_extra_rows():
     out, _ = fused_decode_chunk(params, pc.pools, jnp.asarray(packed), spec,
                                 k)
     out = np.asarray(out)
-    assert out.shape == (k + 2 + 4, n)
-    pairs, hit, full, load = out[k + 2:, 0]
+    assert out.shape == (k + 2 + len(pm.COUNTERS), n)
+    pairs, hit, full, batched, load = out[k + 2:, 0]
     # one live row, 2 expert layers, top-2 with every expert held: 2 pairs
     # a layer and trip on 2 experts, and the dead row routes nowhere
     assert pairs == k * 2 * 2 and hit == pairs and load == 1
-    assert full == 0
+    # a load of 1 fits any capacity: every layer of every trip multiplied
+    # batched over the experts (PR 36), none fell back
+    assert full == 0 and batched == k * 2
     assert (out[k + 2:, 1] == out[k + 2:, 0]).all()
 
 

@@ -96,7 +96,8 @@ def test_the_expert_layer_in_token_blocks_counts_what_the_whole_counts(
     monkeypatch.setattr(qn, "MOE_TOKEN_BLOCK", 8)
     blocks = jax.jit(lambda p, i: qn._dense_layers(p, i, cfg))(params, ids)
     np.testing.assert_allclose(blocks[0], whole[0], atol=LIMIT, rtol=0)
-    pairs, hit, full, load = (np.asarray(c) for c in zip(whole[2], blocks[2]))
+    pairs, hit, full, batched, load = (
+        np.asarray(c) for c in zip(whole[2], blocks[2]))
     assert pairs[0] == pairs[1] > 0             # every pair, exactly once
     assert hit[1] >= hit[0] and load[1] <= load[0]      # per block
 
@@ -497,7 +498,7 @@ def test_the_chunk_carries_the_slot_in_one_more_column():
     out, pools = fused_decode_chunk(params, pc.pools, jnp.asarray(packed),
                                     spec, k)
     out = np.asarray(out)
-    assert out.shape == (k + 2 + 4, n)
+    assert out.shape == (k + 2 + len(spec.counters), n)   # five since PR 36
     assert out[k + 1, 0] == 0                   # no NaN reached the logits
     assert (out[:2, 0] == -1).all() and (out[2:k, 0] >= 0).all()
     state = np.asarray(pools[0].arrays[0])

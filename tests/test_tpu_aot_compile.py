@@ -348,36 +348,59 @@ def test_flash_compiles_per_shard_under_a_mesh(topo):
         set_global_mesh(before)
 
 
+#: (hidden, width, experts scored, held, top-k) of the expert cells
+EXPERT_WIDTHS = {"latent": (7680, 2048, 256, 16, 8),    # cell 3, 16 of 256
+                 "swa": (2304, 896, 64, 64, 8)}         # cell 7, all 64
+
+
 @functools.lru_cache(maxsize=None)
-def _held_experts_compiled(one_chip, rows):
-    """The dropless expert layer at the published widths (16 held experts
-    of width 2048 on hidden 7680, top-8 of 256), compiled for `rows`
-    tokens."""
+def _held_experts_compiled(one_chip, rows, widths="latent"):
+    """The dropless expert layer at a cell's published widths, compiled for
+    `rows` tokens."""
     from paddle_tpu.distributed.moe import held_experts_mlp
+    hidden, width, scored, held, top_k = EXPERT_WIDTHS[widths]
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def layer(x, router, wg, wu, wd):
-        return held_experts_mlp(x, router, wg, wu, wd, (0, 16), 8, 2.5)
+        return held_experts_mlp(x, router, wg, wu, wd, (0, held), top_k, 2.5)
     return jax.jit(layer).lower(
-        sds((rows, 7680), jnp.bfloat16), sds((7680, 256), jnp.float32),
-        sds((16, 7680, 2048), jnp.bfloat16),
-        sds((16, 7680, 2048), jnp.bfloat16),
-        sds((16, 2048, 7680), jnp.bfloat16)).compile()
+        sds((rows, hidden), jnp.bfloat16), sds((hidden, scored), jnp.float32),
+        sds((held, hidden, width), jnp.bfloat16),
+        sds((held, hidden, width), jnp.bfloat16),
+        sds((held, width, hidden), jnp.bfloat16)).compile()
+
+
+def _branches(text: str) -> list:
+    """The text of each branch computation of the program's one
+    `conditional`, with what it calls, in branch order."""
+    found = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}",
+                       text)
+    assert len(found) == 1
+    return [_computation(text, name.strip())
+            for name in found[0].split(",")]
+
+
+def _batched_products(text: str) -> int:
+    """Batched matmuls over the experts (`eck,ekn->ecn`), which the chip's
+    compiler writes as convolutions."""
+    return len(re.findall(r" convolution\(.*eck,ekn->ecn", text))
 
 
 def test_the_held_experts_layer_is_a_grouped_matmul_kernel(one_chip):
     """At 128 decode rows `jax.lax.ragged_dot` becomes Mosaic grouped
     matmuls (three products and their group metadata), not a dense product
     over every (token, expert), and the pair buffer is the only large
-    temporary."""
+    temporary. (PR 36 changed this test on purpose: the batched form is a
+    third branch, and it copies no expert weight either.)"""
     compiled = _held_experts_compiled(one_chip, 128)
     text = compiled.as_text()
     assert text.count("ragged-dot") >= 3
     assert text.count("tpu_custom_call") >= 3
     # 1,024 pair rows of 7,680 float32 are 31 MB; a dense [16, 128, ...]
-    # expansion of the weights or the rows would be hundreds
+    # expansion of the weights or the rows, or a copy of one product's
+    # weights (503 MB), would be hundreds
     assert compiled.memory_analysis().temp_size_in_bytes < 200e6
 
 
@@ -390,15 +413,56 @@ def test_the_pair_buffer_has_a_compact_and_a_full_branch(one_chip, rows,
     pairs against 1,024): one `conditional` with the three products in each
     branch. The kernel's row tile is min(buffer rows, 512), so the compact
     branch of a decode trip multiplies tiles of 128 rows where the full one
-    multiplies 512."""
+    multiplies 512. (PR 36 changed this test on purpose: the batched form
+    is the first of now three branches, at a capacity of 16 and 128 rows an
+    expert; PR 30's two stay as they were.)"""
     compiled = _held_experts_compiled(one_chip, rows)
     text = compiled.as_text()
-    assert len(re.findall(r" conditional\(", text)) == 1
-    assert sorted(re.findall(r'ragged_dot_tiling="([0-9,]+)"', text)) \
-        == sorted([compact_tile] * 3 + ["512,512,512"] * 3)
+    batched, compact, full = _branches(text)
+    assert _batched_products(batched) == 3 and "ragged-dot" not in batched
+    assert f"f32[16,{rows // 8},2048]" in batched
+    for branch, tile in ((compact, compact_tile), (full, "512,512,512")):
+        assert re.findall(r'ragged_dot_tiling="([0-9,]+)"', branch) \
+            == [tile] * 3
+        assert _batched_products(branch) == 0
     # the full branch's 8 x rows pair rows of 7,680 float32, twice
     assert compiled.memory_analysis().temp_size_in_bytes \
         < (200e6 if rows == 128 else 600e6)
+
+
+def test_the_expert_layer_multiplies_batched_where_the_loads_fit(one_chip):
+    """The layer at the sliding-window cell's decode shape ([64, 2304], 64
+    experts of 2,304 x 896, top-8: 512 pairs, 8 an expert): ONE
+    `conditional`; in one branch the three products batched over the
+    experts at the capacity of 32 rows, in the other three `ragged-dot`
+    kernels, the dropless fallback (no compact branch: R >= the pairs). No
+    branch copies an expert weight: the temporaries are the [64, 32, .]
+    buffers, a few MB, where one product's weights are 264 MB."""
+    compiled = _held_experts_compiled(one_chip, 64, "swa")
+    text = compiled.as_text()
+    batched, grouped = _branches(text)
+    assert _batched_products(batched) == 3 and "ragged-dot" not in batched
+    assert "f32[64,32,896]" in batched and "f32[64,32,2304]" in batched
+    # 512 divides neither 2,304 nor 896: the grouped kernel's small tiles
+    assert sorted(re.findall(r'ragged_dot_tiling="([0-9,]+)"', grouped)) \
+        == ["512,128,256", "512,256,128", "512,256,128"]
+    assert grouped.count("tpu_custom_call") >= 3
+    assert _batched_products(grouped) == 0
+    assert not re.search(r"bf16\[64,(?:2304,896|896,2304)\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
+
+
+def test_a_prompts_block_of_tokens_keeps_the_grouped_kernel(one_chip):
+    """The same layer at a prompt's block of 1,024 tokens (8,192 pairs, 128
+    an expert): four times that passes the chip's ridge, so the batched
+    form is not in the program: no `conditional`, three `ragged-dot`
+    kernels, as before PR 36."""
+    compiled = _held_experts_compiled(one_chip, 1024, "swa")
+    text = compiled.as_text()
+    assert not re.findall(r" conditional\(", text)
+    assert len(re.findall(r"ragged_dot_tiling=", text)) == 3
+    assert _batched_products(text) == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < 100e6
 
 
 def test_the_latent_pool_is_scattered_in_place_at_the_cell_size(one_chip):
