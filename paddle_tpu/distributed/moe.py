@@ -159,6 +159,12 @@ GROUPED_TILE = 512
 RIDGE_ROWS = 240
 
 
+def load_capacity(times, pairs, num_experts):
+    """Rows an expert at `times` the load uniform routing gives one
+    (`pairs / num_experts`), in whole 16-row sublane tiles of bfloat16."""
+    return 16 * math.ceil(times * pairs / (16 * num_experts))
+
+
 def batched_form(pairs, num_experts, count, hidden, width):
     """Whether `held_experts_mlp` has a batched form at these shapes, and
     when it is taken: (C, least) or None. Static, from shapes alone.
@@ -171,7 +177,7 @@ def batched_form(pairs, num_experts, count, hidden, width):
     grouped kernel only those reached, so batched wins from 7 in 8 reached
     where the kernel's tile divides `hidden` and `width`, and from 1 in 4
     where it does not (probed on the chip, PERF.md section 6, PR 36)."""
-    capacity = 16 * math.ceil(4 * pairs / (16 * num_experts))
+    capacity = load_capacity(4, pairs, num_experts)
     if capacity > RIDGE_ROWS:
         return None
     tiled = hidden % GROUPED_TILE == 0 and width % GROUPED_TILE == 0
@@ -181,7 +187,7 @@ def batched_form(pairs, num_experts, count, hidden, width):
 def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
                      scale, live=None, scoring="sigmoid"):
     """The dropless expert layer of ONE chip of an expert-parallel
-    deployment: x [T, h] -> (routed part [T, h] float32, counts int32 [5]).
+    deployment: x [T, h] -> (routed part [T, h] float32, counts int32 [8]).
 
     The router is whole: `router_w` [h, E] scores every token over all E
     experts in float32 (`scoring`, static: "sigmoid" of each logit, or
@@ -222,10 +228,13 @@ def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
       top_k the compact form is this one.
 
     counts = (pairs routed here, held experts with at least one token, 1
-    if the full buffer was multiplied, 1 if the batched form was, most
-    tokens one held expert got): sums first, the maximum last
+    if the full buffer was multiplied, 1 if the batched form was, 1 (this
+    call), 1 if the most loaded held expert got at most twice the uniform
+    load in whole sublane tiles (`load_capacity`), 1 if at most four
+    times, most tokens one held expert got): sums first, the maximum last
     (`models.spec.merge_counts`); what the tracing reads, at no extra
-    fetch."""
+    fetch. The two fits are counted whether or not the batched form is in
+    the program: they say what a capacity of that size WOULD hold."""
     first, count = held
     tokens, pairs = x.shape[0], x.shape[0] * top_k
     if scoring not in ("sigmoid", "softmax"):
@@ -299,7 +308,10 @@ def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
         forms.insert(0, lambda: batched(capacity))
         index, full = jnp.where(fits, 0, index + 1), full & ~fits
     routed = forms[0]() if len(forms) == 1 else jax.lax.switch(index, forms)
-    counts = jnp.stack([routed_here, reached, full, fits,
+    experts = router_w.shape[1]
+    counts = jnp.stack([routed_here, reached, full, fits, 1,
+                        max_load <= load_capacity(2, pairs, experts),
+                        max_load <= load_capacity(4, pairs, experts),
                         max_load]).astype(jnp.int32)
     return routed, counts
 

@@ -80,9 +80,9 @@ from ...profiler import RecordEvent
 from .attention import PACK_COLS, as_spec, fused_decode_chunk, pack_f32
 from .paged_cache import (CacheExhausted, PagedKVCache,
                           window_blocks_per_seq)
-from .scheduler import (EngineOverloaded, Request, RequestState,
-                        SamplingParams, ScheduledBatch, Scheduler,
-                        SchedulerConfig, record_promotion_events)
+from .scheduler import (HOLD_REASONS, EngineOverloaded, Request,
+                        RequestState, SamplingParams, ScheduledBatch,
+                        Scheduler, SchedulerConfig, record_promotion_events)
 
 __all__ = ["EngineConfig", "EngineStats", "LLMEngine", "RequestOutput",
            "ServingPredictor"]
@@ -200,6 +200,9 @@ _STAT_EVENTS = ("steps", "prefill_tokens", "generated_tokens",
                 # and those that multiplied batched over the experts
                 "moe_pairs", "moe_experts_hit", "moe_full_buffer_layers",
                 "moe_batched_layers",
+                # calls of the expert layer, and those whose largest load
+                # was at most two / four times the uniform one
+                "moe_layer_calls", "moe_fit_2x", "moe_fit_4x",
                 # the `context_tokens` and `live_row_trips` stats of
                 # serving.decode, summed
                 "context_tokens", "live_row_trips",
@@ -290,12 +293,15 @@ class EngineStats:
             labels=("engine", "phase"))
         self._syncs = {p: sy.labels(phase=p, **lbl)
                        for p in ("prefill", "decode")}
-        self._g_syncs_per_token = obs.gauge(
-            "serving_host_syncs_per_token",
-            "decode host syncs / generated tokens — the steady-state "
-            "per-token host round-trip cost the fused chunk amortizes "
-            "to ~1/k",
-            labels=("engine",)).labels(**lbl)
+        ah = obs.counter(
+            "serving_admission_holds_total",
+            "engine steps by what ended their admission "
+            "(scheduler.HOLD_REASONS): none (the queue ran empty) | rows "
+            "(max_num_seqs) | budget (max_prefill_tokens) | watermark | "
+            "window (the window group's free blocks) | blocks "
+            "(CacheExhausted)",
+            labels=("engine", "reason"))
+        self._holds = {r: ah.labels(reason=r, **lbl) for r in HOLD_REASONS}
         g_run = obs.gauge("serving_running", "running sequences",
                           labels=("engine",))
         g_wait = obs.gauge("serving_waiting", "waiting-queue depth",
@@ -311,13 +317,6 @@ class EngineStats:
             "serving_prefill_chunks_total",
             "prompt chunks consumed inside the fused decode scan — one "
             "per mid-prefill row per chunk dispatch (chunked prefill)",
-            labels=("engine",)).labels(**lbl)
-        self._g_padding_waste = obs.gauge(
-            "serving_padding_waste_ratio",
-            "dead (padded) rows / batch width of the last decode "
-            "dispatch: (bucket - live)/bucket under the bucketed "
-            "fallback; 0 under the ragged kernel, whose per-row length "
-            "gating makes dead rows cost zero kernel work",
             labels=("engine",)).labels(**lbl)
         self._g_cache_bytes_per_token = obs.gauge(
             "serving_cache_bytes_per_token",
@@ -491,12 +490,6 @@ class EngineStats:
     def prefill_chunks(self) -> int:
         return int(self._c_prefill_chunks.value)
 
-    def set_padding_waste(self, v: float) -> None:
-        self._g_padding_waste.set(v)
-
-    def padding_waste(self) -> float:
-        return self._g_padding_waste.value
-
     def inc_host_sync(self, phase: str) -> None:
         self._syncs[phase].inc()
 
@@ -505,11 +498,17 @@ class EngineStats:
         decode syncs == number of chunks, not tokens)."""
         return int(self._syncs[phase].value)
 
-    def set_syncs_per_token(self, v: float) -> None:
-        self._g_syncs_per_token.set(v)
-
     def host_syncs_per_token(self) -> float:
-        return self._g_syncs_per_token.value
+        """Decode host syncs / generated tokens (at most 1 / chunk size):
+        the quotient of the two counters, taken when asked for."""
+        return self.host_syncs("decode") / self.generated_tokens \
+            if self.generated_tokens else 0.0
+
+    def note_hold(self, reason: str) -> None:
+        self._holds[reason].inc()
+
+    def admission_holds(self, reason: str) -> int:
+        return int(self._holds[reason].value)
 
     def record_prefix(self, ps: dict) -> None:
         """Publish one prefix-cache snapshot (PagedKVCache.prefix_stats)
@@ -591,9 +590,7 @@ class EngineStats:
         busy = self.time_prefill + self.time_decode
         d["decode_tokens_per_sec"] = (
             self.generated_tokens / busy if busy > 0 else 0.0)
-        d["host_syncs_per_token"] = (
-            self.host_syncs("decode") / self.generated_tokens
-            if self.generated_tokens else 0.0)
+        d["host_syncs_per_token"] = self.host_syncs_per_token()
         return d
 
 
@@ -712,6 +709,12 @@ class LLMEngine:
         self.geom = geom                  # as given: what the programs key on
         self.spec = spec
         self.config = config
+        # the stat of `serving.prefill` by which a device trace's reader
+        # finds the expert products: (held experts, hidden, expert width)
+        # of their weights, "64x2304x896" (the profiler cuts a stat's
+        # value at a comma)
+        self._moe_shape = {"moe_shape": "x".join(
+            map(str, spec.expert_shape))} if spec.expert_shape else {}
         self.max_blocks_per_seq = S // config.block_size
         self.cache = PagedKVCache(
             spec.num_layers, spec.cache_shape, config.num_blocks,
@@ -1424,7 +1427,7 @@ class LLMEngine:
         step_no = self.stats.steps
         self._step_start = time.perf_counter()
         with RecordEvent("serving.engine_step", cat="serving",
-                         args={"step": step_no}) as step_ev:
+                         args={"step": step_no}):
             # ptlint: disable=PT-C004  fault injector: inert no-op in
             # production (env-gated); chaos tests NEED it inside the lock
             # to corrupt state at the exact point a real fault would
@@ -1440,11 +1443,17 @@ class LLMEngine:
             t0 = time.perf_counter()
             with RecordEvent("serving.schedule", cat="schedule") as ev:
                 batch = self.scheduler.schedule()
-                ev.args = {"prefill": len(batch.prefill),
-                           "decode": len(batch.decode),
-                           "preempted": len(batch.preempted),
-                           "waiting": self.scheduler.num_waiting(),
-                           "free_blocks": self.cache.num_free()}
+                ev.set_stats(
+                    prefill=len(batch.prefill),
+                    prefill_tokens=sum(
+                        len(r.prompt_ids) + len(r.output_ids)
+                        for r in batch.prefill),
+                    chunked=batch.chunked, decode=len(batch.decode),
+                    waiting=self.scheduler.num_waiting(),
+                    preempted=len(batch.preempted),
+                    free_blocks=self.cache.num_free(),
+                    held_by=batch.held_by)
+            self.stats.note_hold(batch.held_by)
             self.stats.preemptions += len(batch.preempted)
             self.stats.time_schedule += time.perf_counter() - t0
 
@@ -1454,19 +1463,14 @@ class LLMEngine:
                 tokens = req.all_token_ids()
                 with RecordEvent("serving.prefill", cat="prefill",
                                  args={"request_id": req.request_id,
-                                       "tokens": int(tokens.size)}) as ev:
+                                       "tokens": int(tokens.size),
+                                       **self._moe_shape}) as ev:
                     try:
                         logits, counts = self._prefill(req, tokens)
                     except Exception as e:
                         self._quarantine(req, outs, f"prefill raised: {e}")
                         continue
-                    if counts:
-                        ev.set_stats(
-                            moe_pairs=counts["moe_pairs"],
-                            moe_full_buffer_layers=counts[
-                                "moe_full_buffer_layers"],
-                            moe_batched_layers=counts[
-                                "moe_batched_layers"])
+                    ev.set_stats(**counts)
                     self.stats.prefill_tokens += int(tokens.size)
                     prefill_spend += int(tokens.size)
                     self.stats.time_prefill += time.perf_counter() - t0
@@ -1535,11 +1539,6 @@ class LLMEngine:
                             ev.set_stats(
                                 window_blocks_freed=self._release_windows(
                                     decode))
-            step_ev.args = {"step": step_no, "outputs": len(outs),
-                            "errors": self.stats.errors,
-                            "expired": self.stats.expired,
-                            "shed": self.stats.shed,
-                            "recoveries": self.stats.recoveries}
         # per-step telemetry: all host values already in hand (scheduler
         # counters, cache free lists) — recording adds no device work
         step_dt = time.perf_counter() - self._step_start
@@ -1548,10 +1547,6 @@ class LLMEngine:
         # early-reject estimator (inert without a tenant registry)
         self.scheduler.note_step_seconds(step_dt)
         self.stats.set_prefill_spend(prefill_spend)
-        if self.stats.generated_tokens:
-            self.stats.set_syncs_per_token(
-                self.stats.host_syncs("decode")
-                / self.stats.generated_tokens)
         self.stats.set_step_gauges(
             running=self.scheduler.num_running(),
             waiting=self.scheduler.num_waiting(),
@@ -1687,7 +1682,8 @@ class LLMEngine:
         their next min(k, remaining-prompt) tokens packed into the feed
         columns and advance prefill_pos iff the chunk came back clean.
         Returns (tokens [k, len(reqs)] int32 with -1 on frozen rows,
-        bad [len(reqs)] bool, the spec's counts of the chunk by name)."""
+        bad [len(reqs)] bool, the span's stats of the chunk: the spec's
+        counts by name, `rows` and `feeding_rows`)."""
         ragged = self.config.kernel == "ragged"
         n = self.config.max_num_seqs if ragged \
             else _bucket(len(reqs), self.config.max_num_seqs)
@@ -1741,9 +1737,6 @@ class LLMEngine:
             fetched = np.asarray(out)        # the chunk's ONE host sync
         self.stats.inc_host_sync("decode")
         live = len(reqs)
-        # padded-vs-live telemetry: the bucketed fallback burns compute
-        # on its dead rows; the ragged kernel's length gating skips them
-        self.stats.set_padding_waste(0.0 if ragged else (n - live) / n)
         if fed:
             self.stats.inc_prefill_chunks(len(fed))
         bad = fetched[k + 1, :live].astype(bool)
@@ -1765,6 +1758,8 @@ class LLMEngine:
                         req.all_token_ids()[:req.prefill_pos])
         counts = self._count(fetched[k + 2:, 0]) if self.spec.counters \
             else {}
+        # the program's row width and the rows that fed prompt tokens
+        counts.update(rows=n, feeding_rows=len(fed))
         return fetched[:k, :live], bad, counts
 
     # ------------------------------------------------------- convenience

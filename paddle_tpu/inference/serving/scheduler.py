@@ -55,7 +55,7 @@ from ...obs import reqtrace
 from .paged_cache import CacheExhausted, PagedKVCache
 
 __all__ = ["EngineOverloaded", "SamplingParams", "Request", "RequestState",
-           "Scheduler", "SchedulerConfig", "ScheduledBatch",
+           "Scheduler", "SchedulerConfig", "ScheduledBatch", "HOLD_REASONS",
            "record_promotion_events"]
 
 ADMISSION_POLICIES = ("reject", "shed_oldest")
@@ -254,11 +254,22 @@ class SchedulerConfig:
     tenants: Optional[object] = None
 
 
+#: why admission ended in a step (`ScheduledBatch.held_by`): the queue was
+#: empty, every row was taken (`max_num_seqs`), the prefill budget was
+#: spent, the cache stood above its watermark, the window group had too
+#: few free blocks, or an allocation raised `CacheExhausted`
+HOLD_REASONS = ("none", "rows", "budget", "watermark", "window", "blocks")
+
+
 @dataclass
 class ScheduledBatch:
     prefill: List[Request] = field(default_factory=list)
     decode: List[Request] = field(default_factory=list)
     preempted: List[Request] = field(default_factory=list)
+    #: requests admitted to ride the decode scan (chunked prefill, prefix
+    #: hits); they are in `decode` too
+    chunked: int = 0
+    held_by: str = "none"                # one of HOLD_REASONS
 
 
 class Scheduler:
@@ -275,8 +286,6 @@ class Scheduler:
         "waiting": "_lock",
         "running": "_lock",
         "num_preemptions": "_lock",
-        "watermark_holds": "_lock",
-        "window_holds": "_lock",
         "_vtime": "_lock",
         "_vfinish": "_lock",
         "_wfq_weights": "_lock",
@@ -300,8 +309,6 @@ class Scheduler:
         self.waiting: deque = deque()
         self.running: List[Request] = []
         self.num_preemptions = 0
-        self.watermark_holds = 0             # admissions paused by watermark
-        self.window_holds = 0                # ... by the window group's free
         # multi-tenant WFQ state (inert when config.tenants is None):
         # start-time fair queuing over per-tenant virtual finish times.
         # _vtime is the system virtual clock (last admission's virtual
@@ -830,8 +837,11 @@ class Scheduler:
         mark = self.config.cache_high_watermark
         thr = self.config.prefill_chunk_threshold
         admitted = 0
-        while self.waiting and len(self.running) \
-                < self.config.max_num_seqs:
+        held_by = "none"                     # the queue ran empty
+        while self.waiting:
+            if len(self.running) >= self.config.max_num_seqs:
+                held_by = "rows"
+                break
             req = self.waiting[0] if self.tenants is None \
                 else self._select_waiting()
             tokens = req.all_token_ids()
@@ -867,7 +877,8 @@ class Scheduler:
             # ptlint: disable=PT-C004  admission cost model (see backlog())
             price = cost_model.cost(eff) if cost_model else eff
             if price > budget and admitted:
-                break                        # budget spent; next step
+                held_by = "budget"           # spent; next step
+                break
             needed = self.cache.blocks_needed(eff)
             used = self.cache.num_used() - self.cache.num_evictable()
             if (used + needed) > mark * self.cache.num_blocks \
@@ -878,7 +889,7 @@ class Scheduler:
                 # demand). With nothing running there is nothing to
                 # strand — admit (the head alone may legitimately
                 # exceed the watermark).
-                self.watermark_holds += 1
+                held_by = "watermark"
                 break
             # a cache with window layers: the request's need in the second
             # group (the blocks of its last window, or of its first chunk)
@@ -886,7 +897,7 @@ class Scheduler:
             if (self.cache.window_blocks_needed(0, eff) if chunked
                     else self.cache.window_blocks_needed(len(tokens))) \
                     > self.cache.num_window_free():
-                self.window_holds += 1
+                held_by = "window"
                 break
             if chunked:
                 remaining = max(0, req.params.max_tokens
@@ -901,7 +912,8 @@ class Scheduler:
                 except CacheExhausted:
                     if self.cache.has_seq(req.request_id):
                         self.cache.free(req.request_id)
-                    break                    # never preempt to admit
+                    held_by = "blocks"       # never preempt to admit
+                    break
                 dd = self.cache.tier_demotions - d0
                 if dd:
                     reqtrace.record("demote", req.tid, req.request_id,
@@ -914,6 +926,7 @@ class Scheduler:
                 # rides THIS step's fused decode dispatch: first chunk
                 # of prompt feed goes out alongside the decode slots
                 batch.decode.append(req)
+                batch.chunked += 1
                 if got:
                     bs = self.cache.block_size
                     reqtrace.record(
@@ -930,7 +943,8 @@ class Scheduler:
                 try:
                     self.cache.allocate(req.request_id, len(tokens))
                 except CacheExhausted:
-                    break                    # never preempt to admit
+                    held_by = "blocks"       # never preempt to admit
+                    break
                 dd = self.cache.tier_demotions - d0
                 if dd:
                     reqtrace.record("demote", req.tid, req.request_id,
@@ -946,6 +960,7 @@ class Scheduler:
                     arrival=req.arrival, tokens=len(tokens))
             admitted += 1
             budget -= price
+        batch.held_by = held_by
         return batch
 
     # ------------------------------------------------------------ results

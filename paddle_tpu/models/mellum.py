@@ -380,7 +380,9 @@ def serving_spec(cfg: MellumConfig) -> ModelSpec:
         prefill=lambda params, ids: prefill(params, ids, cfg),
         counters=COUNTERS, config=cfg,
         layer_caches=caches if windowed else (),
-        window=cfg.sliding_window if windowed else 0)
+        window=cfg.sliding_window if windowed else 0,
+        expert_shape=(cfg.held[1], cfg.hidden_size,
+                      cfg.moe_intermediate_size))
 
 
 # ------------------------------------------------------------ the Layer

@@ -75,9 +75,11 @@ from .spec import ModelSpec, merge_counts
 __all__ = ["PanguMoEConfig", "PanguMoE", "param_shapes", "forward",
            "prefill", "serving_spec", "mla_expanded", "mla_absorbed"]
 
-#: the counts an expert layer returns (ModelSpec.counters)
+#: the counts an expert layer returns (ModelSpec.counters), as
+#: `distributed.moe.held_experts_mlp` stacks them
 COUNTERS = ("moe_pairs", "moe_experts_hit", "moe_full_buffer_layers",
-            "moe_batched_layers", "moe_max_load")
+            "moe_batched_layers", "moe_layer_calls", "moe_fit_2x",
+            "moe_fit_4x", "moe_max_load")
 
 
 @dataclass(frozen=True)
@@ -374,7 +376,10 @@ def serving_spec(cfg: PanguMoEConfig) -> ModelSpec:
         decode_layer=functools.partial(_decode_layer, cfg),
         head=functools.partial(_decode_head, cfg),
         prefill=lambda params, ids: prefill(params, ids, cfg),
-        counters=COUNTERS, config=cfg)
+        counters=COUNTERS, config=cfg,
+        expert_shape=(cfg.held[1], cfg.hidden_size,
+                      cfg.moe_intermediate_size)
+        if cfg.num_hidden_layers > cfg.first_k_dense_replace else ())
 
 
 # ------------------------------------------------------------ the Layer

@@ -657,7 +657,9 @@ def serving_spec(cfg: Qwen3NextConfig) -> ModelSpec:
         layer_caches=tuple(
             "rows" if cfg.is_full_attention(i) else "state"
             for i in range(cfg.num_hidden_layers)),
-        state_shapes=cfg.state_shapes)
+        state_shapes=cfg.state_shapes,
+        expert_shape=(cfg.held[1], cfg.hidden_size,
+                      cfg.moe_intermediate_size))
 
 
 # ------------------------------------------------------------ the Layer

@@ -107,6 +107,12 @@ class ModelSpec:
     #: positions a "window" layer attends to, the query's own included
     #: (0 where no layer has a window)
     window: int = 0
+    #: a family with expert layers: the shape of one of an expert layer's
+    #: stacked weights here, (held experts, hidden, expert width); the
+    #: engine puts it on `serving.prefill` as `moe_shape` ("64x2304x896"),
+    #: and a trace's reader finds a prompt's expert products by it (() = no
+    #: expert layer)
+    expert_shape: Tuple[int, ...] = ()
 
     @property
     def pools_per_layer(self) -> int:

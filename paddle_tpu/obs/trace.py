@@ -112,7 +112,9 @@ class Span:
     clean name (`serving.decode` with stat `num_seqs`), the chrome
     trace the whole dict. `span.args` set inside the scope is read at
     end() and reaches the chrome trace only; `span.set_stats(**kw)` inside
-    the scope reaches both. `annotate=False` skips the
+    the scope reaches both. A string value must hold no comma: the
+    profiler's encoding of an annotation's stats cuts it there ("4,32,16"
+    arrives as 4). `annotate=False` skips the
     jax.profiler.TraceAnnotation for spans that must stay jax-free.
     """
 

@@ -235,7 +235,8 @@ def test_every_new_metric_has_its_reader_and_its_entry():
     listed = {m["name"]: m for m in manifest["per_layer"]}
     readers = {p.stem for p in (BENCH / "layer_metrics").glob("*.py")
                if "spans" in p.read_text()}
-    assert len(readers) == 11 and readers <= set(listed)
+    # PR 27's 11 and whatever later PRs add beside them
+    assert len(readers) >= 11 and readers <= set(listed)
     for name in readers:
         assert listed[name]["source"] in ("device_trace", "program_span")
         # one cell when PR 27 wrote them; later cells append their names

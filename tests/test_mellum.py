@@ -564,9 +564,9 @@ def test_the_chunks_upload_carries_the_window_table_and_its_first_block():
     flat_in = jax.tree_util.tree_leaves(lowered.in_avals)
     assert len(flat_in) == len(params) + 4 * 2 + 1
     out = jax.tree_util.tree_leaves(lowered.out_info)
-    # the spec's five counts as rows (four before PR 36's
-    # `moe_batched_layers`)
-    assert len(out) == 1 + 8 and out[0].shape == (8 + 2 + 5, 2)
+    # the spec's eight counts as rows (five before PR 37's
+    # `moe_layer_calls`, `moe_fit_2x`, `moe_fit_4x`)
+    assert len(out) == 1 + 8 and out[0].shape == (8 + 2 + 8, 2)
     # every pool of both groups is donated: it aliases its output
     assert lowered.as_text().count("tf.aliasing_output") == 8
 

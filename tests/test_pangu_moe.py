@@ -239,7 +239,11 @@ def test_every_form_of_the_expert_layer_equals_a_per_token_loop(
     assert dict(zip(pm.COUNTERS, np.asarray(counts))) == {
         "moe_pairs": load.sum(), "moe_experts_hit": (load > 0).sum(),
         "moe_full_buffer_layers": form == "full",
-        "moe_batched_layers": form == "batched", "moe_max_load": load.max()}
+        "moe_batched_layers": form == "batched", "moe_layer_calls": 1,
+        # PR 37: the uniform load is 512 / 64 = 8 rows: twice it is one
+        # sublane tile of 16, four times it C = 32
+        "moe_fit_2x": load.max() <= 16, "moe_fit_4x": load.max() <= 32,
+        "moe_max_load": load.max()}
     assert form == ("batched" if 0 < load.max() <= 32 else
                     "full" if load.sum() > 128 else "compact")
     if both is not None and live is None:
@@ -517,7 +521,8 @@ def test_the_chunk_returns_its_counts_as_extra_rows():
                                 k)
     out = np.asarray(out)
     assert out.shape == (k + 2 + len(pm.COUNTERS), n)
-    pairs, hit, full, batched, load = out[k + 2:, 0]
+    pairs, hit, full, batched, calls, fit2, fit4, load = out[k + 2:, 0]
+    assert calls == fit2 == fit4 == k * 2       # PR 37: a call a layer, trip
     # one live row, 2 expert layers, top-2 with every expert held: 2 pairs
     # a layer and trip on 2 experts, and the dead row routes nowhere
     assert pairs == k * 2 * 2 and hit == pairs and load == 1

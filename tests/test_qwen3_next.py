@@ -96,10 +96,13 @@ def test_the_expert_layer_in_token_blocks_counts_what_the_whole_counts(
     monkeypatch.setattr(qn, "MOE_TOKEN_BLOCK", 8)
     blocks = jax.jit(lambda p, i: qn._dense_layers(p, i, cfg))(params, ids)
     np.testing.assert_allclose(blocks[0], whole[0], atol=LIMIT, rtol=0)
-    pairs, hit, full, batched, load = (
+    pairs, hit, full, batched, calls, fit2, fit4, load = (
         np.asarray(c) for c in zip(whole[2], blocks[2]))
     assert pairs[0] == pairs[1] > 0             # every pair, exactly once
     assert hit[1] >= hit[0] and load[1] <= load[0]      # per block
+    # PR 37: a call a layer, and in blocks of 8 tokens ceil(29 / 8) a layer
+    assert list(calls) == [cfg.num_hidden_layers, 4 * cfg.num_hidden_layers]
+    assert (fit2 <= fit4).all() and (fit4 <= calls).all()
 
 
 def test_the_layers_alternate_as_the_interval_says():

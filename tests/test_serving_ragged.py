@@ -196,15 +196,26 @@ def test_one_compilation_covers_all_batch_mixes(model):
     assert drive("bucketed") == 3  # one per power-of-two bucket walked
 
 
-def test_padding_waste_gauge(model):
-    """3 live rows the whole run: ragged reports 0.0 (fixed width, dead
-    rows free), bucketed reports (4-3)/4 from its power-of-two pad."""
+def test_the_decode_span_says_how_wide_the_program_ran(model):
+    """3 live rows the whole run: the ragged kernel's chunk runs at the
+    one fixed `max_num_seqs` width, 8 (dead rows cost no kernel work), the
+    bucketed fallback at its power-of-two pad, 4. `rows` beside `num_seqs`
+    on `serving.decode` says so (PR 37: it took the place of the gauge
+    `serving_padding_waste_ratio`, the same quotient of the last chunk)."""
+    from paddle_tpu import obs
     prompts = [np.arange(1, 4, dtype=np.int32)] * 3
     samp = [SamplingParams(max_tokens=6)] * 3
-    eng_r, _, _ = _run_engine(model, prompts, samp, kernel="ragged")
-    eng_b, _, _ = _run_engine(model, prompts, samp, kernel="bucketed")
-    assert eng_r.stats.padding_waste() == 0.0
-    assert eng_b.stats.padding_waste() == pytest.approx(0.25)
+    widths = {}
+    for kernel in ("ragged", "bucketed"):
+        obs.trace.enable()
+        try:
+            _run_engine(model, prompts, samp, kernel=kernel, max_num_seqs=8)
+            widths[kernel] = {
+                (e.args["num_seqs"], e.args["rows"], e.args["feeding_rows"])
+                for e in obs.trace.events() if e.name == "serving.decode"}
+        finally:
+            obs.trace.disable()
+    assert widths == {"ragged": {(3, 8, 0)}, "bucketed": {(3, 4, 0)}}
 
 
 # ------------------------------------------------------ chunked prefill

@@ -140,7 +140,7 @@ def test_cache_high_watermark_pauses_admission(model):
     # head admitted (nothing was running), second held by the watermark
     assert eng.get_request(a).state == RequestState.RUNNING
     assert eng.get_request(b).state == RequestState.WAITING
-    assert eng.scheduler.watermark_holds >= 1
+    assert eng.stats.admission_holds("watermark") >= 1
     eng.run()
     assert eng.get_request(b).finished
     eng.cache.check_integrity()

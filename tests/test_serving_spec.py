@@ -153,10 +153,10 @@ def test_the_other_families_chunk_keeps_its_name_and_argument_shapes(family):
     assert "module @jit_fused_decode_chunk " in lowered.as_text()
     flat_in = jax.tree_util.tree_leaves(lowered.in_avals)
     assert len(flat_in) == len(params) + leaves + 1
-    # the result and the pools back, and the spec's five counts as rows
-    # (four before PR 36's `moe_batched_layers`)
+    # the result and the pools back, and the spec's eight counts as rows
+    # (five before PR 37's `moe_layer_calls`, `moe_fit_2x`, `moe_fit_4x`)
     out = jax.tree_util.tree_leaves(lowered.out_info)
-    assert len(out) == 1 + leaves and out[0].shape == (8 + 2 + 5, 2)
+    assert len(out) == 1 + leaves and out[0].shape == (8 + 2 + 8, 2)
     # every pool leaf is donated: it aliases its output
     assert lowered.as_text().count("tf.aliasing_output") == leaves
 
@@ -195,13 +195,16 @@ def test_a_span_takes_stats_from_inside_its_scope(tmp_path):
 # PR 36 changed `latent.*` and `hybrid.*` on purpose (values from its own
 # tree): `held_experts_mlp` has a batched form under its `switch` and a
 # fifth count, `moe_batched_layers`, so every program with an expert layer
-# differs; `gpt2.*` has none and stays the parent of PR 35's.
+# differs; `gpt2.*` has none and stays the parent of PR 35's. PR 37 changed
+# `latent.*` and `hybrid.*` again on purpose (values from its own tree):
+# three counts more (`moe_layer_calls`, `moe_fit_2x`, `moe_fit_4x`), two
+# compares a call; `gpt2.*` is still the parent of PR 35's.
 PARENT_OF_PR_35 = {
     "gpt2.chunk": "03d3b78e69ba813e", "gpt2.prefill": "094638537d5db785",
-    "gpt2.scatter": "337b6f8e98105a15", "latent.chunk": "a5d765351c1d7f5f",
-    "latent.prefill": "c951147fd08edc7c", "hybrid.chunk": "5596eed5ee34f4ef",
-    "hybrid.prefill": "bf86f78284065a6f",
-    "hybrid.prefill.blocked": "c7c7f992d27141ec",
+    "gpt2.scatter": "337b6f8e98105a15", "latent.chunk": "5cacecb5e963bce6",
+    "latent.prefill": "f571d1216580742f", "hybrid.chunk": "61ed64b9cf040867",
+    "hybrid.prefill": "c6e869e72c58d8eb",
+    "hybrid.prefill.blocked": "cfd9028b55502e65",
 }
 
 

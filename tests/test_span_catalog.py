@@ -124,9 +124,11 @@ def test_decode_span_counts_the_positions_its_rows_attend_to(engine_trace):
     by_hand = sum(range(6, 11)) + sum(range(4, 12))
     # and the live rows summed over the trips: 5 + 8
     # both requests are greedy: no row of the chunk samples
+    # PR 37: the program's row width (`max_num_seqs` under the ragged
+    # kernel) and the rows that fed prompt tokens, from inside the scope
     assert decode[3] == {"num_seqs": 2, "chunk": 8,
                          "context_tokens": by_hand, "live_row_trips": 13,
-                         "sampled_rows": 0}
+                         "sampled_rows": 0, "rows": 4, "feeding_rows": 0}
     steps = [s[3]["step"] for s in engine_trace
              if s[0] == "serving.engine_step"]
     assert steps == [1, 2]
