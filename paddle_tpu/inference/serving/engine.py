@@ -203,9 +203,9 @@ _STAT_EVENTS = ("steps", "prefill_tokens", "generated_tokens",
                 # calls of the expert layer, and those whose largest load
                 # was at most two / four times the uniform one
                 "moe_layer_calls", "moe_fit_2x", "moe_fit_4x",
-                # the `context_tokens` and `live_row_trips` stats of
-                # serving.decode, summed
-                "context_tokens", "live_row_trips",
+                # the `context_tokens`, `live_row_trips` and `live_blocks`
+                # stats of serving.decode, summed
+                "context_tokens", "live_row_trips", "live_blocks",
                 # a spec with window layers: the `window_context_tokens`
                 # stat of serving.decode summed, and the window blocks the
                 # drains gave back (`PagedKVCache.release_behind`)
@@ -641,6 +641,18 @@ def _context_tokens(reqs, k: int) -> int:
         trips = _live_trips(req, k)
         total += trips * req.slot[2] + trips * (trips + 1) // 2
     return total
+
+
+def _live_blocks(reqs, k: int, block_size: int) -> int:
+    """Table entries a k-trip chunk attends through, summed over rows and
+    trips: cdiv(p + j + 1, block_size) at trip j, the blocks that hold the
+    p + j + 1 positions `_context_tokens` counts (what the ragged kernel
+    visits of `num_seqs x max_blocks_per_seq x k` entries)."""
+    def upto(n):    # sum of cdiv(x, block_size) over x = 1 .. n
+        whole, rest = divmod(n, block_size)
+        return block_size * whole * (whole + 1) // 2 + rest * (whole + 1)
+    return sum(upto(req.slot[2] + _live_trips(req, k)) - upto(req.slot[2])
+               for req in reqs)
 
 
 def _window_context_tokens(reqs, k: int, window: int) -> int:
@@ -1503,8 +1515,10 @@ class LLMEngine:
                 k = self.config.decode_chunk_size
                 context = _context_tokens(decode, k)
                 row_trips = sum(_live_trips(r, k) for r in decode)
+                blocks = _live_blocks(decode, k, self.config.block_size)
                 self.stats.context_tokens += context
                 self.stats.live_row_trips += row_trips
+                self.stats.live_blocks += blocks
                 in_window = {}
                 if self.spec.window:
                     in_window["window_context_tokens"] = \
@@ -1518,7 +1532,7 @@ class LLMEngine:
                 with RecordEvent("serving.decode", cat="decode", args={
                         "num_seqs": len(decode), "chunk": k,
                         "context_tokens": context,
-                        "live_row_trips": row_trips,
+                        "live_row_trips": row_trips, "live_blocks": blocks,
                         "sampled_rows": sampled_rows, **in_window}) as ev:
                     # ptlint: disable=PT-C004  fault injector: stalls ON
                     # PURPOSE under the lock to exercise the watchdog

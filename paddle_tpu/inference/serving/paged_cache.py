@@ -162,9 +162,13 @@ def physical_shape(cache_shape):
     program that indexes the pool by block copies the whole pool out of
     that layout and back (PERF.md section 6, PR 32). So:
 
-    - heads (H, D), D a divisor of 128 below it and H * D a multiple of
-      8 x 128 (GPT-2 medium: 16 x 64): (H * D // 128, 128), that is
-      128 // D heads side by side on the lanes;
+    - heads (H, D), D a divisor of 128 below it and H * D whole lane rows,
+      more than half a sublane tile of them (GPT-2 medium: 16 x 64 -> 8
+      rows, the whole tile that keeps the pool block-major; GPT-2 small:
+      12 x 64 -> 6 rows, padded to 8 where [12, 64] is padded to [16,
+      128]): (H * D // 128, 128), that is 128 // D heads side by side on
+      the lanes. Whole lane rows are also what the ragged kernel's block
+      copies need (ops/pallas/ragged_paged_attention.supported);
     - latent (W,), W at least a lane row: W rounded up to 128 lanes
       (576 -> 640), the padding zero and never read;
     - heads (H, D), D whole lane rows and H at most half a sublane tile
@@ -176,16 +180,16 @@ def physical_shape(cache_shape):
       then (block_size, H * D), as the latent layout's; the ragged kernel,
       whose tile is a four-dimensional stored block, does not read such a
       pool: `generation.decode_layer` gathers it;
-    - anything else (12 heads x 64, the toy sizes of the CPU tests, 8 heads
-      of 128): the logical shape. Such a pool keeps whatever copies the
-      compiler makes for it."""
+    - anything else (the toy sizes of the CPU tests, 6 or 8 heads of 128):
+      the logical shape. A pool whose two minor dimensions fill no whole
+      tile keeps whatever copies the compiler makes for it."""
     cache_shape = tuple(cache_shape)
     if len(cache_shape) == 1:
         (w,) = cache_shape
         return (-(-w // _LANES) * _LANES,) if w > _LANES else cache_shape
     h, d = cache_shape
-    if d < _LANES and _LANES % d == 0 \
-            and (h * d) % (_SUBLANES * _LANES) == 0:
+    if d < _LANES and _LANES % d == 0 and (h * d) % _LANES == 0 \
+            and 2 * (h * d // _LANES) > _SUBLANES:
         return (h * d // _LANES, _LANES)
     if d % _LANES == 0 and 2 * h <= _SUBLANES:
         return (h * d,)

@@ -261,7 +261,7 @@ def serving_spec(geom) -> ModelSpec:
         # ONE row of H * D lanes a position (`physical_shape`: at most 4
         # heads of whole lane rows) has no such tile and takes the gather
         if ragged and kp.ndim == 4 \
-                and _ragged.route_gate(head_dim, num_heads, kp.shape[1]):
+                and _ragged.route_gate(head_dim, num_heads, kp.shape[1:]):
             att = _ragged.ragged_decode_attention(
                 qkv[0][:, :, 0, :], kp, vp, tables, att_lens)
             x = _attn_merge(params, i, x, att[:, :, None, :], geom)

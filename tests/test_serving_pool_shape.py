@@ -22,7 +22,7 @@ from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
     ((16, 64), jnp.float32, (8, 128)),     # GPT-2 medium: two heads a row
     ((16, 64), jnp.bfloat16, (8, 128)),
     ((8, 128), jnp.float32, (8, 128)),     # already whole tiles
-    ((12, 64), jnp.float32, (12, 64)),     # 6 lane rows: no whole tile
+    ((12, 64), jnp.float32, (6, 128)),     # 6 lane rows: two heads a row
     ((6, 128), jnp.float32, (6, 128)),
     ((2, 16), jnp.float32, (2, 16)),       # the toy sizes of the CPU tests
     ((2, 256), jnp.bfloat16, (512,)),      # 2 KV heads of 256: one row of
@@ -166,6 +166,24 @@ def test_the_kernel_reads_the_packed_pool_in_place(sentinel):
                                    rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("block, dtype, table, group", [
+    ((32, 8, 128), jnp.float32, 32, 4),    # the GPT-2 cells: 128 KiB a block
+    ((32, 6, 128), jnp.float32, 32, 4),    # 6 rows fill a tile of 8
+    ((32, 8, 128), jnp.bfloat16, 32, 4),   # half the bytes, a tile of 16 rows
+    ((32, 12, 64), jnp.float32, 32, 2),    # [12, 64] fills [16, 128]
+    ((8, 8, 128), jnp.float32, 32, 16),
+    ((8, 8, 128), jnp.float32, 5, 5),      # never more than a row's table
+    ((64, 32, 128), jnp.float32, 32, 1),   # a block past the budget: one
+    ((4, 4, 8), jnp.float32, 5, 5),        # the toy sizes of the CPU tests
+])
+def test_the_blocks_of_a_dma_group_are_decided_from_the_stored_block(
+        block, dtype, table, group):
+    """`blocks_per_group`: as many stored blocks as half a MiB of VMEM
+    holds, the block's two minor dimensions padded to the dtype's tile; no
+    option, no field of `EngineConfig`."""
+    assert rpa.blocks_per_group(block, dtype, table) == group
+
+
 def test_a_pool_that_holds_no_such_heads_is_refused():
     q = jnp.zeros((2, 16, 64), jnp.float32)
     pool = jnp.zeros((4, 8, 12, 64), jnp.float32)
@@ -231,6 +249,7 @@ def test_an_engine_serves_generate_s_tokens_on_pools_as_stored(
     (2, 256, (512,), False),
     (8, 128, (8, 128), True),      # stored as it is: the kernel's tile
     (16, 64, (8, 128), True),      # packed: the kernel's tile
+    (12, 64, (6, 128), True),      # packed, six lane rows (GPT-2 small)
 ])
 def test_the_decode_layer_under_the_ragged_route_reads_every_stored_shape(
         monkeypatch, heads, head_dim, stored, through_kernel):

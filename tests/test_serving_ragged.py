@@ -19,6 +19,8 @@ The load-bearing pins:
   size, with EOS-mid-chunk, preemption/requeue and chaos recovery
   holding the zero-leak / zero-lost / survivor-bitwise contracts.
 """
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -77,15 +79,20 @@ def _random_paged(seed, n, nb, bs, h, d):
     v_pool = rng.randn(nb, bs, h, d).astype(np.float32)
     q = rng.randn(n, h, d).astype(np.float32)
     lengths = np.array([0, 1, bs * mb - 1, 7][:n], np.int32)
-    perm = rng.permutation(nb)
-    tables = np.full((n, mb), -1, np.int32)
-    used = 0
-    for i in range(n):
-        need = -(-int(lengths[i]) // bs)
-        tables[i, :need] = perm[used:used + need]
-        used += need
+    tables = _block_tables(rng, lengths, nb, bs, mb)
     return (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
             jnp.asarray(tables), jnp.asarray(lengths))
+
+
+def _block_tables(rng, lengths, nb, bs, mb, sentinel=-1):
+    """[N, mb] tables: each row's live blocks drawn from a permutation of
+    the pool, `sentinel` in every entry past them."""
+    tables = np.full((len(lengths), mb), sentinel, np.int32)
+    perm, used = rng.permutation(nb), 0
+    for i, n in enumerate(-(-np.asarray(lengths) // bs)):
+        tables[i, :n] = perm[used:used + n]
+        used += n
+    return tables
 
 
 def _dense_oracle(q, k_pool, v_pool, tables, lengths):
@@ -122,7 +129,168 @@ def test_kernel_interpret_bitwise_matches_reference():
     assert np.all(got[0] == 0.0)          # lengths[0] == 0: dead row
 
 
+# the serving tile: blocks of 32 positions whose stored block pads to 128
+# KiB of VMEM, so a DMA group is 4 blocks and a table of 9 holds two whole
+# groups and one more block
+_BS, _MB, _NB = 32, 9, 24
+_ROW_LENGTHS = {
+    "dead": 0, "one-position": 1, "a-block": _BS, "a-block-and-one": _BS + 1,
+    "a-whole-group": 4 * _BS, "one-more-than-a-group": 4 * _BS + 1,
+    "not-a-multiple-of-the-group": 7 * _BS - 5, "two-groups": 8 * _BS,
+    "the-whole-table": _MB * _BS,
+}
+
+
+@pytest.mark.parametrize("sentinel", ["minus-one", "num_blocks"])
+@pytest.mark.parametrize("stored", ["logical", "packed"])
+@pytest.mark.parametrize("length", list(_ROW_LENGTHS.values()),
+                         ids=list(_ROW_LENGTHS))
+def test_kernel_walks_a_rows_live_blocks(length, stored, sentinel):
+    """The row under test between two live rows of 3 and 6 blocks (for
+    length 0: a dead row between two live ones, whose first group the row
+    before it starts): what a row leaves in the two buffer slots and on
+    their semaphores must not reach the next. Table entries past a row's
+    live blocks hold a sentinel that no copy may dereference (the pool has
+    no such block). Logical [4, 16] pools: bitwise the lax.scan reference;
+    16 x 64 packed as [8, 128]: inside float32 rounding of it; both against
+    the dense softmax oracle."""
+    heads, head_dim = (4, 16) if stored == "logical" else (16, 64)
+    tile = (heads, head_dim) if stored == "logical" else (8, 128)
+    assert rpa.blocks_per_group((_BS,) + tile, np.float32, _MB) == 4
+    rng = np.random.RandomState(length)
+    lengths = np.array([2 * _BS + 5, length, 5 * _BS + 3], np.int32)
+    k, v = (rng.randn(_NB, _BS, heads, head_dim).astype(np.float32)
+            for _ in range(2))
+    q = jnp.asarray(rng.randn(3, heads, head_dim).astype(np.float32))
+    tables = _block_tables(rng, lengths, _NB, _BS, _MB,
+                           -1 if sentinel == "minus-one" else _NB)
+    args = (q, jnp.asarray(k).reshape((_NB, _BS) + tile),
+            jnp.asarray(v).reshape((_NB, _BS) + tile), jnp.asarray(tables),
+            jnp.asarray(lengths))
+    got = np.asarray(rpa.ragged_decode_attention(*args, interpret=True))
+    ref = np.asarray(rpa.ragged_attention_reference(*args))
+    if stored == "logical":
+        np.testing.assert_array_equal(got, ref)
+    else:
+        np.testing.assert_allclose(got, ref, rtol=2e-6, atol=2e-6)
+    oracle = _dense_oracle(q, k, v, np.where(tables == _NB, -1, tables),
+                           lengths)
+    np.testing.assert_allclose(got, oracle, rtol=2e-6, atol=2e-6)
+    assert length or np.all(got[1] == 0.0)
+
+
+@pytest.mark.parametrize("lengths", [
+    (3 * _BS + 1, 0, 6 * _BS), (_MB * _BS, 1, 0), (0, 0, 5 * _BS - 1)],
+    ids=["dead-between", "whole-table-then-one", "dead-rows-first"])
+def test_kernel_waits_for_the_copies_it_reads(lengths):
+    """Under the TPU interpreter with every DMA carried out only when it is
+    waited on (`interpret=True` copies at the start): a block multiplied
+    before its copy was waited on, or a wait on the wrong slot's semaphore,
+    reads what the buffer held before and shows here."""
+    from jax.experimental.pallas import tpu as pltpu
+    rng = np.random.RandomState(7)
+    lengths = np.asarray(lengths, np.int32)
+    k, v = (jnp.asarray(rng.randn(_NB, _BS, 8, 128).astype(np.float32))
+            for _ in range(2))
+    q = jnp.asarray(rng.randn(3, 16, 64).astype(np.float32))
+    tables = _block_tables(rng, lengths, _NB, _BS, _MB, _NB)
+    args = (q, k, v, jnp.asarray(tables), jnp.asarray(lengths))
+    got = np.asarray(rpa.ragged_decode_attention(
+        *args, interpret=pltpu.InterpretParams(dma_execution_mode="on_wait")))
+    np.testing.assert_allclose(
+        got, np.asarray(rpa.ragged_attention_reference(*args)),
+        rtol=2e-6, atol=2e-6)
+    assert np.all(got[lengths == 0] == 0.0)
+
+
+def test_one_row_alone_walks_its_blocks():
+    """A batch of one: no row before it starts its first group and none
+    after it takes a hand-over; seven blocks are a whole group and one that
+    is not full."""
+    rng = np.random.RandomState(3)
+    lengths = np.array([7 * _BS - 2], np.int32)
+    k, v = (jnp.asarray(rng.randn(_NB, _BS, 4, 16).astype(np.float32))
+            for _ in range(2))
+    q = jnp.asarray(rng.randn(1, 4, 16).astype(np.float32))
+    args = (q, k, v, jnp.asarray(_block_tables(rng, lengths, _NB, _BS, _MB)),
+            jnp.asarray(lengths))
+    np.testing.assert_array_equal(
+        np.asarray(rpa.ragged_decode_attention(*args, interpret=True)),
+        np.asarray(rpa.ragged_attention_reference(*args)))
+
+
+def _equations(jaxpr) -> int:
+    """Equations of a jaxpr and of every jaxpr inside it (loop and branch
+    bodies, the kernel under its `pallas_call`)."""
+    def inner(v):
+        if hasattr(v, "eqns"):
+            yield v
+        elif hasattr(v, "jaxpr"):
+            yield v.jaxpr
+        elif isinstance(v, (tuple, list)):
+            for x in v:
+                yield from inner(x)
+    return sum(1 + sum(_equations(j) for v in e.params.values()
+                       for j in inner(v)) for e in jaxpr.eqns)
+
+
+def test_the_kernels_program_does_not_grow_with_the_group():
+    """The set-up budget's guard that needs no chip (PERF.md section 6, PR
+    39): a group's blocks are loops the kernel runs, so the program traced
+    at a group of 4 blocks is as long as at a group of 1. Unrolled in
+    Python it was four times as long in the part that repeats, and every
+    GPT-2 serving cell's set-up paid the host time of tracing it."""
+    def traced(table_len, group):
+        block = (_BS, 8, 128)
+        assert rpa.blocks_per_group(block, np.float32, table_len) == group
+        pool = jax.ShapeDtypeStruct((_NB,) + block, jnp.float32)
+        return _equations(jax.make_jaxpr(
+            functools.partial(rpa.ragged_decode_attention, interpret=True))(
+            jax.ShapeDtypeStruct((3, 16, 64), jnp.float32), pool, pool,
+            jax.ShapeDtypeStruct((3, table_len), jnp.int32),
+            jax.ShapeDtypeStruct((3,), jnp.int32)).jaxpr)
+    one, four = traced(1, 1), traced(_MB, 4)
+    assert one > 100                    # the kernel's body was counted
+    assert abs(four - one) <= 4
+
+
 # ------------------------------------------------------- engine parity
+def test_live_blocks_sum_to_the_entries_the_tables_hold(model, monkeypatch):
+    """Chunks of ONE trip: a row's table then holds exactly the blocks of
+    the positions that trip attends to, so `live_blocks` of every
+    `serving.decode` span is the table entries packed into its upload, and
+    `EngineStats.live_blocks` their sum over the run."""
+    from paddle_tpu import obs
+    from paddle_tpu.inference.serving import engine as engine_mod
+    eng = _engine(model, decode_chunk_size=1)
+    table_of, chunk = eng.cache.block_table, engine_mod.fused_decode_chunk
+    asked, uploaded = [], []
+
+    def recording_table(rid):
+        asked.append(len(table_of(rid)))
+        return table_of(rid)
+
+    def recording_chunk(params, pools, packed, *rest):
+        live = int(np.asarray(packed)[:, 2].sum())
+        uploaded.append(sum(asked[-live:]))     # the pack's own calls
+        return chunk(params, pools, packed, *rest)
+
+    monkeypatch.setattr(eng.cache, "block_table", recording_table)
+    monkeypatch.setattr(engine_mod, "fused_decode_chunk", recording_chunk)
+    obs.trace.enable()
+    try:
+        for n, new in ((3, 9), (7, 5), (4, 12)):    # across block edges
+            eng.add_request(np.arange(1, n + 1, dtype=np.int32),
+                            SamplingParams(max_tokens=new))
+        eng.run(max_steps=100)
+        spans = [e.args["live_blocks"] for e in obs.trace.events()
+                 if e.name == "serving.decode"]
+    finally:
+        obs.trace.disable()
+    assert spans == uploaded and len(spans) > 10
+    assert eng.stats.live_blocks == sum(uploaded)
+
+
 def test_greedy_ragged_bucketed_dense_bitwise(model):
     """THE tentpole pin: kernel='ragged' output == kernel='bucketed'
     output == dense generate(), token-exact, on a mixed-length

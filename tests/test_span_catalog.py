@@ -126,24 +126,48 @@ def test_decode_span_counts_the_positions_its_rows_attend_to(engine_trace):
     # both requests are greedy: no row of the chunk samples
     # PR 37: the program's row width (`max_num_seqs` under the ragged
     # kernel) and the rows that fed prompt tokens, from inside the scope
+    # PR 39: the table entries those positions lie in, blocks of 4:
+    # ceil(6/4) .. ceil(10/4) and ceil(4/4) .. ceil(11/4)
+    blocks_by_hand = sum(-(-n // 4) for n in (*range(6, 11), *range(4, 12)))
     assert decode[3] == {"num_seqs": 2, "chunk": 8,
                          "context_tokens": by_hand, "live_row_trips": 13,
+                         "live_blocks": blocks_by_hand,
                          "sampled_rows": 0, "rows": 4, "feeding_rows": 0}
     steps = [s[3]["step"] for s in engine_trace
              if s[0] == "serving.engine_step"]
     assert steps == [1, 2]
 
 
+def _chunk_row(pf_target, prefill_pos, out, max_tokens, pos):
+    from paddle_tpu.inference.serving.scheduler import Request
+    r = Request("r", np.zeros(pf_target or 1, np.int32),
+                SamplingParams(max_tokens=max_tokens))
+    r.pf_target, r.prefill_pos = pf_target, prefill_pos
+    r.output_ids, r.slot = [0] * out, (0, 0, pos)
+    return r
+
+
+@pytest.mark.parametrize("block_size", [1, 4, 32])
+@pytest.mark.parametrize("row", [
+    (32, 12, 0, 4, 12),     # all 8 trips eat prompt
+    (32, 29, 0, 2, 29),     # 3 prompt trips, one decode trip
+    (0, 0, 4, 5, 9),        # plain decode, one token left
+    (0, 0, 0, 64, 31),      # a block boundary inside the chunk
+    (0, 0, 0, 64, 0),       # from the first position
+], ids=["feeding", "feed-then-decode", "one-trip", "boundary", "start"])
+def test_live_blocks_are_the_table_entries_the_positions_lie_in(row,
+                                                                block_size):
+    from paddle_tpu.inference.serving.engine import (_live_blocks,
+                                                     _live_trips)
+    req = _chunk_row(*row)
+    by_hand = sum(-(-(req.slot[2] + j + 1) // block_size)
+                  for j in range(_live_trips(req, 8)))
+    assert _live_blocks([req, req], 8, block_size) == 2 * by_hand
+
+
 def test_context_tokens_of_a_row_still_in_chunked_prefill():
     from paddle_tpu.inference.serving.engine import _context_tokens
-    from paddle_tpu.inference.serving.scheduler import Request
-
-    def row(pf_target, prefill_pos, out, max_tokens, pos):
-        r = Request("r", np.zeros(pf_target or 1, np.int32),
-                    SamplingParams(max_tokens=max_tokens))
-        r.pf_target, r.prefill_pos = pf_target, prefill_pos
-        r.output_ids, r.slot = [0] * out, (0, 0, pos)
-        return r
+    row = _chunk_row
     # 20 prompt tokens left at position 12: all 8 trips eat prompt
     assert _context_tokens([row(32, 12, 0, 4, 12)], 8) == sum(range(13, 21))
     # 3 left at 29, 2 tokens wanted: trips at 29, 30, 31 (the third

@@ -4,8 +4,8 @@ The kernels of the two main paths, compiled at the real widths of the
 flagship GPT for a described (not attached) v5e:2x2: the flash forward
 and backward of the 6-head flagship, the packed-pair kernels of the
 12-head one at both backward branches, the ragged decode kernel at three
-head geometries (one of them packed two heads a lane row, as the cache
-stores it), the flash kernel per shard under a 2x2 mesh, and the serving
+head geometries (two of them packed two heads a lane row, as the cache
+stores them) and at the largest blocks its gate admits, the flash kernel per shard under a 2x2 mesh, and the serving
 cells' decode chunk and dense-admission scatter with their pools donated
 and no pool copied. Interpret
 mode accepts what Mosaic refuses (an unaligned slice, a batched dot with no
@@ -184,6 +184,40 @@ def test_ragged_decode_compiles(one_chip, heads, head_dim):
     assert _kernel_calls(
         ragged_decode_attention, sds((8, heads, head_dim), jnp.float32),
         pool, pool, sds((8, 32), jnp.int32), sds((8,), jnp.int32)) == 1
+
+
+# the serving tile, and the largest stored blocks the kernel's gate admits
+# (1 MiB of VMEM as float32: tall, wide, and packed two heads a lane row)
+@pytest.mark.parametrize("block_size,heads,head_dim,group", [
+    (32, 16, 64, 4), (64, 32, 128, 1), (256, 8, 128, 1), (256, 16, 64, 1)])
+def test_ragged_decode_scratch_stays_inside_scoped_vmem(
+        one_chip, monkeypatch, block_size, heads, head_dim, group):
+    """What the kernel holds in VMEM for its copies (k and v, two slots, a
+    group of blocks each) is at most a quarter of the v5e's default scoped
+    limit of 16 MiB at every block the gate admits, the group is what the
+    stored block's bytes say, and the chip's compiler takes the kernel with
+    its block-sized temporaries under that default (no `vmem_limit_bytes`
+    is set). One block-size step past the largest, the gate says no."""
+    from paddle_tpu.inference.serving.paged_cache import physical_shape
+    from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    block = (block_size,) + physical_shape((heads, head_dim))
+    assert rpa.blocks_per_group(block, jnp.float32, 32) == group
+    assert 4 * group * rpa._block_vmem_bytes(block, jnp.float32) \
+        <= 16 * 2 ** 20 // 4
+    pool = sds((64,) + block, jnp.float32)
+    assert _kernel_calls(
+        rpa.ragged_decode_attention, sds((8, heads, head_dim), jnp.float32),
+        pool, pool, sds((8, 32), jnp.int32), sds((8,), jnp.int32)) == 1
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert rpa.supported(head_dim, heads, block)
+    if group == 1:
+        assert not rpa.supported(head_dim, heads,
+                                 (block_size + 8,) + block[1:])
+    # a lane row cut in two has no copy out of HBM, whatever its size
+    assert not rpa.supported(64, 12, (32, 12, 64))
 
 
 def _pool_copies(text: str, num_blocks: int) -> list:
