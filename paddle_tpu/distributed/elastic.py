@@ -15,12 +15,14 @@ restart window is bounded by `save_every_n_steps`.
 
 Hang detection is heartbeat-based: each worker incarnation gets a private
 heartbeat file (env `PADDLE_ELASTIC_HEARTBEAT_FILE`); the training loop
-touches it via `elastic.heartbeat()` (wired into the checkpoint manager's
-step/epoch ranges, so supervised jobs get it for free). A worker whose
-heartbeat goes stale past `heartbeat_timeout` is killed and restarted
+touches it via `elastic.heartbeat()` each time a step ends (wired into the
+checkpoint manager's step/epoch ranges after the step and its save, so
+supervised jobs get it for free). A beat marks a finished step. A worker
+whose heartbeat goes stale past `heartbeat_timeout` is killed and restarted
 through the same backoff path. The timeout is only enforced once the first
-beat lands — startup (imports, first-step compile) can legitimately take
-longer than a steady-state step.
+beat lands, i.e. once the incarnation's first step has ended: start-up
+(imports, restore, the first step's compile) can legitimately take longer
+than a steady-state step and is not judged.
 """
 from __future__ import annotations
 
@@ -44,8 +46,11 @@ MAX_RESTARTS_ENV = "PADDLE_ELASTIC_MAX_RESTARTS"
 def heartbeat():
     """Touch this worker's heartbeat file (no-op outside a supervised run).
 
-    Called once per training step/epoch by AutoCheckpointManager's ranges;
-    long custom loops should call it at least once per `heartbeat_timeout`.
+    A beat marks a finished step: AutoCheckpointManager's ranges call it
+    once each step/epoch has ended (after its save). The supervisor does
+    not judge a worker until its first beat, so the first step (and its
+    compile) counts as start-up. Custom loops should beat at the end of
+    each step, and long steps at least once per `heartbeat_timeout`.
     """
     path = os.environ.get(HEARTBEAT_FILE_ENV)
     if not path:

@@ -372,7 +372,6 @@ class AutoCheckpointManager:
         start = 0 if self.restored_kind != "epoch" else last + 1
         try:
             for epoch in range(start, max_epoch_num):
-                elastic.heartbeat()  # no-op outside a supervised run
                 yield epoch
                 if (epoch + 1) % self.save_interval == 0 \
                         or epoch == max_epoch_num - 1:
@@ -380,6 +379,9 @@ class AutoCheckpointManager:
                         self.save_async(epoch)
                     else:
                         self.save(epoch)
+                # beat AFTER the epoch and its save: a beat marks progress,
+                # so the first (compiling) epoch is start-up, not judged
+                elastic.heartbeat()  # no-op outside a supervised run
         finally:
             # also runs on generator close (caller `break`): the last
             # dispatched snapshot must be durable — the writer thread is a
@@ -391,22 +393,25 @@ class AutoCheckpointManager:
         workers: yields step indices, snapshotting every
         `save_every_n_steps` (and at the final step), and resumes from the
         newest step snapshot after a kill — the restart window is bounded
-        by the save interval instead of an epoch. Each step also beats the
-        elastic heartbeat, so a hung step is detectable by the
-        supervisor."""
+        by the save interval instead of an epoch. Each step beats the
+        elastic heartbeat once it has ended (after its snapshot, if any),
+        so a step that hangs is detectable by the supervisor: the beat
+        marks progress. No beat comes before the first step of an
+        incarnation ends, so the supervisor does not judge import,
+        restore and the first step's compile."""
         from ..distributed import elastic
         every = self.save_every_n_steps or 1
         last = self.restore_latest()
         start = 0 if self.restored_kind != "step" else last + 1
         try:
             for step in range(start, max_steps):
-                elastic.heartbeat()
                 yield step
                 if (step + 1) % every == 0 or step == max_steps - 1:
                     if self.async_save:
                         self.save_step_async(step)
                     else:
                         self.save_step(step)
+                elastic.heartbeat()  # step (and its save) ended
         finally:
             self.wait()
 

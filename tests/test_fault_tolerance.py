@@ -15,7 +15,9 @@ import sys
 import numpy as np
 import pytest
 
-from paddle_tpu.distributed.elastic import (ElasticJobError,
+from paddle_tpu.distributed import elastic
+from paddle_tpu.distributed.elastic import (HEARTBEAT_FILE_ENV,
+                                            ElasticJobError,
                                             ElasticSupervisor, WorkerSpec)
 from paddle_tpu.testing.faults import KILL_EXIT_CODE, FaultInjector
 
@@ -180,6 +182,36 @@ def test_hang_detected_by_heartbeat_and_restarted(tmp_path, baseline):
         np.testing.assert_array_equal(got[k], baseline[k], err_msg=k)
 
 
+def _slow_first_step_worker(ckpt_dir, stall_s):
+    """Spawn-style worker whose first step outlasts the heartbeat timeout,
+    as a first step's compile may on a loaded host; later steps are
+    quick."""
+    import time
+    from paddle_tpu.incubate.checkpoint import AutoCheckpointManager
+    acp = AutoCheckpointManager(ckpt_dir, save_every_n_steps=3)
+    for step in acp.train_step_range(3):
+        if step == 0:
+            time.sleep(stall_s)
+
+
+def test_slow_first_step_is_start_up_not_a_hang(tmp_path):
+    """A beat marks a finished step, so a first step that runs for twice
+    `heartbeat_timeout` is start-up the supervisor does not judge: the
+    worker ends with no restart (with a beat before each step it would be
+    killed as hung in every incarnation)."""
+    # the hang test's timeout: a worker's exit after its last beat takes
+    # 0.3-0.7 s on an 8-core CPU host under load, well inside it
+    timeout = 1.5
+    sup = ElasticSupervisor(max_restarts=0, heartbeat_timeout=timeout,
+                            monitor_interval=0.02,
+                            heartbeat_dir=str(tmp_path / "hb"), seed=0)
+    report = sup.run([WorkerSpec(_slow_first_step_worker,
+                                 args=(str(tmp_path / "ckpt"), 2 * timeout),
+                                 env={"JAX_PLATFORMS": "cpu"})])
+    assert report["restarts"] == {0: 0}
+    assert report["history"] == {0: []}
+
+
 # ---------------------------------------------------------------- unit level
 def test_backoff_is_capped_exponential_with_jitter():
     sup = ElasticSupervisor(backoff_base=0.5, backoff_factor=2.0,
@@ -315,3 +347,35 @@ def test_step_range_resumes_in_process(tmp_path):
     for step in range(10):
         one(m3, o3, step)
     np.testing.assert_array_equal(m2.weight.numpy(), m3.weight.numpy())
+
+
+@pytest.mark.parametrize("kind,interval", [
+    ("step", "save_every_n_steps"), ("epoch", "save_interval_epochs")])
+def test_range_beats_after_each_step(tmp_path, monkeypatch, kind, interval):
+    """The step and epoch ranges beat once each step (and its snapshot) has
+    ended, never before a step starts: no beat while step 0 runs. Each
+    beat records the snapshots on disk at that moment (a count that needs
+    no clock)."""
+    from paddle_tpu.incubate.checkpoint import AutoCheckpointManager
+    hb = tmp_path / "hb"
+    monkeypatch.setenv(HEARTBEAT_FILE_ENV, str(hb))
+    save_dir = tmp_path / "ckpt"
+    beats = []
+    real = elastic.heartbeat
+
+    def counted():
+        real()
+        beats.append(sorted(os.listdir(save_dir)))
+
+    monkeypatch.setattr(elastic, "heartbeat", counted)
+    acp = AutoCheckpointManager(str(save_dir), **{interval: 2})
+    it = getattr(acp, f"train_{kind}_range")(3)
+    assert next(it) == 0
+    assert not hb.exists() and beats == []  # step 0 in progress
+    assert next(it) == 1
+    assert hb.exists() and beats == [[]]  # step 0 ended, nothing saved
+    assert next(it) == 2
+    assert beats[1] == [f"{kind}_1"]  # step 1's beat follows its snapshot
+    assert list(it) == []
+    assert beats[2] == [f"{kind}_1", f"{kind}_2"]
+    assert len(beats) == 3
