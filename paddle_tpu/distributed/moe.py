@@ -184,8 +184,26 @@ def batched_form(pairs, num_experts, count, hidden, width):
     return capacity, math.ceil(count * (7 / 8 if tiled else 1 / 4))
 
 
+def _choice(scores, bias, groups):
+    """The scores the top-k is taken over: `scores + bias`, and with
+    `groups` = (n_group, topk_group) -inf outside each token's topk_group
+    best groups (a group scores the sum of its two best)."""
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if groups is None:
+        return choice
+    n_group, keep = groups
+    tokens, experts = choice.shape
+    grouped = choice.reshape(tokens, n_group, experts // n_group)
+    best = jax.lax.top_k(jax.lax.top_k(grouped, 2)[0].sum(-1), keep)[1]
+    kept = jnp.zeros((tokens, n_group), bool).at[
+        jnp.arange(tokens)[:, None], best].set(True)
+    return jnp.where(jnp.repeat(kept, experts // n_group, axis=1), choice,
+                     -jnp.inf)
+
+
 def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
-                     scale, live=None, scoring="sigmoid"):
+                     scale, live=None, scoring="sigmoid", bias=None,
+                     groups=None, limit=0.0):
     """The dropless expert layer of ONE chip of an expert-parallel
     deployment: x [T, h] -> (routed part [T, h] float32, counts int32 [8]).
 
@@ -199,7 +217,20 @@ def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
     pairs are sorted by held expert (pairs of experts held elsewhere and of
     rows that `live` [T] switches off sort behind the last group), the
     three products run group by group, and each token sums its weighted
-    rows back. No token is dropped at any imbalance; a token none of whose
+    rows back. Three options of the router, none on by default (with all
+    three off the traced program is the one without them):
+
+    - `bias` [E] float32: a correction bias that CHOOSES and does not
+      weigh (DeepSeek-V3's `noaux_tc`): the top-k are taken over
+      `scores + bias`, their weights are still their scores;
+    - `groups` = (n_group, topk_group), static: group-limited top-k. The E
+      experts are n_group groups of E / n_group in order; a group scores
+      the sum of its two best choice scores, the topk_group best groups are
+      kept, and the top_k are taken within them;
+    - `limit` (static float): a clamped SwiGLU where > 0, silu(min(gate,
+      limit)) * clip(up, -limit, limit).
+
+    No token is dropped at any imbalance; a token none of whose
     experts is held gets zeros. What the absent experts would add is
     another chip's, and nothing here stands in for them or for the
     exchange.
@@ -243,7 +274,11 @@ def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
     logits = jnp.dot(x.astype(jnp.float32), router_w, precision="highest")
     scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
         else jax.nn.softmax(logits, axis=-1)
-    top_s, top_i = jax.lax.top_k(scores, top_k)
+    if bias is None and groups is None:
+        top_s, top_i = jax.lax.top_k(scores, top_k)
+    else:
+        top_i = jax.lax.top_k(_choice(scores, bias, groups), top_k)[1]
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
     weight = (top_s / jnp.sum(top_s, axis=-1, keepdims=True)
               * jnp.float32(scale)).reshape(-1)
     local = top_i.astype(jnp.int32) - first
@@ -267,7 +302,11 @@ def held_experts_mlp(x, router_w, w_gate, w_up, w_down, held, top_k,
     def gated(rows, product):
         """(silu(rows W_gate) * (rows W_up)) W_down, float32, with the
         experts' weights multiplied as `product` does it."""
-        act = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
+        if limit > 0:
+            act = jax.nn.silu(jnp.minimum(product(rows, w_gate), limit)) \
+                * jnp.clip(product(rows, w_up), -limit, limit)
+        else:
+            act = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
         return product(act.astype(x.dtype), w_down)
 
     def grouped(n):

@@ -203,6 +203,9 @@ _STAT_EVENTS = ("steps", "prefill_tokens", "generated_tokens",
                 # calls of the expert layer, and those whose largest load
                 # was at most two / four times the uniform one
                 "moe_layer_calls", "moe_fit_2x", "moe_fit_4x",
+                # a family with KDA layers: live rows x KDA layers that a
+                # decode step updated in place, and the prefills' chunks
+                "kda_state_row_trips", "kda_prefill_chunks",
                 # the `context_tokens`, `live_row_trips` and `live_blocks`
                 # stats of serving.decode, summed
                 "context_tokens", "live_row_trips", "live_blocks",

@@ -362,7 +362,9 @@ class PagedKVCache:
 
     State slots (`layout` "hybrid"): `layer_caches` says per layer "rows"
     or "state" (`ModelSpec.layer_caches`). A rows layer is a (k, v) pair as
-    above. A state layer's leaf is a `SeqState` of arrays
+    above, or, where `cache_shape` is (W,), ONE latent pool (multi-head
+    latent attention beside recurrent state; window layers still need
+    (k, v) pairs). A state layer's leaf is a `SeqState` of arrays
     [num_state_slots, ...shape] (`state_shapes`): one fixed-size entry a
     SEQUENCE, whatever its length. The two kinds have ONE owner: `allocate`
     takes a sequence's blocks and its slot, `free` returns both (a
@@ -432,10 +434,9 @@ class PagedKVCache:
                     "window > 0 and num_window_blocks > 0")
             self.layout = "hybrid"
         if "state" in layer_caches:
-            if not heads or not state_shapes or num_state_slots <= 0:
+            if not state_shapes or num_state_slots <= 0:
                 raise ValueError(
-                    "state layers need (num_heads, head_dim) rows layers, "
-                    "state_shapes and num_state_slots > 0")
+                    "state layers need state_shapes and num_state_slots > 0")
             self.layout = "hybrid"
         if self.layout != "heads":
             # what still assumes (k, v) pairs of heads in every layer
@@ -483,9 +484,12 @@ class PagedKVCache:
                 for _ in range(num_layers))
         else:
             self._qpools = None
+            # a rows layer of (W,) rows is one latent pool, else a pair
+            rows = (lambda: jnp.zeros(shape, dtype)) if not heads \
+                else (lambda: (jnp.zeros(shape, dtype),
+                               jnp.zeros(shape, dtype)))
             self._pools: Tuple[Tuple[jnp.ndarray, jnp.ndarray], ...] = \
-                tuple((jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-                      if kind == "rows" else SeqState(*(
+                tuple(rows() if kind == "rows" else SeqState(*(
                           jnp.zeros((num_state_slots,) + tuple(sh), dt)
                           for sh, dt in state_shapes))
                       if kind == "state" else tuple(

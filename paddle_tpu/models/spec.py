@@ -9,7 +9,8 @@ frozen (hashable, so usable as a jit static argument) object per (family,
 configuration). Every family builds its own, `serving_spec(...)` beside
 the functions it wraps (`models.generation.serving_spec`, the first, and
 `models.pangu_moe.serving_spec`, `models.qwen3_next.serving_spec`,
-`models.mellum.serving_spec`); all are cached, so the same configuration
+`models.mellum.serving_spec`, `models.ling_flash.serving_spec`); all are
+cached, so the same configuration
 always gives the same object and the same compiled programs. The serving layer imports this module and no family.
 
 Cache layouts (`PagedKVCache` builds the pools from `cache_shape` and
@@ -24,7 +25,8 @@ to `write_prefill_scatter`):
             are [B, S, W];
 - "hybrid": `layer_caches` says per LAYER what it caches. A "rows" layer
             is a "heads" layer, a (k, v) pair of pools of `cache_shape`
-            (H, D). A "state" layer caches no position: it keeps ONE
+            (H, D), or, where `cache_shape` is (W,), a "latent" layer, ONE
+            pool (dense prefill rows [B, S, W]). A "state" layer caches no position: it keeps ONE
             fixed-size entry a SEQUENCE (a recurrent state), the leaves
             `state_shapes` names, stored [num_state_slots, ...shape] as a
             `paged_cache.SeqState`; the cache manager gives a sequence a
@@ -116,7 +118,9 @@ class ModelSpec:
 
     @property
     def pools_per_layer(self) -> int:
-        return 1 if self.cache_layout == "latent" else 2
+        """Pools a rows layer: one of (W,) latent rows, a (k, v) pair of
+        (H, D) heads."""
+        return 1 if len(self.cache_shape) == 1 else 2
 
     @property
     def state_layers(self) -> int:

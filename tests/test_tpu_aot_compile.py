@@ -505,3 +505,31 @@ def test_the_latent_pool_is_scattered_in_place_at_the_cell_size(one_chip):
     rows, which a v5e keeps block-major; 2,048 dense positions)."""
     _scatter_in_place(one_chip, 5, 8192, (576,), jnp.bfloat16,
                       (1, 2048, 576))
+
+
+def test_the_kda_step_updates_the_slots_in_place_at_the_cell_size(one_chip):
+    """The KDA decode kernel at the Ling cell's widths (128 rows, 32 heads
+    of 128, 128 slots): Mosaic takes it (the (8, 128) transpose, the
+    history's bfloat16 lane rows), the state and history slot arrays are
+    aliased to the results, and nothing of the state's size is a
+    temporary: the update is in place."""
+    from paddle_tpu.ops.pallas import kda_step as kk
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    n, h, d, taps = 128, 32, 128, 4
+    # the slot arrays are donated as the decode chunk's carry is
+    step = jax.jit(functools.partial(kk.kda_step, scale=1 / np.sqrt(d)),
+                   donate_argnums=(4, 5))
+    compiled = step.lower(
+        sds((n, h, 3 * d), jnp.bfloat16), sds((n, h, d), jnp.float32),
+        sds((n, h), jnp.float32), sds((taps, h, 3 * d), jnp.bfloat16),
+        sds((n, h, d, d), jnp.float32),
+        sds((n, h, (taps - 1) * 3 * d), jnp.bfloat16), sds((n,), jnp.int32),
+        sds((n,), jnp.bool_), sds((n,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%kda_step[.\d]* = ", text)) == 1
+    mem = compiled.memory_analysis()
+    state = n * h * d * d * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state / 100
