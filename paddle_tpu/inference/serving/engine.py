@@ -206,6 +206,9 @@ _STAT_EVENTS = ("steps", "prefill_tokens", "generated_tokens",
                 # a family with KDA layers: live rows x KDA layers that a
                 # decode step updated in place, and the prefills' chunks
                 "kda_state_row_trips", "kda_prefill_chunks",
+                # a family with Gated DeltaNet layers: live rows x DeltaNet
+                # layers that a decode step updated in place
+                "gdn_state_row_trips",
                 # the `context_tokens`, `live_row_trips` and `live_blocks`
                 # stats of serving.decode, summed
                 "context_tokens", "live_row_trips", "live_blocks",

@@ -23,13 +23,16 @@ of 128; key head j serves the value heads 2j, 2j + 1):
 
 with S_0 = 0 in R^{128 x 128} per value head. The cache of such a layer is
 one fixed-size entry a SEQUENCE, whatever its length: S (float32) and the
-last three columns of u (`state_shapes`; the second stored as one row of
-3 x channels, oldest first). Prefill evaluates the recurrence chunk-wise
-(`gdn_chunked`, the chunked delta rule of arXiv:2412.06464: inside a chunk
-of C positions the d's solve one unit-lower-triangular system, between
-chunks the state moves by three products) and hands back the final entry;
-decode applies one step (`gdn_step`). tests/test_qwen3_next.py holds both
-equal to the recurrence as written.
+last three columns of u (`state_shapes`; the second stored per VALUE head,
+[3, q | k of its key head | its v] oldest first, so the q and k channels
+are there once for each value head they serve). Prefill evaluates the
+recurrence chunk-wise (`gdn_chunked`, the chunked delta rule of
+arXiv:2412.06464: inside a chunk of C positions the d's solve one
+unit-lower-triangular system, between chunks the state moves by three
+products) and hands back the final entry; decode applies one step in place
+with the KDA family's kernel (`ops/pallas/kda_step.py`, named `kda_step`),
+the decay of a head given to each of its key channels.
+tests/test_qwen3_next.py holds both equal to the recurrence as written.
 
 Gated attention (H query heads, G key-value heads of D = 256, G < H):
 
@@ -71,12 +74,14 @@ from ..distributed.moe import held_experts_mlp
 from ..inference.serving.paged_cache import (SeqState, gather_rows,
                                              write_rows)
 from ..nn import initializer as I
-from .pangu_moe import COUNTERS, _mm, gated_mlp, rms_norm, rope
+from ..ops.pallas import kda_step as kda_kernel
+from .pangu_moe import COUNTERS as MOE_COUNTERS
+from .pangu_moe import _mm, gated_mlp, rms_norm, rope
 from .spec import ModelSpec, merge_counts
 
 __all__ = ["Qwen3NextConfig", "Qwen3Next", "param_shapes", "forward",
            "prefill", "serving_spec", "gdn_recurrence", "gdn_chunked",
-           "gdn_step", "init_value"]
+           "init_value", "COUNTERS"]
 
 F32 = jnp.float32
 #: queries a block of the prefill's attention scores at a time
@@ -85,6 +90,10 @@ QUERY_BLOCK = 512
 MOE_TOKEN_BLOCK = 1024
 #: positions a chunk of the prefill's DeltaNet scan
 GDN_CHUNK = 64
+#: the counts of a layer (ModelSpec.counters): the expert layer's, then
+#: live rows x DeltaNet layers a decode step updated, the expert layer's
+#: maximum last (`merge_counts`)
+COUNTERS = MOE_COUNTERS[:-1] + ("gdn_state_row_trips",) + MOE_COUNTERS[-1:]
 
 
 @dataclass(frozen=True)
@@ -135,11 +144,13 @@ class Qwen3NextConfig:
     @property
     def state_shapes(self):
         """A DeltaNet layer's entry a sequence: S per value head (float32)
-        and the conv window's history, oldest column first."""
-        return (((self.linear_num_value_heads, self.linear_key_head_dim,
-                  self.linear_value_head_dim), "float32"),
-                (((self.linear_conv_kernel_dim - 1) * self.conv_dim,),
-                 self.dtype))
+        and the conv window's history per value head, oldest column first
+        (`per_value_head`: the layout of `kda_step`, which takes q, k and v
+        of one width, dk = dv as every published configuration has)."""
+        Hv, dk = self.linear_num_value_heads, self.linear_key_head_dim
+        return (((Hv, dk, self.linear_value_head_dim), "float32"),
+                ((Hv, (self.linear_conv_kernel_dim - 1)
+                  * (2 * dk + self.linear_value_head_dim)), self.dtype))
 
 
 def param_shapes(cfg: Qwen3NextConfig) -> dict:
@@ -252,6 +263,20 @@ def gdn_heads(c, cfg):
     return (jnp.repeat(q, rep, axis=-2), jnp.repeat(k, rep, axis=-2), v)
 
 
+def per_value_head(u, cfg):
+    """The conv's channels [..., conv_dim] ([q|k|v], q and k by key head)
+    laid out by value head, [..., Hv, 2 dk + dv]: value head h's q and k
+    are those of its key head h // (Hv / Hk), then its own v. A depthwise
+    convolution of this layout is the one of the flat channels."""
+    Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, kd = cfg.linear_key_head_dim, cfg.key_dim
+    lead, rep = u.shape[:-1], Hv // Hk
+    q, k = (jnp.repeat(u[..., at:at + kd].reshape(lead + (Hk, dk)), rep,
+                       axis=-2) for at in (0, kd))
+    v = u[..., 2 * kd:].reshape(lead + (Hv, cfg.linear_value_head_dim))
+    return jnp.concatenate([q, k, v], axis=-1)
+
+
 def gdn_output(p, pre, o, z, cfg, dtype):
     """[RMSNorm_dv(o) * w_n * silu(z)] W_out: o [..., Hv, dv] float32,
     z [..., value_dim]."""
@@ -264,8 +289,8 @@ def gdn_output(p, pre, o, z, cfg, dtype):
 def gdn_recurrence(q, k, v, g, beta, state):
     """The delta rule as written, one position after another: q, k
     [T, H, dk], v [T, H, dv], g, beta [T, H], state [H, dk, dv] (float32)
-    -> (o [T, H, dv], final state). What `gdn_chunked` and `gdn_step` are
-    held to."""
+    -> (o [T, H, dv], final state). What `gdn_chunked` and the decode
+    step are held to."""
     def step(S, t):
         q_t, k_t, v_t, g_t, b_t = t
         S = jnp.exp(g_t)[:, None, None] * S
@@ -334,23 +359,10 @@ def gdn_chunked(q, k, v, g, beta, state, chunk):
     return o[:T], state
 
 
-def gdn_step(q, k, v, g, beta, state):
-    """One position for N rows: q, k [N, H, dk], v [N, H, dv], g, beta
-    [N, H], state [N, H, dk, dv] -> (o [N, H, dv], new state). Two passes
-    over the state, both elementwise: S~^T k and S~^T q are read together,
-    o = S~^T q + d (k . q) needs no third."""
-    decayed = jnp.exp(g)[..., None, None] * state
-    r = jnp.sum(decayed * k[..., :, None], axis=-2)
-    sq = jnp.sum(decayed * q[..., :, None], axis=-2)
-    d = beta[..., None] * (v - r)
-    o = sq + d * jnp.sum(k * q, axis=-1, keepdims=True)
-    return o, decayed + k[..., :, None] * d[..., None, :]
-
-
 def _gdn_dense(p, pre, h, cfg):
     """A DeltaNet layer over whole sequences h [B, T, hidden] from zero
     state: (output [B, T, hidden], SeqState of the final S [B, Hv, dk, dv]
-    and conv history [B, (K - 1) * conv_dim])."""
+    and conv history [B, Hv, (K - 1) (2 dk + dv)])."""
     K, B, T = cfg.linear_conv_kernel_dim, h.shape[0], h.shape[1]
     u, z, beta, g = gdn_inputs(p, pre, h, cfg)
     padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0)))   # zero history
@@ -359,7 +371,8 @@ def _gdn_dense(p, pre, h, cfg):
     zero = jnp.zeros(q.shape[2:] + (v.shape[-1],), F32)
     o, state = jax.vmap(
         lambda *a: gdn_chunked(*a, zero, GDN_CHUNK))(q, k, v, g, beta)
-    history = padded[:, T:].reshape(B, -1)
+    history = per_value_head(padded[:, T:], cfg).transpose(0, 2, 1, 3) \
+        .reshape(B, cfg.linear_num_value_heads, -1)
     return gdn_output(p, pre, o, z, cfg, h.dtype), SeqState(state, history)
 
 
@@ -485,6 +498,12 @@ def moe_block(p, i, h, cfg, live=None):
         lambda flat, on: _moe_tokens(p, pre, flat, cfg, on), h, live)
 
 
+def _counts(moe, state_rows=0):
+    """A layer's counts in COUNTERS' order."""
+    return jnp.concatenate([moe[:-1], jnp.asarray(state_rows, jnp.int32)[None],
+                            moe[-1:]])
+
+
 def _dense_layers(p, ids, cfg):
     """The forward over whole sequences ids [B, T]: (hidden states after
     the last layer, per layer the dense (k, v) rows or the final SeqState,
@@ -504,7 +523,7 @@ def _dense_layers(p, ids, cfg):
             p, i, rms_norm0(x, p[pre + "norm2.weight"], cfg.rms_norm_eps),
             cfg)
         x = x + m
-        total = merge_counts(total, counts)
+        total = merge_counts(total, _counts(counts))
     return x, cached, total
 
 
@@ -541,29 +560,23 @@ def _token_embed(params, tokens, positions):
 
 
 def _gdn_decode(cfg, p, pre, h, leaf, positions, live, state_slots):
-    """One DeltaNet step for N rows against their slots of the layer's
-    SeqState: a row at position 0 starts from zeros whatever its slot held,
-    a row not live leaves its entry as it is."""
-    states, histories = leaf
-    K, fresh = cfg.linear_conv_kernel_dim, positions == 0
+    """One DeltaNet step for N rows, each row's entry updated in place in
+    its slot by the `kda_step` kernel, the decay of a value head given to
+    each of its key channels (a row at position 0 starts from zeros, a row
+    not live is not written)."""
+    Hv, dk = cfg.linear_num_value_heads, cfg.linear_key_head_dim
+    dv, N = cfg.linear_value_head_dim, h.shape[0]
+    states, history = leaf
     u, z, beta, g = gdn_inputs(p, pre, h, cfg)
-    history = jnp.where(fresh[:, None], 0, histories[state_slots]) \
-        .reshape(h.shape[0], K - 1, -1)
-    window = jnp.concatenate([history, u[:, None]], axis=1)
-    q, k, v = gdn_heads(gdn_conv(
-        p, pre, [window[:, j] for j in range(K)], h.dtype), cfg)
-    state = jnp.where(fresh[:, None, None, None], 0, states[state_slots])
-    o, state = gdn_step(q, k, v, g, beta, state)
-    at = jnp.where(live, state_slots, states.shape[0])   # dropped
-    # the entries are back in their slots before the layer's output goes
-    # on: left to itself the compiler puts off every layer's write to the
-    # end of the trip and keeps all their gathered states until then
-    o, states, histories = jax.lax.optimization_barrier((
-        o, states.at[at].set(state, mode="drop"),
-        histories.at[at].set(window[:, 1:].reshape(h.shape[0], -1),
-                             mode="drop")))
-    return gdn_output(p, pre, o, z, cfg, h.dtype), \
-        SeqState(states, histories)
+    with jax.named_scope("gdn_step"):
+        o, states, history = kda_kernel.kda_step(
+            per_value_head(u, cfg), jnp.broadcast_to(g[..., None],
+                                                     (N, Hv, dk)), beta,
+            per_value_head(p[pre + "gdn.conv.weight"].T, cfg), states,
+            history, state_slots, live, positions == 0,
+            scale=1.0 / math.sqrt(dk),
+            interpret=not kda_kernel.supported(Hv, dk, dv))
+    return gdn_output(p, pre, o, z, cfg, h.dtype), SeqState(states, history)
 
 
 def _own_group(x, G):
@@ -626,14 +639,16 @@ def _decode_layer(cfg, params, i, x, pool, slot_blocks, slot_offsets,
     if cfg.is_full_attention(i):
         mixed, pool = _attn_decode(cfg, params, pre, h, pool, slot_blocks,
                                    slot_offsets, tables, positions, att_lens)
+        rows = 0
     else:
         mixed, pool = _gdn_decode(cfg, params, pre, h, pool, positions,
                                   live, state_slots)
+        rows = jnp.sum(live)
     y = x[:, 0] + mixed
     m, counts = moe_block(
         params, i, rms_norm0(y, params[pre + "norm2.weight"], eps), cfg,
         live)
-    return (y + m)[:, None], pool, counts
+    return (y + m)[:, None], pool, _counts(counts, rows)
 
 
 def _decode_head(cfg, params, x):

@@ -96,9 +96,10 @@ def test_the_expert_layer_in_token_blocks_counts_what_the_whole_counts(
     monkeypatch.setattr(qn, "MOE_TOKEN_BLOCK", 8)
     blocks = jax.jit(lambda p, i: qn._dense_layers(p, i, cfg))(params, ids)
     np.testing.assert_allclose(blocks[0], whole[0], atol=LIMIT, rtol=0)
-    pairs, hit, full, batched, calls, fit2, fit4, load = (
+    pairs, hit, full, batched, calls, fit2, fit4, state_rows, load = (
         np.asarray(c) for c in zip(whole[2], blocks[2]))
     assert pairs[0] == pairs[1] > 0             # every pair, exactly once
+    assert list(state_rows) == [0, 0]           # a prefill steps no row
     assert hit[1] >= hit[0] and load[1] <= load[0]      # per block
     # PR 37: a call a layer, and in blocks of 8 tokens ceil(29 / 8) a layer
     assert list(calls) == [cfg.num_hidden_layers, 4 * cfg.num_hidden_layers]
@@ -143,17 +144,141 @@ def test_chunkwise_deltanet_equals_the_recurrence(length):
     np.testing.assert_allclose(got_s, want_s, atol=2e-6, rtol=0)
 
 
+# ------------------------------------------- the decode step, in place
+def _composed_step(q, k, v, g, beta, state):
+    """The step as the family composed it of XLA operations before it went
+    through the `kda_step` kernel: q, k [N, H, dk], v [N, H, dv], g, beta
+    [N, H], state [N, H, dk, dv] -> (o [N, H, dv], new state)."""
+    decayed = jnp.exp(g)[..., None, None] * state
+    r = jnp.sum(decayed * k[..., :, None], axis=-2)
+    sq = jnp.sum(decayed * q[..., :, None], axis=-2)
+    d = beta[..., None] * (v - r)
+    o = sq + d * jnp.sum(k * q, axis=-1, keepdims=True)
+    return o, decayed + k[..., :, None] * d[..., None, :]
+
+
+def _composed_decode(cfg, p, pre, h, states, histories, positions, live,
+                     state_slots):
+    """The family's decode step before the kernel, as it was: the rows'
+    entries gathered by slot (the history flat, [slots, (K - 1) conv_dim]),
+    stepped, scattered back (rows not live dropped)."""
+    K, fresh = cfg.linear_conv_kernel_dim, positions == 0
+    u, z, beta, g = qn.gdn_inputs(p, pre, h, cfg)
+    history = jnp.where(fresh[:, None], 0, histories[state_slots]) \
+        .reshape(h.shape[0], K - 1, -1)
+    window = jnp.concatenate([history, u[:, None]], axis=1)
+    q, k, v = qn.gdn_heads(qn.gdn_conv(
+        p, pre, [window[:, j] for j in range(K)], h.dtype), cfg)
+    state = jnp.where(fresh[:, None, None, None], 0, states[state_slots])
+    o, state = _composed_step(q, k, v, g, beta, state)
+    at = jnp.where(live, state_slots, states.shape[0])
+    return (qn.gdn_output(p, pre, o, z, cfg, h.dtype),
+            states.at[at].set(state, mode="drop"),
+            histories.at[at].set(window[:, 1:].reshape(h.shape[0], -1),
+                                 mode="drop"))
+
+
+def _per_head_history(flat, cfg):
+    """A flat history [slots, (K - 1) conv_dim] in the stored layout
+    [slots, Hv, (K - 1) (2 dk + dv)]."""
+    K = cfg.linear_conv_kernel_dim
+    return qn.per_value_head(flat.reshape(flat.shape[0], K - 1, -1), cfg) \
+        .transpose(0, 2, 1, 3).reshape(flat.shape[0],
+                                       cfg.linear_num_value_heads, -1)
+
+
+def _gdn_layer(seed):
+    """Layer 0's DeltaNet parameters at the small widths (2 key heads
+    under 4 value heads), drawn so that the conv, the decays (g between
+    about -6 and 0) and the gates all matter; and the config."""
+    cfg = qn.Qwen3NextConfig(**SMALL)
+    rng = np.random.default_rng(seed)
+    p = {name: jnp.asarray(rng.normal(size=shape) * 0.3, jnp.float32)
+         for name, (shape, _) in qn.param_shapes(cfg).items()
+         if name.startswith("layers.0.gdn.")}
+    p["layers.0.gdn.A_log"] = jnp.asarray(
+        rng.uniform(-1.5, 0.5, size=(4,)), jnp.float32)
+    return cfg, p
+
+
+def test_the_kernel_step_equals_the_composed_step():
+    """`_gdn_decode` through `kda_step` (interpret mode) against the
+    composed step it replaced, on rows dead (first and in the middle),
+    live out of slot order, and fresh (position 0 over a slot that still
+    holds its last owner's entry): the live rows' outputs and entries at
+    float32 resolution, the history exactly, every other slot to the
+    bit."""
+    cfg, p = _gdn_layer(11)
+    rng = np.random.default_rng(12)
+    K, slots = cfg.linear_conv_kernel_dim, 8
+    h = jnp.asarray(rng.normal(size=(6, 64)), jnp.float32)
+    slot = jnp.asarray([5, 3, 0, 1, 6, 2], jnp.int32)
+    live = jnp.asarray([False, True, True, False, True, True])
+    positions = jnp.asarray([4, 0, 9, 2, 5, 1], jnp.int32)
+    states = jnp.asarray(rng.normal(size=(slots, 4, 8, 8)), jnp.float32)
+    flat = jnp.asarray(rng.normal(size=(slots, (K - 1) * cfg.conv_dim)),
+                       jnp.float32)
+    want_y, want_s, want_h = _composed_decode(
+        cfg, p, "layers.0.", h, states, flat, positions, live, slot)
+    y, (got_s, got_h) = qn._gdn_decode(
+        cfg, p, "layers.0.", h, SeqState(states, _per_head_history(flat, cfg)),
+        positions, live, slot)
+    assert got_h.shape == (slots,) + cfg.state_shapes[1][0]
+    on = np.asarray(live)
+    np.testing.assert_allclose(np.asarray(y)[on], np.asarray(want_y)[on],
+                               atol=2e-5, rtol=0)
+    np.testing.assert_allclose(got_s, want_s, atol=2e-6, rtol=0)
+    np.testing.assert_array_equal(got_h, _per_head_history(want_h, cfg))
+    untouched = [s for s in range(slots) if s not in (3, 0, 6, 2)]
+    assert untouched == [1, 4, 5, 7]
+    for got, was in ((got_s, states), (got_h, _per_head_history(flat, cfg))):
+        np.testing.assert_array_equal(np.asarray(got)[untouched],
+                                      np.asarray(was)[untouched])
+    # the fresh row read nothing of what its slot held
+    assert np.abs(np.asarray(got_s[3] - states[3])).max() > 0.1
+
+
 def test_a_decode_step_continues_from_a_prefills_state():
-    q, k, v, g, beta, state = _delta_inputs(np.random.default_rng(3), 21)
-    want_o, want_s = qn.gdn_recurrence(q, k, v, g, beta, state)
-    _, at_19 = qn.gdn_chunked(q[:19], k[:19], v[:19], g[:19], beta[:19],
-                              state, 8)
-    rows = at_19[None]                          # one row of a decode batch
-    for t in (19, 20):
-        o, rows = qn.gdn_step(q[t][None], k[t][None], v[t][None],
-                              g[t][None], beta[t][None], rows)
-        np.testing.assert_allclose(o[0], want_o[t], atol=2e-6, rtol=0)
-    np.testing.assert_allclose(rows[0], want_s, atol=2e-6, rtol=0)
+    """The layer's prefill over 19 positions, then five decode steps
+    through the kernel in slot 2 of 3 (the others full of noise) are the
+    recurrence as written over all 24, outputs and final state; so is the
+    composed step from the same prefill."""
+    cfg, p = _gdn_layer(3)
+    pre, K, T, at = "layers.0.", cfg.linear_conv_kernel_dim, 24, 19
+    h = jnp.asarray(np.random.default_rng(4).normal(size=(T, 64)),
+                    jnp.float32)
+    u, z, beta, g = qn.gdn_inputs(p, pre, h, cfg)
+    padded = jnp.pad(u, ((K - 1, 0), (0, 0)))
+    q, k, v = qn.gdn_heads(qn.gdn_conv(
+        p, pre, [padded[j:j + T] for j in range(K)], h.dtype), cfg)
+    want_o, want_s = qn.gdn_recurrence(q, k, v, g, beta,
+                                       jnp.zeros((4, 8, 8), jnp.float32))
+    want_y = qn.gdn_output(p, pre, want_o, z, cfg, h.dtype)
+    y_pre, (s0, h0) = jax.jit(lambda p, h: qn._gdn_dense(p, pre, h, cfg))(
+        p, h[None, :at])
+    np.testing.assert_allclose(y_pre[0], want_y[:at], atol=2e-5, rtol=0)
+    noise = np.random.default_rng(5)
+    leaf = SeqState(
+        jnp.asarray(noise.normal(size=(3, 4, 8, 8)), jnp.float32)
+        .at[2].set(s0[0]),
+        jnp.asarray(noise.normal(size=(3,) + h0.shape[1:]), jnp.float32)
+        .at[2].set(h0[0]))
+    others = [np.asarray(a)[:2] for a in leaf]
+    rows = s0, u[at - K + 1:at].reshape(1, -1)  # the composed form's entry
+    kernel, composed = (jax.jit(functools.partial(fn, cfg), static_argnums=1)
+                        for fn in (qn._gdn_decode, _composed_decode))
+    for t in range(at, T):
+        args = (jnp.asarray([t], jnp.int32), jnp.asarray([True]),
+                jnp.asarray([2], jnp.int32))
+        y, leaf = kernel(p, pre, h[t][None], leaf, *args)
+        np.testing.assert_allclose(y[0], want_y[t], atol=2e-5, rtol=0)
+        y_c, *rows = composed(p, pre, h[t][None], *rows, args[0], args[1],
+                              jnp.asarray([0], jnp.int32))
+        np.testing.assert_allclose(y_c[0], want_y[t], atol=2e-5, rtol=0)
+    np.testing.assert_allclose(leaf.arrays[0][2], want_s, atol=2e-6, rtol=0)
+    np.testing.assert_allclose(rows[0][0], want_s, atol=2e-6, rtol=0)
+    for got, was in zip(leaf, others):
+        np.testing.assert_array_equal(np.asarray(got)[:2], was)
 
 
 def test_strong_decay_leaves_no_overflow_in_a_chunk():
@@ -439,10 +564,11 @@ def test_engine_streams_are_bit_equal_for_chunks_of_8_and_of_1():
         assert (lg.max(-1) - lg[np.arange(len(toks)), toks]).max() < LIMIT
     assert eng8.stats.moe_pairs == eng1.stats.moe_pairs > 0
     # rows a position in 1 layer of 4: k and v of 2 x 16 float32; a state a
-    # sequence in the other 3: 4 heads x 8 x 8 and 3 columns of 64 channels
+    # sequence in the other 3: 4 heads x 8 x 8 and 3 columns of 4 value
+    # heads x 24 channels (its key head's q and k, its own v)
     assert eng8.stats.cache_bytes_per_token == 2 * 2 * 16 * 4 \
         == eng8.stats.cache_physical_bytes_per_token
-    assert eng8.stats.state_bytes_per_seq == 3 * (256 + 192) * 4
+    assert eng8.stats.state_bytes_per_seq == 3 * (256 + 288) * 4
     assert eng8.stats.state_slots_in_use == 0           # all drained
     # live rows summed over trips: every decoded token but each request's
     # first (its prefill's) took one trip
@@ -476,6 +602,8 @@ def test_a_row_preempted_by_recompute_comes_back_with_its_state():
     assert eng.stats.preemptions > 0
     assert tight == roomy
     assert eng.cache.num_state_slots_used() == 0
+    # every live row of every trip stepped in each of the 3 DeltaNet layers
+    assert eng.stats.gdn_state_row_trips == 3 * eng.stats.live_row_trips > 0
 
 
 def test_the_chunk_carries_the_slot_in_one_more_column():

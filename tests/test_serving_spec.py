@@ -153,10 +153,13 @@ def test_the_other_families_chunk_keeps_its_name_and_argument_shapes(family):
     assert "module @jit_fused_decode_chunk " in lowered.as_text()
     flat_in = jax.tree_util.tree_leaves(lowered.in_avals)
     assert len(flat_in) == len(params) + leaves + 1
-    # the result and the pools back, and the spec's eight counts as rows
-    # (five before PR 37's `moe_layer_calls`, `moe_fit_2x`, `moe_fit_4x`)
+    # the result and the pools back, and the spec's counts as rows: the
+    # expert layer's eight (five before PR 37's `moe_layer_calls`,
+    # `moe_fit_2x`, `moe_fit_4x`), and the hybrid family's DeltaNet rows
+    counts = {"latent": 8, "hybrid": 9}[family]
+    assert len(spec.counters) == counts
     out = jax.tree_util.tree_leaves(lowered.out_info)
-    assert len(out) == 1 + leaves and out[0].shape == (8 + 2 + 8, 2)
+    assert len(out) == 1 + leaves and out[0].shape == (8 + 2 + counts, 2)
     # every pool leaf is donated: it aliases its output
     assert lowered.as_text().count("tf.aliasing_output") == leaves
 
@@ -198,13 +201,19 @@ def test_a_span_takes_stats_from_inside_its_scope(tmp_path):
 # differs; `gpt2.*` has none and stays the parent of PR 35's. PR 37 changed
 # `latent.*` and `hybrid.*` again on purpose (values from its own tree):
 # three counts more (`moe_layer_calls`, `moe_fit_2x`, `moe_fit_4x`), two
-# compares a call; `gpt2.*` is still the parent of PR 35's.
+# compares a call; `gpt2.*` is still the parent of PR 35's. The hybrid
+# family's DeltaNet decode step through the `kda_step` kernel changed
+# `hybrid.*` again on purpose (values from its own tree): the chunk calls
+# the kernel in place of the gather, step and scatter, both programs keep
+# the conv history per value head, and a ninth count,
+# `gdn_state_row_trips`, rides beside the expert layer's; `latent.*` and
+# `gpt2.*` stay.
 PARENT_OF_PR_35 = {
     "gpt2.chunk": "03d3b78e69ba813e", "gpt2.prefill": "094638537d5db785",
     "gpt2.scatter": "337b6f8e98105a15", "latent.chunk": "5cacecb5e963bce6",
-    "latent.prefill": "f571d1216580742f", "hybrid.chunk": "61ed64b9cf040867",
-    "hybrid.prefill": "c6e869e72c58d8eb",
-    "hybrid.prefill.blocked": "cfd9028b55502e65",
+    "latent.prefill": "f571d1216580742f", "hybrid.chunk": "16435f8054a988d9",
+    "hybrid.prefill": "b52e5c6d2b0f7e3b",
+    "hybrid.prefill.blocked": "1759582e5fe80c10",
 }
 
 

@@ -533,3 +533,58 @@ def test_the_kda_step_updates_the_slots_in_place_at_the_cell_size(one_chip):
     state = n * h * d * d * 4
     assert mem.alias_size_in_bytes >= state
     assert mem.temp_size_in_bytes < state / 100
+
+
+def test_a_deltanet_layer_steps_its_state_in_place_at_the_cell_size(
+        one_chip):
+    """One Gated DeltaNet layer of the Qwen3-Next cell's decode program
+    (16 key heads under 32 value heads of 128 x 128, hidden 2,048, 128
+    rows against 128 state slots, bfloat16 weights): the mixer is ONE
+    `kda_step` Mosaic call, the slot arrays are aliased to the results,
+    and no gather, scatter, slice or copy of the [., 32, 128, 128] state
+    is left, as the composed step's were (a temporary three times the slot
+    array). The mixer alone leaves temporaries under 1 % of the slot
+    array; the whole layer adds the expert block's own buffers."""
+    from paddle_tpu.inference.serving.paged_cache import SeqState
+    from paddle_tpu.models import qwen3_next as qn
+    cfg = qn.Qwen3NextConfig(
+        vocab_size=1024, hidden_size=2048, num_hidden_layers=1,
+        num_experts=512, held_experts=(0, 16), dtype="bfloat16")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    n, slots = 128, 128
+    params = {k: sds(s, d) for k, (s, d) in qn.param_shapes(cfg).items()}
+    (state_shape, _), (history_shape, _) = cfg.state_shapes
+    assert state_shape == (32, 128, 128) and history_shape == (32, 3 * 384)
+    pool = SeqState(sds((slots,) + state_shape, jnp.float32),
+                    sds((slots,) + history_shape, jnp.bfloat16))
+    rows = (sds((n,), jnp.int32), sds((n,), jnp.bool_),
+            sds((n,), jnp.int32))
+
+    def layer(p, x, pool, positions, live, state_slots):
+        return qn._decode_layer(cfg, p, 0, x, pool, None, None, None,
+                                positions, None, live, True, state_slots)
+
+    def mixer(p, x, pool, positions, live, state_slots):
+        return qn._gdn_decode(cfg, p, "layers.0.", x, pool, positions,
+                              live, state_slots)
+    backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        whole, alone = (
+            jax.jit(fn, donate_argnums=(2,)).lower(
+                params, sds(shape, jnp.bfloat16), pool, *rows).compile()
+            for fn, shape in ((layer, (n, 1, 2048)), (mixer, (n, 2048))))
+    finally:
+        jax.default_backend = backend
+    state = slots * 32 * 128 * 128 * 4
+    for compiled in (whole, alone):
+        text = compiled.as_text()
+        assert len(re.findall(r"%kda_step[.\d]* = ", text)) == 1
+        assert not re.findall(
+            r"f32\[\d+,32,128,128\]\S* (?:gather|scatter|dynamic-slice|"
+            r"dynamic-update-slice|copy|copy-start)\(", text)
+        assert compiled.memory_analysis().alias_size_in_bytes >= state
+    assert alone.memory_analysis().temp_size_in_bytes < state / 100
+    assert whole.memory_analysis().temp_size_in_bytes < state / 50
