@@ -177,9 +177,10 @@ def physical_shape(cache_shape):
       the sublanes and be padded to a tile of 8 (float32) or 16 (bfloat16):
       4 or 8 times the bytes (from 5 heads on the padding stays under
       double, and the shape is left alone). The row's minor dimensions are
-      then (block_size, H * D), as the latent layout's; the ragged kernel,
-      whose tile is a four-dimensional stored block, does not read such a
-      pool: `generation.decode_layer` gathers it;
+      then (block_size, H * D), as the latent layout's; the ragged kernel
+      reads such a pool as its flat grouped tile, where the H heads are KV
+      heads that each serve several query heads
+      (`qwen3_next.full_rows_attention`);
     - anything else (the toy sizes of the CPU tests, 6 or 8 heads of 128):
       the logical shape. A pool whose two minor dimensions fill no whole
       tile keeps whatever copies the compiler makes for it."""

@@ -66,7 +66,7 @@ from ..inference.serving.paged_cache import gather_rows, write_rows
 from ..nn import initializer as I
 from .pangu_moe import COUNTERS, _mm, rms_norm
 from .qwen3_next import (QUERY_BLOCK, _grouped_attention, _rows_attention,
-                         map_token_blocks)
+                         full_rows_attention, map_token_blocks)
 from .spec import ModelSpec, merge_counts
 
 __all__ = ["MellumConfig", "Mellum", "param_shapes", "forward", "prefill",
@@ -328,13 +328,14 @@ def _decode_layer(cfg, params, i, x, pool, slot_blocks, slot_offsets,
                   tables, positions, att_lens, live, ragged,
                   state_slots=None, att_starts=None, table_starts=None):
     """Layer i for N rows of one token each (`ModelSpec.decode_layer`):
-    write the token's k and v at its slot, gather each row's blocks through
-    its table as flat rows of G * D, attend, run the expert block. A full
-    layer gathers the sequence's whole table and attends to its first
-    att_lens positions; a sliding layer is handed its WINDOW table (row w
-    of the gathered context is position table_starts + w) and attends to
-    att_starts .. att_lens - 1. Composed of XLA operations (`ragged` has
-    no kernel to choose here yet)."""
+    write the token's k and v at its slot, attend, run the expert block. A
+    full layer attends to the first att_lens positions of the sequence's
+    whole table through `qwen3_next.full_rows_attention` (under `ragged`,
+    where the kernel applies, it walks the row's live blocks; elsewhere the
+    table is gathered as flat rows of G * D); a sliding layer gathers its
+    WINDOW table (row w of the gathered context is position table_starts +
+    w) and attends to att_starts .. att_lens - 1, composed of XLA
+    operations."""
     pre, eps, kind = f"layers.{i}.", cfg.rms_norm_eps, cfg.kinds[i]
     G = cfg.num_key_value_heads
     h = rms_norm(x[:, 0], params[pre + "norm1.weight"], eps)
@@ -342,14 +343,16 @@ def _decode_layer(cfg, params, i, x, pool, slot_blocks, slot_offsets,
     kp, vp = pool
     kp = write_rows(kp, k, slot_blocks, slot_offsets)
     vp = write_rows(vp, v, slot_blocks, slot_offsets)
-    flat = (G * cfg.head_dim,)
     if kind == SLIDING:         # as rows of the gathered window
         att_lens, att_starts = att_lens - table_starts, \
             att_starts - table_starts
-    att = _rows_attention(
-        q, gather_rows(kp, tables, flat).astype(h.dtype),
-        gather_rows(vp, tables, flat).astype(h.dtype), att_lens, G,
-        att_starts)
+        flat = (G * cfg.head_dim,)
+        att = _rows_attention(
+            q, gather_rows(kp, tables, flat).astype(h.dtype),
+            gather_rows(vp, tables, flat).astype(h.dtype), att_lens, G,
+            att_starts)
+    else:
+        att = full_rows_attention(q, kp, vp, tables, att_lens, G, ragged)
     y = x[:, 0] + _mm(att.reshape(att.shape[0], -1),
                       params[pre + "attn.o.weight"])
     m, counts = moe_block(

@@ -75,6 +75,7 @@ from ..inference.serving.paged_cache import (SeqState, gather_rows,
                                              write_rows)
 from ..nn import initializer as I
 from ..ops.pallas import kda_step as kda_kernel
+from ..ops.pallas import ragged_paged_attention as ragged_kernel
 from .pangu_moe import COUNTERS as MOE_COUNTERS
 from .pangu_moe import _mm, gated_mlp, rms_norm, rope
 from .spec import ModelSpec, merge_counts
@@ -612,33 +613,49 @@ def _rows_attention(q, ctx_k, ctx_v, att_lens, G, att_starts=None):
     return _own_group(out.reshape(N, G, H // G, G, D), G).reshape(N, H, D)
 
 
+def full_rows_attention(q, kp, vp, tables, att_lens, G, ragged):
+    """One query a row against its sequence's rows in the (k, v) pools of
+    a full layer, positions 0 .. att_lens - 1 -> [N, H, D] in q's dtype.
+    Under `ragged`, where the pools' stored block is the ragged kernel's
+    flat grouped tile (ops/pallas/ragged_paged_attention.route_gate: on
+    the chip, G heads on whole lane rows), the kernel walks each row's
+    live blocks in the pools; elsewhere each row's whole table is gathered
+    and `_rows_attention` attends over it."""
+    H, D = q.shape[1:]
+    if ragged and ragged_kernel.route_gate(D, H, kp.shape[1:], kp.dtype):
+        return ragged_kernel.ragged_decode_attention(q, kp, vp, tables,
+                                                     att_lens)
+    flat = (G * D,)
+    return _rows_attention(
+        q, gather_rows(kp, tables, flat).astype(q.dtype),
+        gather_rows(vp, tables, flat).astype(q.dtype), att_lens, G)
+
+
 def _attn_decode(cfg, p, pre, h, pool, slot_blocks, slot_offsets, tables,
-                 positions, att_lens):
+                 positions, att_lens, ragged):
     """One gated-attention step for N rows against the (k, v) pools: write
-    the token's rows at its slot, gather each row's blocks through its
-    table, attend (composed of XLA operations)."""
+    the token's rows at its slot, attend over each row's rows through its
+    table (`full_rows_attention`)."""
     kp, vp = pool
-    G = cfg.num_key_value_heads
     q, gate, k, v = attn_inputs(p, pre, h, positions, cfg)
     kp = write_rows(kp, k, slot_blocks, slot_offsets)
     vp = write_rows(vp, v, slot_blocks, slot_offsets)
-    flat = (G * cfg.head_dim,)
-    att = _rows_attention(
-        q, gather_rows(kp, tables, flat).astype(h.dtype),
-        gather_rows(vp, tables, flat).astype(h.dtype), att_lens, G)
+    att = full_rows_attention(q, kp, vp, tables, att_lens,
+                              cfg.num_key_value_heads, ragged)
     return _attn_output(p, pre, att, gate), (kp, vp)
 
 
 def _decode_layer(cfg, params, i, x, pool, slot_blocks, slot_offsets,
                   tables, positions, att_lens, live, ragged,
                   state_slots=None, att_starts=None, table_starts=None):
-    """Layer i for N rows of one token each (`ModelSpec.decode_layer`;
-    `ragged` has no kernel to choose here yet; no layer has a window)."""
+    """Layer i for N rows of one token each (`ModelSpec.decode_layer`; no
+    layer has a window)."""
     pre, eps = f"layers.{i}.", cfg.rms_norm_eps
     h = rms_norm0(x[:, 0], params[pre + "norm1.weight"], eps)
     if cfg.is_full_attention(i):
         mixed, pool = _attn_decode(cfg, params, pre, h, pool, slot_blocks,
-                                   slot_offsets, tables, positions, att_lens)
+                                   slot_offsets, tables, positions, att_lens,
+                                   ragged)
         rows = 0
     else:
         mixed, pool = _gdn_decode(cfg, params, pre, h, pool, positions,

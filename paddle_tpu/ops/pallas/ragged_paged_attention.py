@@ -16,19 +16,31 @@ the next live row's first group in flight during a row's last. A block
 past a row's length costs nothing (no step, no copy, its table entry
 never read), and a padded/dead row (length 0) costs one empty grid step,
 which is what lets the engine pad every batch to one fixed width
-(``max_num_seqs``). The kernel has no MXU work at all: one query token a
-row, so both products are VPU multiplies with a reduce. The tile is the
-pool's block AS STORED (serving/paged_cache.physical_shape):
-``[block_size, H, D]``, or ``[block_size, H * D // 128, 128]`` with
-``128 // D`` heads side by side on the lanes, read in place either way;
-on the chip it has to be whole lane rows (``supported``).
+(``max_num_seqs``). The tile is the pool's block AS STORED
+(serving/paged_cache.physical_shape), read in place; on the chip it has
+to be whole lane rows (``supported``). Two tiles, told apart by the
+pool's rank when the kernel is traced; the walk is the same and only the
+product differs:
+
+- one cached head a query head, ``[block_size, H, D]`` or
+  ``[block_size, H * D // 128, 128]`` with ``128 // D`` heads side by side
+  on the lanes: one query token a row leaves the MXU nothing to tile, so
+  both products are VPU multiplies with a reduce, a block at a time;
+- the flat grouped tile ``[block_size, W]``, W = Hkv * D: Hkv KV heads on
+  whole lane rows, each serving Hq / Hkv query heads (grouped-query
+  attention). The query heads, each laid on its own KV head's lanes with
+  zeros on the others (``[Hq, W]``), multiply a whole DMA group of keys
+  ``[group * block_size, W]`` on the MXU, and the probabilities its
+  values; each head keeps its own KV head's lanes of the result.
 
 Reference parity: ``ragged_attention_reference`` is a ``lax.scan``
 over the same block axis performing the *identical* flash update, a
 block at a time, so the kernel (run under ``interpret=True`` on CPU in
 tier-1) is pinned against it with bounded error (bitwise on a pool stored
-one head a row); the bucketed gather path remains the bitwise oracle at
-the engine level (see tests/test_serving_ragged.py).
+one head a row); the flat grouped tile against the composed attention it
+replaces (``qwen3_next._rows_attention`` over the gathered rows); the
+bucketed gather path remains the bitwise oracle at the engine level (see
+tests/test_serving_ragged.py).
 
 Blueprint: "Ragged Paged Attention: A High-Performance and Flexible
 LLM Inference Kernel for TPU" (PAPERS.md); built on the flash /
@@ -47,28 +59,41 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30  # matches the serving masks: exact erase, no NaN from inf-inf
 
 
-def supported(head_dim: int, num_heads: int, block) -> bool:
+def supported(head_dim: int, num_heads: int, block,
+              dtype=jnp.float32) -> bool:
     """Kernel scope: TPU backend only (CPU tier-1 exercises it through
-    ``interpret=True``), and a stored block ``[block_size, G, L]`` that the
-    kernel can copy and multiply as it is: whole lane rows (a DMA out of
-    HBM cannot cut a lane row), a sublane-aligned block_size, and two
-    slots of k and v with the products' block-sized temporaries inside the
-    default scoped VMEM (``_MAX_BLOCK_VMEM_BYTES``)."""
+    ``interpret=True``), and a stored block that the kernel can copy and
+    multiply as it is: whole lane rows (a DMA out of HBM cannot cut a lane
+    row), a sublane-aligned block_size, and two slots of k and v with the
+    products' block-sized temporaries inside the default scoped VMEM
+    (``_MAX_BLOCK_VMEM_BYTES``). ``block`` is ``[block_size, G, L]`` with
+    one cached head a query head (num_heads of head_dim), or the flat
+    grouped tile ``[block_size, W]`` of ``dtype``, whose W // head_dim KV
+    heads each serve as many of the num_heads query heads; a group's
+    blocks are then one operand of the MXU, so block_size is a whole
+    number of the dtype's sublane tiles."""
     if jax.default_backend() != "tpu":
         return False
+    if len(block) == 2:
+        bs, lanes = block
+        return lanes % 128 == 0 and lanes % head_dim == 0 \
+            and num_heads % (lanes // head_dim) == 0 \
+            and bs % _sublane_tile(dtype) == 0 \
+            and _block_vmem_bytes(block, dtype) <= _MAX_BLOCK_VMEM_BYTES
     bs, g, lanes = block
     return lanes % 128 == 0 and lanes % head_dim == 0 \
         and g * lanes == num_heads * head_dim and bs % 8 == 0 \
         and _block_vmem_bytes(block, jnp.float32) <= _MAX_BLOCK_VMEM_BYTES
 
 
-def route_gate(head_dim: int, num_heads: int, block) -> bool:
+def route_gate(head_dim: int, num_heads: int, block,
+               dtype=jnp.float32) -> bool:
     """Serving-side routing gate: the ragged kernel applies whenever the
     engine selected ``kernel="ragged"`` (the default) and the pool's
     stored block is in scope. Off-TPU the caller keeps the block-table
     gather + composed attention — same jitted sub-programs as the dense
     path, preserving the engine's structural bitwise-parity contract."""
-    return supported(head_dim, num_heads, block)
+    return supported(head_dim, num_heads, block, dtype)
 
 
 # what one DMA buffer (k or v of one group) may hold in VMEM: four of them
@@ -82,14 +107,19 @@ _GROUP_VMEM_BYTES = 512 * 1024
 _MAX_BLOCK_VMEM_BYTES = 1024 * 1024
 
 
+def _sublane_tile(dtype) -> int:
+    """Rows of one VMEM tile of `dtype`: 8 rows of 32 bits."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
 def _block_vmem_bytes(block_shape, dtype) -> int:
-    """Bytes a stored block [bs, G, L] takes in VMEM: G padded to the
-    dtype's sublane tile (8 rows of 32 bits), L to 128 lanes."""
-    bs, g, lanes = block_shape
-    itemsize = jnp.dtype(dtype).itemsize
-    sublanes = 8 * max(1, 4 // itemsize)
-    return bs * -(-g // sublanes) * sublanes * -(-lanes // 128) * 128 \
-        * itemsize
+    """Bytes a stored block takes in VMEM: [bs, G, L] with G padded to the
+    dtype's sublane tile, or [bs, W] with bs padded to it; the lanes to
+    128."""
+    *lead, rows, lanes = block_shape
+    sublanes = _sublane_tile(dtype)
+    return int(np.prod(lead, dtype=int)) * -(-rows // sublanes) * sublanes \
+        * -(-lanes // 128) * 128 * jnp.dtype(dtype).itemsize
 
 
 def blocks_per_group(block_shape, dtype, max_blocks: int) -> int:
@@ -111,6 +141,9 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
     group 1: the copy's start, its wait and the flash update are traced
     once each per site. Tracing and lowering this body is host time in
     every serving run's set-up (PERF.md section 6, PR 39)."""
+    # the flat grouped tile (buffers [slot, block, bs, W]) multiplies a
+    # whole group at once, the other tile a block at a time
+    flat = len(k_buf.shape) == 4
     i = pl.program_id(0)
     num_rows = pl.num_programs(0)
 
@@ -194,6 +227,37 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
         acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)
         m_ref[...] = m_new
 
+    def accumulate_group(grp, k, v):
+        # The flat grouped tile: q_ref holds each query head on its own
+        # KV head's lanes, zeros on the others ([Hq, W], the pool's
+        # dtype), so one MXU product gives every head's scores over the
+        # group's positions, and acc takes the probabilities times every
+        # KV head's lanes (the caller keeps each head's own). m and l are
+        # [Hq, 1].
+        rows = k.shape[0] * k.shape[1]
+        k = k.reshape(rows, k.shape[2])
+        v = v.reshape(rows, v.shape[2])
+        first = grp * group * block_size
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * jnp.float32(scale)
+        pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < length, s, jnp.float32(NEG_INF))
+
+        m_prev = m_ref[...]                                  # [Hq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)                               # [Hq, rows]
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        # the rows past the group's landed blocks hold what the slot held
+        # before: their probabilities are 0, but 0 x NaN is NaN
+        held = first + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        v = jnp.where(held < length, v, jnp.zeros_like(v))
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
     def one_group(grp, _):
         slot = (first_slot + grp) % 2
         # the group after this one is in flight while this one is
@@ -207,6 +271,9 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
                   1 - slot)
 
         landed = group_copies(i, grp, slot, lambda dma: dma.wait())
+        if flat:
+            accumulate_group(grp, k_buf[slot], v_buf[slot])
+            return
 
         def one_block(b, _):
             accumulate(grp * group + b, k_buf[slot, b].astype(jnp.float32),
@@ -249,11 +316,14 @@ def ragged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     """One attention step over ragged paged KV state.
 
     q:            [N, H, D]  one query token per row
-    k_pool/v_pool:[num_blocks, block_size, G, L] paged-cache pools AS
-                  STORED (serving/paged_cache.physical_shape): [H, D]
-                  itself, or L // D heads side by side on L = 128 lanes
-                  (G * L == H * D). The kernel's tile is the stored
-                  block; q and the result are viewed the same way.
+    k_pool/v_pool:paged-cache pools AS STORED
+                  (serving/paged_cache.physical_shape), either
+                  [num_blocks, block_size, G, L]: [H, D] itself, or
+                  L // D heads side by side on L = 128 lanes
+                  (G * L == H * D), q and the result viewed the same way;
+                  or [num_blocks, block_size, W]: W // D KV heads side by
+                  side, each serving H // (W // D) consecutive query heads
+                  (grouped-query attention), q laid on them as [H, W]
     block_tables: [N, MB] int32 pool indices (row-major positions); the
                   first cdiv(lengths[i], block_size) entries of row i are
                   pool blocks, the rest are never read
@@ -261,47 +331,76 @@ def ragged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     returns       [N, H, D]; dead rows return zeros.
     """
     n, h, d = q.shape
-    num_blocks_kv, bs, g, lanes = k_pool.shape
-    if g * lanes != h * d or lanes % d:
-        raise ValueError(
-            f"a pool stored as [.., {g}, {lanes}] holds no [{h}, {d}] heads")
+    block = k_pool.shape[1:]
+    if len(block) == 2:
+        bs, lanes = block
+        kv = lanes // d
+        if lanes % d or h % kv:
+            raise ValueError(f"a pool stored as [.., {lanes}] holds no KV "
+                             f"heads of {d} for {h} query heads")
+        rows, stats = h, (h, 1)
+
+        def tile(x):        # each query head on its own KV head's lanes
+            eye = jnp.eye(kv, dtype=x.dtype)[None, :, None, :, None]
+            return (x.reshape(n, kv, h // kv, 1, d) * eye).reshape(
+                n, h, lanes).astype(k_pool.dtype)
+
+        def untile(o):      # each query head's own KV head's lanes
+            return jnp.moveaxis(jnp.diagonal(
+                o.reshape(n, kv, h // kv, kv, d), axis1=1, axis2=3),
+                -1, 1).reshape(n, h, d)
+    else:
+        bs, g, lanes = block
+        if g * lanes != h * d or lanes % d:
+            raise ValueError(
+                f"a pool stored as [.., {g}, {lanes}] holds no [{h}, {d}] "
+                "heads")
+        rows, stats = g, (g, lanes)
+
+        def tile(x):
+            return x.reshape(n, g, lanes)
+
+        def untile(o):
+            return o.reshape(n, h, d)
     mb = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
-    group = blocks_per_group((bs, g, lanes), k_pool.dtype, mb)
+    group = blocks_per_group(block, k_pool.dtype, mb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, g, lanes), lambda i, t, le: (i, 0, 0)),
+            pl.BlockSpec((1, rows, lanes), lambda i, t, le: (i, 0, 0)),
             # the pools stay in HBM; the kernel copies a row's live blocks
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, g, lanes), lambda i, t, le: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, rows, lanes), lambda i, t, le: (i, 0, 0)),
         scratch_shapes=[
             # two slots of one group of k and of v, a semaphore a slot
-            pltpu.VMEM((2, group, bs, g, lanes), k_pool.dtype),
-            pltpu.VMEM((2, group, bs, g, lanes), v_pool.dtype),
+            pltpu.VMEM((2, group) + block, k_pool.dtype),
+            pltpu.VMEM((2, group) + block, v_pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SMEM((2,), jnp.int32),
-            # m, l (each head's value on all of its lanes) and acc
-        ] + [pltpu.VMEM((g, lanes), jnp.float32)] * 3,
+            # m, l (each head's value on all of its lanes, or [H, 1] on the
+            # flat tile) and acc
+        ] + [pltpu.VMEM(stats, jnp.float32)] * 2
+        + [pltpu.VMEM((rows, lanes), jnp.float32)],
     )
     kernel = functools.partial(_kernel, block_size=bs, group=group,
                                scale=float(scale), head_dim=d)
     # int32 grid arithmetic (same reason flash_attention scopes x64 off)
     with jax.enable_x64(False):
-        return pl.pallas_call(
+        return untile(pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((n, g, lanes), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((n, rows, lanes), q.dtype),
             # rows in order: a row starts the next live row's first group
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-          q.reshape(n, g, lanes), k_pool, v_pool).reshape(n, h, d)
+          tile(q), k_pool, v_pool))
 
 
 @functools.partial(jax.jit, static_argnames=("scale",))

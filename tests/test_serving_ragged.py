@@ -252,6 +252,118 @@ def test_the_kernels_program_does_not_grow_with_the_group():
     one, four = traced(1, 1), traced(_MB, 4)
     assert one > 100                    # the kernel's body was counted
     assert abs(four - one) <= 4
+    # the flat grouped tile multiplies a whole group at once: its program
+    # is as long at a group of 16 blocks as at 1
+    flat = [_flat_equations(table_len, group)
+            for table_len, group in ((1, 1), (_FLAT_MB, 16))]
+    assert flat[0] > 100 and flat[0] == flat[1]
+
+
+def _flat_equations(table_len, group):
+    block = (_BS, 512)
+    assert rpa.blocks_per_group(block, jnp.bfloat16, table_len) == group
+    pool = jax.ShapeDtypeStruct((_FLAT_NB,) + block, jnp.bfloat16)
+    return _equations(jax.make_jaxpr(
+        functools.partial(rpa.ragged_decode_attention, interpret=True))(
+        jax.ShapeDtypeStruct((3, 16, 256), jnp.bfloat16), pool, pool,
+        jax.ShapeDtypeStruct((3, table_len), jnp.int32),
+        jax.ShapeDtypeStruct((3,), jnp.int32)).jaxpr)
+
+
+# sha1 of the jaxpr text of the kernel at GPT-2 medium's stored block
+# [32, 8, 128] float32 (cells 2, 4 and 8: 16 rows, 512 blocks, tables of 32),
+# taken with jax 0.9.0 on the tree before the flat grouped tile existed
+GPT2M_KERNEL_JAXPR_SHA1 = "acb0045eded51df1adb5318aa3e3e123b48ba946"
+
+
+@pytest.mark.skipif(jax.__version__ != "0.9.0",
+                    reason="the recorded text is jax 0.9.0's")
+def test_the_one_head_a_query_head_tile_traces_as_it_did():
+    """The flat grouped tile is chosen when the kernel is traced, from the
+    pool's rank: the tile GPT-2's cells read gains no equation and traces
+    to the same text, so its lowering and its set-up stay what they were."""
+    import hashlib
+    pool = jax.ShapeDtypeStruct((512, 32, 8, 128), jnp.float32)
+    text = str(jax.make_jaxpr(rpa.ragged_decode_attention)(
+        jax.ShapeDtypeStruct((16, 16, 64), jnp.float32), pool, pool,
+        jax.ShapeDtypeStruct((16, 32), jnp.int32),
+        jax.ShapeDtypeStruct((16,), jnp.int32)))
+    assert hashlib.sha1(text.encode()).hexdigest() == GPT2M_KERNEL_JAXPR_SHA1
+
+
+# ------------------------------------------- the flat grouped tile
+# blocks [32, 512] in a table of 20: a DMA group is 16 blocks in bfloat16
+# (32 KiB a block) and 8 in float32, so the tables hold a whole group and a
+# partial one (two and a partial one in float32)
+_FLAT_NB, _FLAT_MB = 160, 20
+_FLAT_LENGTHS = (0, 1, _BS, _BS + 1, 17 * _BS + 3, _FLAT_MB * _BS)
+# against the composed path on the same pools: float32 rounding of two
+# orders of summation; in bfloat16 both round the probabilities and the
+# result to bfloat16 (2 ** -8 relative), at different points of the sum
+_FLAT_TOL = {"float32": 2e-6, "bfloat16": 2 ** -6}
+
+
+def _flat_case(kv, head_dim, dtype, seed=0):
+    """Rows of every length in _FLAT_LENGTHS (a dead row first) over pools
+    of W = kv * head_dim lanes: each row's live blocks drawn from the pool,
+    its sentinel entries pointing at a block of NaN, and NaN in every block
+    no table holds. Returns the kernel's arguments and the same pools with
+    zeros for NaN, for the composed path."""
+    rng = np.random.RandomState(seed)
+    lengths = np.asarray(_FLAT_LENGTHS, np.int32)
+    lanes = kv * head_dim
+    k, v = (rng.randn(_FLAT_NB, _BS, lanes).astype(np.float32)
+            for _ in range(2))
+    sentinel = _FLAT_NB - 1
+    tables = _block_tables(rng, lengths, sentinel, _BS, _FLAT_MB, sentinel)
+    outside = np.setdiff1d(np.arange(_FLAT_NB), tables[tables != sentinel])
+    assert sentinel in outside and len(outside) > 1
+    k[outside] = np.nan
+    v[outside] = np.nan
+    q = rng.randn(len(lengths), 8 * kv, head_dim).astype(np.float32)
+    k, v, q = (jnp.asarray(a, dtype) for a in (k, v, q))
+    clean = [jnp.where(jnp.isnan(p), 0, p) for p in (k, v)]
+    return (q, k, v, jnp.asarray(tables), jnp.asarray(lengths)), clean
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kv,head_dim", [(2, 256), (4, 128)],
+                         ids=["2x256", "4x128"])
+def test_the_flat_grouped_tile_is_the_composed_attention(kv, head_dim,
+                                                         dtype):
+    """8 query heads a KV head over pools stored as 512 flat lanes, against
+    `qwen3_next._rows_attention` over `gather_rows` (what cells 6 and 7
+    composed before), row lengths 0, 1, a block, a block and one, a whole
+    DMA group and part of the next, the whole table. NaN in every block the
+    kernel must not read reaches no result; a dead row returns zeros."""
+    from paddle_tpu.inference.serving.paged_cache import gather_rows
+    from paddle_tpu.models.qwen3_next import _rows_attention
+    args, (k, v) = _flat_case(kv, head_dim, dtype)
+    q, _, _, tables, lengths = args
+    got = np.asarray(rpa.ragged_decode_attention(*args, interpret=True),
+                     np.float32)
+    flat = (kv * head_dim,)
+    want = np.asarray(_rows_attention(
+        q, gather_rows(k, tables, flat), gather_rows(v, tables, flat),
+        lengths, kv), np.float32)
+    assert np.isfinite(got).all()
+    assert np.all(got[0] == 0.0)
+    tol = _FLAT_TOL[dtype]
+    np.testing.assert_allclose(got[1:], want[1:], rtol=tol, atol=tol)
+
+
+def test_the_flat_grouped_tile_waits_for_the_copies_it_reads():
+    """As the other tile's test: every DMA carried out only when it is
+    waited on, so a group multiplied before its copies land reads what the
+    slot held before (NaN among it)."""
+    from jax.experimental.pallas import tpu as pltpu
+    args, _ = _flat_case(2, 256, "float32", seed=1)
+    got = np.asarray(rpa.ragged_decode_attention(
+        *args, interpret=pltpu.InterpretParams(dma_execution_mode="on_wait")))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(
+        got, np.asarray(rpa.ragged_decode_attention(*args, interpret=True)),
+        rtol=_FLAT_TOL["float32"], atol=_FLAT_TOL["float32"])
 
 
 # ------------------------------------------------------- engine parity
@@ -502,3 +614,180 @@ def test_chunked_chaos_zero_leak_zero_lost(model):
             res[rid], _reference_tokens(model, p, 7))
     assert eng.cache.num_free() == eng.config.num_blocks
     eng.cache.check_integrity()
+
+
+# ------------------------ full layers of the grouped families, kernel forced
+# toy widths whose pools are stored as the flat grouped tile (2 KV heads of
+# 128 -> 256 lanes, `physical_shape`), 4 query heads a KV head
+_GROUPED = dict(vocab_size=128, hidden_size=64, num_hidden_layers=4,
+                num_attention_heads=8, num_key_value_heads=2, head_dim=128,
+                moe_intermediate_size=32, num_experts=8,
+                num_experts_per_tok=2, max_seq_len=64)
+
+
+def _grouped_family(name):
+    from paddle_tpu.models import mellum, qwen3_next
+    from paddle_tpu.models.generation import extract_params
+    if name == "qwen3_next":
+        cfg = qwen3_next.Qwen3NextConfig(
+            **_GROUPED, linear_num_key_heads=2, linear_num_value_heads=4,
+            linear_key_head_dim=8, linear_value_head_dim=8,
+            shared_expert_intermediate_size=32)
+        paddle.seed(3)
+        model, spec = qwen3_next.Qwen3Next(cfg), qwen3_next.serving_spec(cfg)
+    else:
+        cfg = mellum.MellumConfig(**_GROUPED, sliding_window=12)
+        paddle.seed(3)
+        model, spec = mellum.Mellum(cfg), mellum.serving_spec(cfg)
+    return spec, extract_params(model)
+
+
+@pytest.fixture
+def kernel_forced(monkeypatch):
+    """The ragged kernel's gate open off the chip, the kernel run by the
+    interpreter; jit's caches emptied on both sides, so no program traced
+    the other way is reused."""
+    def forced():
+        monkeypatch.setattr(rpa, "route_gate", lambda *a: True)
+        monkeypatch.setattr(rpa, "ragged_decode_attention", functools.partial(
+            rpa.ragged_decode_attention, interpret=True))
+        jax.clear_caches()
+    yield forced
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+def _grouped_engine(spec, params):
+    return LLMEngine(params, spec, EngineConfig(
+        block_size=8, num_blocks=40, max_num_seqs=4))
+
+
+def _grouped_prompts():
+    rng = np.random.default_rng(11)
+    return [rng.integers(0, 128, (n,)).astype(np.int32)
+            for n in (3, 14, 25, 9)]
+
+
+def _grouped_stream(spec, params):
+    eng = _grouped_engine(spec, params)
+    for i, p in enumerate(_grouped_prompts()):
+        eng.add_request(p, SamplingParams(max_tokens=20),
+                        request_id=f"r{i}")
+    out = eng.run(max_steps=200)
+    assert not any(eng.cache.check_integrity().values())
+    return [out[f"r{i}"].tolist() for i in range(4)]
+
+
+def _hand_decoded_logits(spec, params, prompt, steps):
+    """[1 + steps, V] logits of the prefill and `steps` paged decode steps
+    of one sequence, greedy, the tokens taken from the composed path's
+    logits; each step runs twice on two caches, composed and with every
+    layer's `ragged` set. Returns both rows."""
+    import dataclasses
+    from paddle_tpu.inference.serving.attention import paged_decode_step
+
+    def ragged_layer(*args):
+        return spec.decode_layer(*args[:10], True, *args[11:])
+
+    specs = (spec, dataclasses.replace(spec, decode_layer=ragged_layer))
+    steps_of = [jax.jit(functools.partial(paged_decode_step, geom=s),
+                        donate_argnums=(1,)) for s in specs]
+    caches = [_grouped_engine(spec, params).cache for _ in specs]
+    logits, dense, _ = spec.prefill(params, jnp.asarray(prompt[None]))
+    rows = [[np.asarray(logits, np.float32)[0]] for _ in specs]
+    for cache in caches:
+        cache.allocate("s", len(prompt))
+        cache.write_prefill("s", dense, len(prompt))
+    for _ in range(steps):
+        tok = np.asarray([rows[0][-1].argmax()], np.int32)
+        for step, cache, out in zip(steps_of, caches, rows):
+            block, offset, pos = cache.append_slot("s")
+            table = np.zeros((1, 8), np.int32)
+            table[0, :len(cache.block_table("s"))] = cache.block_table("s")
+            kw = {}
+            if spec.state_layers:
+                kw["state_slots"] = np.asarray([cache.state_slot("s")],
+                                               np.int32)
+            if spec.window:
+                wtable, first = cache.window_table("s")
+                kw["window_tables"] = np.zeros((1, 8), np.int32)
+                kw["window_tables"][0, :len(wtable)] = wtable
+                kw["window_firsts"] = np.asarray([first], np.int32)
+            logits, cache.pools = step(
+                params, cache.pools, tok, np.asarray([pos], np.int32),
+                table, np.asarray([block], np.int32),
+                np.asarray([offset], np.int32), **kw)
+            out.append(np.asarray(logits, np.float32)[0])
+            if spec.window:
+                cache.release_behind("s")
+    return np.stack(rows[0]), np.stack(rows[1])
+
+
+def _gathers_and_kernel_calls(jaxpr):
+    """Shapes of the arrays every `gather` of a jaxpr (its loop bodies
+    included) reads from, and the number of `ragged_decode_attention`
+    calls."""
+    shapes, calls = [], 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            shapes.append(tuple(eqn.invars[0].aval.shape))
+        if eqn.params.get("name") == "ragged_decode_attention":
+            calls += 1
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    more, n = _gathers_and_kernel_calls(inner)
+                    shapes += more
+                    calls += n
+    return shapes, calls
+
+
+@pytest.mark.parametrize("family", ["qwen3_next", "mellum"])
+def test_full_layers_through_the_kernel_stream_the_composed_tokens(
+        family, kernel_forced, monkeypatch):
+    """The families of cells 6 and 7 at toy widths stored as the flat
+    grouped tile, with the kernel's gate forced open (the interpreter runs
+    it): the same greedy tokens as the composed path over several chunks,
+    logits within the kernel test's tolerance, and in the chunk's program
+    the full layers' pools read by one kernel call a layer and never
+    gathered, the window layers' pools still gathered."""
+    from paddle_tpu.inference.serving import engine as engine_mod
+    spec, params = _grouped_family(family)
+    assert spec.cache_shape == (2, 128)
+    composed = _grouped_stream(spec, params)
+    kernel_forced()
+
+    plain, ragged = _hand_decoded_logits(spec, params,
+                                         _grouped_prompts()[2], 16)
+    tol = _FLAT_TOL["float32"]
+    np.testing.assert_allclose(ragged, plain, rtol=tol, atol=tol)
+
+    chunks = []
+
+    def recording_chunk(*args):
+        chunks.append(jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args[:3]))
+        return chunk(*args)
+
+    chunk = engine_mod.fused_decode_chunk
+    monkeypatch.setattr(engine_mod, "fused_decode_chunk", recording_chunk)
+    assert _grouped_stream(spec, params) == composed
+    assert len(chunks) >= 3
+
+    k, kernel = 8, "ragged"
+    jaxpr = jax.make_jaxpr(fused_decode_chunk, static_argnums=(3, 4, 5))(
+        *chunks[0], spec, k, kernel).jaxpr
+    gathered, calls = _gathers_and_kernel_calls(jaxpr)
+    rows_layers = [i for i, c in enumerate(spec.layer_caches or
+                                           ("rows",) * spec.num_layers)
+                   if c == "rows"]
+    pools = chunks[0][1]
+    full = {tuple(pools[i][0].shape) for i in rows_layers}
+    assert calls == len(rows_layers) > 0
+    assert not full & set(gathered)
+    windowed = {tuple(pools[i][0].shape) for i, c in
+                enumerate(spec.layer_caches) if c == "window"}
+    assert (family == "mellum") == bool(windowed)
+    assert windowed <= set(gathered) and not windowed & full

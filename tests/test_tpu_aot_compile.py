@@ -5,7 +5,8 @@ flagship GPT for a described (not attached) v5e:2x2: the flash forward
 and backward of the 6-head flagship, the packed-pair kernels of the
 12-head one at both backward branches, the ragged decode kernel at three
 head geometries (two of them packed two heads a lane row, as the cache
-stores them) and at the largest blocks its gate admits, the flash kernel per shard under a 2x2 mesh, and the serving
+stores them), at the largest blocks its gate admits and in its flat
+grouped tile at the two grouped-query cells' widths, the flash kernel per shard under a 2x2 mesh, and the serving
 cells' decode chunk and dense-admission scatter with their pools donated
 and no pool copied. Interpret
 mode accepts what Mosaic refuses (an unaligned slice, a batched dot with no
@@ -218,6 +219,40 @@ def test_ragged_decode_scratch_stays_inside_scoped_vmem(
                                  (block_size + 8,) + block[1:])
     # a lane row cut in two has no copy out of HBM, whatever its size
     assert not rpa.supported(64, 12, (32, 12, 64))
+
+
+# the flat grouped tile at cells 6 and 7's widths: bfloat16 pools stored as
+# one row of 512 lanes a position (2 KV heads of 256 under 16 query heads, 4
+# of 128 under 32), the cells' rows, blocks and tables
+@pytest.mark.parametrize("rows,heads,head_dim,num_blocks,table", [
+    (128, 16, 256, 20480, 160), (64, 32, 128, 18432, 288)],
+    ids=["cell6", "cell7"])
+def test_the_flat_grouped_tile_compiles_at_the_cells_widths(
+        one_chip, monkeypatch, rows, heads, head_dim, num_blocks, table):
+    """The chip's compiler takes the kernel's MXU products over a whole DMA
+    group of 16 blocks, its copies hold a quarter of the default scoped
+    VMEM at most, and the gate admits the tile there."""
+    from paddle_tpu.inference.serving.paged_cache import physical_shape
+    from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    block = (32,) + physical_shape((512 // head_dim, head_dim))
+    assert block == (32, 512)
+    group = rpa.blocks_per_group(block, jnp.bfloat16, table)
+    assert group == 16
+    assert 4 * group * rpa._block_vmem_bytes(block, jnp.bfloat16) \
+        <= 16 * 2 ** 20 // 4
+    pool = sds((num_blocks,) + block, jnp.bfloat16)
+    assert _kernel_calls(
+        rpa.ragged_decode_attention, sds((rows, heads, head_dim),
+                                         jnp.bfloat16),
+        pool, pool, sds((rows, table), jnp.int32), sds((rows,), jnp.int32)) \
+        == 1
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert rpa.route_gate(head_dim, heads, block, jnp.bfloat16)
+    # a block of 8 positions cuts bfloat16's tile of 16 rows in two
+    assert not rpa.route_gate(head_dim, heads, (8, 512), jnp.bfloat16)
 
 
 def _pool_copies(text: str, num_blocks: int) -> list:
